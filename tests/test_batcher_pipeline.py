@@ -20,15 +20,6 @@ import pytest
 from tpu_dist_nn.serving.server import _Batcher
 
 
-def _mesh_available() -> bool:
-    try:
-        from jax.sharding import AxisType  # noqa: F401
-
-        return True
-    except ImportError:
-        return False
-
-
 class _Handle:
     def __init__(self, value):
         self.value = value
@@ -372,8 +363,6 @@ def test_compile_cache_proxy_keys_on_launch_shape_plain_path():
     assert (3, 8) in e._seen_infer_shapes
 
 
-@pytest.mark.skipif(not _mesh_available(),
-                    reason="installed jax lacks the engine's mesh API")
 def test_compile_cache_proxy_counts_padded_launch_shape_data_sharded():
     # The satellite fix: the data-sharded path pads rows to the shard
     # count before jit sees them, so 3 rows and 4 rows on a 2-shard

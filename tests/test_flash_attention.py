@@ -125,3 +125,42 @@ def test_rejects_mismatched_shapes():
     q, k, v = _qkv(1, 16, 2, 8)
     with pytest.raises(ValueError, match="must match"):
         flash_attention(q, k[:, :8], v, causal=True)
+
+
+@pytest.mark.parametrize("head_dim,dtype,fwd,bwd", [
+    (64, jnp.bfloat16, 94208, 31360),
+    (64, jnp.float32, 47104, 23552),
+    (128, jnp.bfloat16, 94208, 31360),   # lanes pad to 128 either way
+    (256, jnp.float32, 23552, 15616),
+])
+def test_sequence_ceiling_follows_the_resident_vmem_model(
+        head_dim, dtype, fwd, bwd):
+    from tpu_dist_nn.kernels.flash_attention import max_seq_len
+
+    assert max_seq_len(head_dim, dtype) == fwd
+    assert max_seq_len(head_dim, dtype, backward=True) == bwd
+
+
+def test_length_past_the_ceiling_raises_before_tracing_a_kernel():
+    """The whole-sequence operands are VMEM-resident, so a length the
+    compiler would refuse is refused here first, by name — forward at
+    the forward ceiling, and at the lower backward ceiling once the VJP
+    is traced. Shapes only: nothing this long is allocated."""
+    from tpu_dist_nn.kernels.flash_attention import max_seq_len
+
+    def attn(q, k, v):
+        return flash_attention(q, k, v, causal=True)
+
+    def spec(t):
+        return (jax.ShapeDtypeStruct((1, t, 1, 64), jnp.bfloat16),) * 3
+
+    fwd, bwd = max_seq_len(64, jnp.bfloat16), max_seq_len(
+        64, jnp.bfloat16, backward=True
+    )
+    with pytest.raises(ValueError, match=f"T={fwd + 1} .* {fwd} rows"):
+        jax.eval_shape(attn, *spec(fwd + 1))
+    grad = jax.grad(lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum())
+    assert jax.eval_shape(attn, *spec(bwd + 1)).shape == (1, bwd + 1, 1, 64)
+    with pytest.raises(ValueError, match="backward pass"):
+        jax.eval_shape(grad, *spec(bwd + 1))
+    jax.eval_shape(grad, *spec(bwd))

@@ -114,7 +114,18 @@ def test_peak_resolution_table_then_measured_host():
     peak, source = resolve_peak(None)
     assert peak > 0 and source == "measured-host-blas"
     # Cached: a second resolve returns the same measurement.
-    assert resolve_peak("unknown-kind")[0] == peak
+    assert resolve_peak(None)[0] == peak
+
+
+def test_unknown_accelerator_kind_is_an_error_not_a_host_peak():
+    """A device kind missing from PEAK_FLOPS must fail the configure
+    step: an MFU over the host-BLAS figure would look like a number."""
+    with pytest.raises(ValueError, match="TPU v9 imaginary"):
+        resolve_peak("TPU v9 imaginary")
+    t = GoodputTracker(registry=Registry())
+    with pytest.raises(ValueError, match="PEAK_FLOPS"):
+        t.ensure_peak(device_kind="TPU v9 imaginary", device_count=4)
+    assert t.snapshot()["peak_source"] is None
 
 
 # ------------------------------------------------------ conservation

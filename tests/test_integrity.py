@@ -21,19 +21,6 @@ from tpu_dist_nn.testing import faults
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _engine_available() -> bool:
-    """The seed's Engine/mesh layer needs jax.sharding.AxisType (and
-    jax.shard_map); on older jax every Engine.up fails at import —
-    the real-engine variants skip rather than re-report a known
-    environment gap (the test_obs.py convention)."""
-    try:
-        from jax.sharding import AxisType  # noqa: F401
-
-        return True
-    except ImportError:
-        return False
-
-
 @pytest.fixture(autouse=True)
 def _armed_guard():
     """Every test here assumes the guard is armed (the bench A/B and a
@@ -137,9 +124,6 @@ def fleet(tmp_path_factory):
     """Two real engines of the SAME weights, each behind its own gRPC
     server — replica A is the corruption victim (tests attach/clear its
     launch_hook), replica B stays golden."""
-    if not _engine_available():
-        pytest.skip("jax too old for the Engine mesh layer "
-                    "(no jax.sharding.AxisType)")
     from tpu_dist_nn.api.engine import Engine
     from tpu_dist_nn.core.schema import save_model
     from tpu_dist_nn.serving import serve_engine

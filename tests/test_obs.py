@@ -30,18 +30,6 @@ from tpu_dist_nn.obs import (
 from tpu_dist_nn.obs.registry import POW2_BUCKETS
 
 
-def _engine_available() -> bool:
-    """The seed's Engine/mesh layer needs jax.sharding.AxisType (and
-    jax.shard_map); on older jax every Engine.up fails at import —
-    those variants skip rather than re-report a known environment gap."""
-    try:
-        from jax.sharding import AxisType  # noqa: F401
-
-        return True
-    except ImportError:
-        return False
-
-
 def _get(url: str, timeout: float = 5.0) -> str:
     with urllib.request.urlopen(url, timeout=timeout) as resp:
         return resp.read().decode()
@@ -362,8 +350,6 @@ def test_sampler_survives_broken_source():
 # -------------------------------------------------------- engine + trainers
 
 
-@pytest.mark.skipif(not _engine_available(),
-                    reason="installed jax lacks the engine's mesh API")
 def test_engine_infer_metrics_and_compile_cache(tmp_path):
     from tpu_dist_nn.api.engine import Engine
     from tpu_dist_nn.core.schema import save_model
@@ -528,8 +514,6 @@ def _free_port():
     return port
 
 
-@pytest.mark.skipif(not _engine_available(),
-                    reason="installed jax lacks the engine's mesh API")
 def test_cli_up_metrics_port_end_to_end(tmp_path):
     """The full --metrics-port acceptance path: `tdn up --grpc-port
     --metrics-port` serves /metrics next to the gRPC endpoint; RPC

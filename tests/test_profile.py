@@ -704,8 +704,7 @@ def test_bench_gate_history_fails_checked_in_host_fed_drift():
     """The ISSUE-10 quick-tier smoke: r02->r05 host-fed drifted −3%/
     round — under the pairwise 5% threshold every single time — and
     compounded to −15% vs the r02 best. Best-of-history mode must fail
-    that trajectory on the CHECKED-IN rounds (r01's error record is
-    skipped, not fatal)."""
+    that trajectory on the CHECKED-IN rounds."""
     # --current is PINNED to r05: once a later (recovered) round is
     # checked in, discovery would gate that instead and the drift this
     # smoke exists to reproduce would vanish.
@@ -724,7 +723,7 @@ def test_bench_gate_history_fails_checked_in_host_fed_drift():
     # The bar is the r02 high-water mark, not the r04 predecessor.
     assert row["best_round"] == "BENCH_r02.json"
     assert row["regression"] > 0.10
-    # r01 (failed round, no payload) was skipped without killing the run.
+    # r01 (a failed round with no payload) is no longer checked in.
     assert "BENCH_r01.json" not in verdict["history_rounds"]
     # Report-only still exits 0 on the same trajectory.
     report = subprocess.run(

@@ -1253,24 +1253,20 @@ def test_cli_help_lists_router_and_session_flags(capsys):
                  "--replica-metrics", "m:1"]) == 2
 
 
-def test_spawn_argv_shape():
-    """The subprocess command `--spawn` launches (the slow end-to-end
-    spawn itself is exercised operationally, not in tier-1)."""
-    import sys as _sys
-
-    pool = ReplicaPool([], seed=0)
-    # spawn_local builds `python -m tpu_dist_nn.cli up --config ...
-    # --grpc-port 0 --metrics-port 0`; verify via a stub Popen.
+def _stub_spawn(monkeypatch, platforms):
+    """Stub the subprocess half of spawn_local: each Popen records its
+    argv, and the child 'reports' the next platform and fresh ports."""
     import subprocess
+
     import tpu_dist_nn.serving.pool as pool_mod
 
-    captured = {}
+    argvs = []
 
     class FakeProc:
         stdout = None
 
         def __init__(self, argv, **kw):
-            captured["argv"] = argv
+            argvs.append(argv)
 
         def poll(self):
             return None
@@ -1278,29 +1274,79 @@ def test_spawn_argv_shape():
         def terminate(self):
             pass
 
-    real_popen = subprocess.Popen
-    real_reader = pool_mod._read_child_ports
-    subprocess.Popen = FakeProc
-    pool_mod._read_child_ports = lambda proc, timeout: {
-        "grpc_port": 5101, "metrics_port": 9100,
-    }
-    try:
-        rep = pool.spawn_local("model.json",
-                               extra_args=["--serve-warm-rows", "8"])
-    finally:
-        subprocess.Popen = real_popen
-        pool_mod._read_child_ports = real_reader
-    argv = captured["argv"]
+        def wait(self, timeout=None):
+            return 0
+
+    reports = iter(
+        {"grpc_port": 5101 + i, "metrics_port": 9100 + i, "platform": p}
+        for i, p in enumerate(platforms)
+    )
+
+    def read(proc, timeout):
+        report = next(reports)
+        if report["platform"] is None:  # died before its ready line
+            raise RuntimeError("child exited before printing its ports")
+        return report
+
+    monkeypatch.setattr(subprocess, "Popen", FakeProc)
+    monkeypatch.setattr(pool_mod, "_read_child_ports", read)
+    return argvs
+
+
+def test_spawn_argv_shape(monkeypatch):
+    """The subprocess command `--spawn` launches (the slow end-to-end
+    spawn itself is exercised operationally, not in tier-1)."""
+    import sys as _sys
+
+    argvs = _stub_spawn(monkeypatch, ["cpu"])
+    pool = ReplicaPool([], seed=0)
+    # spawn_local builds `python -m tpu_dist_nn.cli --platform P up
+    # --config ... --grpc-port 0 --metrics-port 0`.
+    rep = pool.spawn_local("model.json", platform="cpu",
+                           extra_args=["--serve-warm-rows", "8"])
+    (argv,) = argvs
     assert argv[0] == _sys.executable
-    assert argv[1:4] == ["-m", "tpu_dist_nn.cli", "up"]
+    assert argv[1:6] == ["-m", "tpu_dist_nn.cli", "--platform", "cpu", "up"]
     assert "--config" in argv and "model.json" in argv
     assert "--grpc-port" in argv and "--metrics-port" in argv
     assert rep.target == "127.0.0.1:5101"
     assert rep.metrics_target == "127.0.0.1:9100"
-    # The respawn argv reuses the now-known ports (reused address).
+    # The respawn argv reuses the now-known ports (reused address) and
+    # keeps the platform.
     assert "5101" in rep.spawn_argv and "9100" in rep.spawn_argv
+    assert rep.spawn_argv[3:5] == ["--platform", "cpu"]
     pool.close()
     CircuitBreaker.evict(rep.target)
+
+
+@pytest.mark.parametrize("platforms,refused", [
+    (["cpu", "cpu", "cpu"], None),   # host replicas: as many as asked
+    (["tpu"], None),                 # one child may own the chips
+    (["tpu", "tpu"], 1),             # a second would open them again
+    (["cpu", "tpu"], 1),
+    (["tpu", None], 1),              # ... or dies on the taken chips
+])
+def test_spawn_refuses_second_replica_on_an_accelerator(
+        monkeypatch, platforms, refused):
+    """Spawned replicas are not assigned chips, and a chip belongs to
+    one process: a child that reports an accelerator next to another
+    spawned child is terminated and the spawn fails, saying why."""
+    _stub_spawn(monkeypatch, platforms)
+    pool = ReplicaPool([], seed=0)
+    try:
+        for i in range(len(platforms)):
+            if i == refused:
+                with pytest.raises(RuntimeError,
+                                   match="one process at a time"):
+                    pool.spawn_local("model.json", platform="tpu")
+            else:
+                pool.spawn_local("model.json", platform="tpu")
+        assert len(pool.replicas()) == len(platforms) - (refused is not None)
+    finally:
+        targets = [r.target for r in pool.replicas()]
+        pool.close()
+        for t in targets:
+            CircuitBreaker.evict(t)
 
 
 def test_scrape_respawns_exited_spawned_replica():

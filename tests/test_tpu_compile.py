@@ -1,0 +1,168 @@
+"""The main path's Pallas kernels compile for a v5e — without a v5e.
+
+The TPU compiler is installed wherever libtpu is, and compiles for a
+chip that is described (``v5e:2x2``) and not attached. That catches what
+interpret mode cannot: misaligned tiles, more VMEM than a kernel may
+use, a kernel that quietly dispatched to its reference (no
+``tpu_custom_call`` in the program). Nothing runs, so nothing here says
+anything about results or speed — ``tests/test_tpu_hardware.py`` and
+``chip_smoke.py`` do that on the chip.
+
+libtpu takes a machine-wide lock for the life of the process that loads
+it, and a second process that tries gets an error. So each case compiles
+in a short-lived child (this file, run as a script) and the children
+queue on a file lock: the cases stay correct when a parallel runner
+spreads them over workers. The child forces the kernels' ``_interpret``
+switch off — code that asks ``jax.default_backend()`` still sees the CPU
+here — and turns the persistent compile cache off, which cannot read
+such entries back without a chip.
+"""
+
+import fcntl
+import importlib
+import json
+import os
+import subprocess
+import sys
+import tempfile
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ACTS = ("relu", "relu", "softmax")
+FCNN = (784, 128, 64, 10)
+
+
+def _fused_dense(m, k, n, activation):
+    def build(S, jnp):
+        from tpu_dist_nn.kernels.fused_dense import fused_dense
+
+        fn = lambda x, w, b: fused_dense(x, w, b, activation=activation)  # noqa: E731
+        return fn, (S((m, k)), S((k, n)), S((n,)))
+    return build
+
+
+def _chain(batch, int8):
+    def build(S, jnp):
+        from tpu_dist_nn.kernels.fused_dense import fcnn_fused_forward
+        from tpu_dist_nn.kernels.quantized import fcnn_quantized_forward
+
+        dims = list(zip(FCNN, FCNN[1:]))
+        if int8:
+            params = [
+                {"wq": S((a, b), jnp.int8), "scale": S((b,)), "b": S((b,))}
+                for a, b in dims
+            ]
+            fn = lambda p, x: fcnn_quantized_forward(  # noqa: E731
+                p, x, activations=ACTS, prefer_kernel=True
+            )
+        else:
+            params = [{"w": S((a, b)), "b": S((b,))} for a, b in dims]
+            fn = lambda p, x: fcnn_fused_forward(p, x, activations=ACTS)  # noqa: E731
+        return fn, (params, S((batch, FCNN[0])))
+    return build
+
+
+def _flash(t, heads, head_dim, dtype, grad):
+    def build(S, jnp):
+        import jax
+
+        fa = importlib.import_module("tpu_dist_nn.kernels.flash_attention")
+        seq = t if t else fa.max_seq_len(
+            head_dim, jnp.dtype(dtype), backward=grad
+        )
+        fwd = lambda q, k, v: fa.flash_attention(q, k, v, causal=True)  # noqa: E731
+        fn = jax.grad(
+            lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        ) if grad else fwd
+        return fn, (S((1, seq, heads, head_dim), jnp.dtype(dtype)),) * 3
+    return build
+
+
+def _conv(S, jnp):
+    from tpu_dist_nn.kernels.conv2d import fused_conv2d
+
+    fn = lambda imgs, w, b: fused_conv2d(  # noqa: E731
+        imgs, w, b, padding="same", activation="relu", pool_window=(2, 2)
+    )
+    return fn, (S((64, 16, 16, 32)), S((3, 3, 32, 64)), S((64,)))
+
+
+CASES = {
+    "fused_dense_256x784x128_relu": _fused_dense(256, 784, 128, "relu"),
+    "fused_dense_256x64x10_softmax": _fused_dense(256, 64, 10, "softmax"),
+    "fused_dense_100x784x128_relu": _fused_dense(100, 784, 128, "relu"),
+    "f32_chain_b256": _chain(256, int8=False),
+    "f32_chain_b8192": _chain(8192, int8=False),
+    "int8_chain_b512": _chain(512, int8=True),
+    "conv_16x16x32_to_64_pool": _conv,
+    "flash_t4096_h12_d64_bf16_fwd": _flash(4096, 12, 64, "bfloat16", False),
+    "flash_t4096_h12_d64_bf16_grad": _flash(4096, 12, 64, "bfloat16", True),
+    # Past the compiler's 16 MiB default: refused before the kernels
+    # raised their scoped-VMEM limit.
+    "flash_t8192_h12_d64_bf16_grad": _flash(8192, 12, 64, "bfloat16", True),
+    "flash_t8192_h8_d32_f32_fwd": _flash(8192, 8, 32, "float32", False),
+    # The ceiling flash_attention admits is one the compiler accepts.
+    "flash_ceiling_d64_bf16_fwd": _flash(None, 2, 64, "bfloat16", False),
+    "flash_ceiling_d64_bf16_grad": _flash(None, 2, 64, "bfloat16", True),
+    "flash_ceiling_d256_f32_grad": _flash(None, 2, 256, "float32", True),
+}
+
+
+def _compile(case: str) -> dict:
+    """Child side: compile one case for a described v5e."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # The chip runs at default matmul precision. The CPU suite's
+    # "highest" would reach the kernels too, and Mosaic refuses an int8
+    # matmul at fp32 contract precision.
+    os.environ.pop("JAX_DEFAULT_MATMUL_PRECISION", None)
+    sys.path.insert(0, ROOT)
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    for name in ("conv2d", "flash_attention", "fused_dense", "quantized"):
+        importlib.import_module(
+            f"tpu_dist_nn.kernels.{name}"
+        )._interpret = lambda: False
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no compiler here: the parent skips
+        return {"skip": f"{type(e).__name__}: {e}"[:300]}
+    chip = SingleDeviceSharding(topo.devices[0])
+
+    def S(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    fn, args = CASES[case](S, jnp)
+    try:
+        text = jax.jit(fn).lower(*args).compile().as_text()
+    except Exception as e:  # noqa: BLE001 — reported, the parent fails
+        return {"error": f"{type(e).__name__}: {e}"[:2000]}
+    return {"custom_call": "tpu_custom_call" in text}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_to_a_mosaic_call_for_v5e(case):
+    lock = os.path.join(tempfile.gettempdir(), "tdn_tpu_compile.lock")
+    with open(lock, "a") as held:
+        fcntl.flock(held, fcntl.LOCK_EX)  # released when the file closes
+        proc = subprocess.run(
+            [sys.executable, os.path.abspath(__file__), case],
+            capture_output=True, text=True, timeout=300,
+        )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    if "skip" in out:
+        pytest.skip(f"no described v5e here: {out['skip']}")
+    assert out == {"custom_call": True}, out
+
+
+if __name__ == "__main__":
+    print(json.dumps(_compile(sys.argv[1])))

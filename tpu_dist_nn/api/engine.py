@@ -32,6 +32,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 import numpy as np
 
 from tpu_dist_nn.core.schema import (
@@ -49,7 +50,13 @@ from tpu_dist_nn.models.network import (
     network_model_from_params,
 )
 from tpu_dist_nn.train.trainer import jitted_forward, train_network
-from tpu_dist_nn.parallel.mesh import MeshSpec, batch_sharding, build_mesh, replicated
+from tpu_dist_nn.parallel.mesh import (
+    AXIS_STAGE,
+    MeshSpec,
+    batch_sharding,
+    build_mesh,
+    replicated,
+)
 from tpu_dist_nn.parallel.pipeline import (
     build_pipeline_params,
     extract_model,
@@ -63,6 +70,7 @@ from tpu_dist_nn.obs.registry import REGISTRY
 from tpu_dist_nn.train.metrics import classification_metrics
 from tpu_dist_nn.train.trainer import TrainConfig, train_fcnn
 from tpu_dist_nn.train.pipeline_trainer import train_pipelined
+from tpu_dist_nn.utils.backend import param_devices
 
 log = logging.getLogger("tpu_dist_nn.engine")
 slog = get_logger("tpu_dist_nn.engine")
@@ -220,6 +228,16 @@ class Engine:
         elif self.pipelined:
             stages = partition_model(model, self.distribution)
             self._pp = build_pipeline_params(stages, dtype)
+            if jax.process_count() == 1:
+                # Each stage's block lives on that stage's device. Left
+                # on the default device, the executor would copy every
+                # other stage's weights out of it on every launch. (A
+                # multi-process job is left as it was: its arrays are
+                # host-local, and placing them is untested here.)
+                self._pp = self._pp._replace(weights=jax.device_put(
+                    self._pp.weights,
+                    NamedSharding(self.mesh, P(AXIS_STAGE)),
+                ))
             self._params = None
         else:
             self._pp = None
@@ -428,6 +446,11 @@ class Engine:
         }
         if self.virtual_stages > 1:
             base["virtual_stages"] = self.virtual_stages
+        base["param_devices"] = param_devices(
+            [s["params"] for s in self._hp.stages] if self._hp is not None
+            else self._pp.weights if self._pp is not None
+            else self._params
+        )
         if self._hp is not None:
             base.update(self._hp.placement_summary())
         elif self.pipelined:
@@ -901,8 +924,6 @@ class Engine:
                 # (Deriving rows from process_index arithmetic instead
                 # would silently permute outputs on meshes whose data
                 # axis is not process-contiguous.)
-                from jax.sharding import PartitionSpec as P
-
                 from tpu_dist_nn.data.feed import global_from_replicated
                 from tpu_dist_nn.parallel.mesh import AXIS_DATA
 

@@ -15,6 +15,17 @@ and this kernel is its throughput lever. Design:
   producing ``dk``/``dv``. No ``(T, T)`` tensor exists in any pass.
 * **Causal masking + padding** are handled with in-kernel iota masks;
   ragged sequence lengths pad up to the block size and slice back.
+* **Sequence ceiling** — the whole-sequence operands (K/V in the
+  forward and ``dq`` kernels; Q, dO and the ``(T, 1)`` lse/delta
+  columns in the ``dk``/``dv`` kernel) are VMEM-resident, lane-padded
+  to 128 and double-buffered, so T is bounded by VMEM, not HBM. The
+  kernels raise Mosaic's scoped-VMEM limit from its 16 MiB default to
+  :data:`VMEM_LIMIT_BYTES` (a v5e core has 128 MiB), and a length
+  that still would not fit raises a ``ValueError`` naming the ceiling
+  (:func:`max_seq_len`) before anything is traced. At head dim 64 in
+  bf16 that is 94 208 rows forward and 31 360 through the backward
+  pass. Past it the K/V stream has to be blocked over the grid — not
+  built.
 
 Runs in interpreter mode off-TPU (the CPU test mesh), compiles to
 Mosaic on TPU. Swaps into any ``attn_fn`` hook
@@ -33,8 +44,44 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+
+# Scoped-VMEM limit handed to Mosaic for every kernel here, and the room
+# kept free in it for the streamed blocks, outputs and temporaries.
+VMEM_LIMIT_BYTES = 96 * 1024 * 1024
+_VMEM_SLACK_BYTES = 4 * 1024 * 1024
+_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+def max_seq_len(head_dim: int, dtype, *, backward: bool = False) -> int:
+    """Longest (block-padded) sequence whose resident operands fit
+    :data:`VMEM_LIMIT_BYTES`: forward only, or forward and backward.
+
+    Per row a program holds two ``head_dim``-wide operands in ``dtype``
+    (K and V; in the ``dk``/``dv`` kernel Q and dO) and, backward only,
+    the two f32 lse/delta columns — each padded to 128 lanes, each
+    double-buffered. The v5e compiler's refusals fall where this
+    predicts (tests/test_tpu_compile.py)."""
+    lanes = -(-head_dim // 128) * 128
+    row = 2 * lanes * jnp.dtype(dtype).itemsize
+    if backward:
+        row += 2 * 128 * 4
+    rows = (VMEM_LIMIT_BYTES - _VMEM_SLACK_BYTES) // (2 * row)
+    return rows // 128 * 128
+
+
+def _check_seq_len(T: int, Tp: int, head_dim: int, dtype, *, backward: bool):
+    limit = max_seq_len(head_dim, dtype, backward=backward)
+    if Tp > limit:
+        raise ValueError(
+            f"flash_attention keeps whole-sequence operands in VMEM: "
+            f"T={T} (head_dim {head_dim}, {jnp.dtype(dtype).name}) is past "
+            f"the {limit} rows that fit its "
+            f"{VMEM_LIMIT_BYTES // 2**20} MiB limit "
+            f"{'through the backward pass' if backward else 'forward'}"
+        )
 
 
 def _interpret() -> bool:
@@ -116,6 +163,7 @@ def _flash_fwd(q, k, v, *, scale, causal, block_q, block_k, seq_len):
             jax.ShapeDtypeStruct((BH, Tp, 1), jnp.float32),
         ],
         interpret=_interpret(),
+        compiler_params=_PARAMS,
     )(q, k, v)
 
 
@@ -195,6 +243,7 @@ def _flash_bwd(res, g, *, scale, causal, block_q, block_k, seq_len):
     q, k, v, o, lse = res
     do = g.astype(jnp.float32)
     BH, Tp, d = q.shape
+    _check_seq_len(seq_len, Tp, d, q.dtype, backward=True)
     # delta_i = Σ_d dO_id · O_id — the softmax-jacobian diagonal term.
     delta = jnp.sum(do * o.astype(jnp.float32), axis=-1, keepdims=True)
 
@@ -215,6 +264,7 @@ def _flash_bwd(res, g, *, scale, causal, block_q, block_k, seq_len):
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Tp, d), q.dtype),
         interpret=_interpret(),
+        compiler_params=_PARAMS,
     )(q, k, v, g.astype(q.dtype), lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -240,6 +290,7 @@ def _flash_bwd(res, g, *, scale, causal, block_q, block_k, seq_len):
             jax.ShapeDtypeStruct((BH, Tp, d), q.dtype),
         ],
         interpret=_interpret(),
+        compiler_params=_PARAMS,
     )(q, k, v, g.astype(q.dtype), lse, delta)
     return dq, dk, dv
 
@@ -255,7 +306,9 @@ def flash_attention(q, k, v, *, causal: bool, block_q: int = 128,
     Pads T up to the block size (padded keys are masked via the in-kernel
     ``seq_len`` guard, padded queries sliced off), flattens ``(..., H)``
     into the grid's batch dim, and runs the online-softmax kernels.
-    Differentiable via the custom flash VJP.
+    Differentiable via the custom flash VJP. Raises ``ValueError`` for
+    a length past :func:`max_seq_len` (forward here, the lower backward
+    ceiling when the VJP is traced).
     """
     *batch, T, H, Dh = q.shape
     if k.shape != q.shape or v.shape != q.shape:
@@ -268,6 +321,7 @@ def flash_attention(q, k, v, *, causal: bool, block_q: int = 128,
     # bq and the in-kernel k loop by bk, so each must divide Tp exactly.
     step = int(np.lcm(bq, bk))
     Tp = int(np.ceil(T / step) * step)
+    _check_seq_len(T, Tp, Dh, q.dtype, backward=False)
 
     def to_bh(a):
         a = jnp.moveaxis(a, -2, -3)  # (..., H, T, Dh)
@@ -312,14 +366,12 @@ def _flash_call_bwd(scale, causal, block_q, block_k, seq_len, res, g):
 _flash_call.defvjp(_flash_call_fwd, _flash_call_bwd)
 
 
-# Measured crossover on a live TPU v5 lite (artifacts/tpu_r04/
-# kernel_sweep.json, B=4 H=8 Dh=64 causal bf16): XLA's materialized
-# attention wins below this — flash 0.81x/0.89x at T=1024/2048 — and
-# collapses above it (T^2 f32 logits go HBM-bound): flash is 2.32x fwd
-# / 1.74x grad at T=4096. Shapes are static under jit, so the dispatch
-# resolves at trace time. ``TDN_FLASH_MIN_SEQ`` overrides for on-chip
-# re-verification at other shapes (the r4 85M MFU note named the
-# seq-1024 attention path a suspect; the scale suite A/Bs it).
+# Crossover below which XLA's materialized attention is used instead.
+# Not measured on today's code: the figure comes from one round-4 run
+# (B=4 H=8 Dh=64 causal bf16: flash behind XLA at T=1024/2048, ahead at
+# T=4096, where the T^2 f32 logits go HBM-bound). Shapes are static
+# under jit, so the dispatch resolves at trace time.
+# ``TDN_FLASH_MIN_SEQ`` overrides for on-chip re-verification.
 try:
     FLASH_MIN_SEQ = int(os.environ.get("TDN_FLASH_MIN_SEQ", "") or 3072)
 except ValueError:
@@ -328,8 +380,8 @@ except ValueError:
 
 def select_attention(q, k, v, *, causal: bool):
     """Shape-aware attention dispatch, resolved at trace time: the
-    flash kernel where it measures faster (T >= FLASH_MIN_SEQ, or any
-    length where the materialized T^2 score matrix would not fit), the
+    flash kernel from ``FLASH_MIN_SEQ`` up to its VMEM ceiling
+    (:func:`max_seq_len`; past it :func:`flash_attention` raises), the
     jnp reference below that."""
     from tpu_dist_nn.models.transformer import dot_product_attention
 

@@ -132,16 +132,14 @@ def fcnn_quantized_forward(qparams, x, *,
         return forward_quantized(qparams, x, activations)
     if not quantized_chain_fits_vmem(qparams):
         return forward_quantized(qparams, x, activations)
-    # Measured on a live TPU v5 lite (artifacts/tpu_r04/
-    # kernel_sweep.json, resident_probe.json, int8_crossover.jsonl):
-    # there is no sharp width crossover — uniform-width chains land
-    # within ~0.9-1.5x either way — but the one decisive signal is the
-    # flagship-like shape (784-128-64-10: jnp 1.9x faster; its 64/10
-    # interior dims sit below the 128-lane MXU tile). The final
-    # layer's output dim (a classifier head) measured irrelevant:
-    # 1024-1024-1024-10 still favors the kernel (1.017x). So the gate
-    # routes to jnp only when an INTERIOR dim (any input dim, or any
-    # output dim except the last layer's) is sub-tile.
+    # Not measured on today's code. The one round-4 run on a v5e found
+    # no sharp width crossover — uniform-width chains landed close
+    # either way — and one decisive signal: the flagship-like shape
+    # (784-128-64-10), whose 64/10 interior dims sit below the 128-lane
+    # MXU tile, ran faster through jnp. The final layer's output dim (a
+    # classifier head) did not matter. So the gate routes to jnp only
+    # when an INTERIOR dim (any input dim, or any output dim except the
+    # last layer's) is sub-tile.
     if prefer_kernel is None:
         interior = [p["wq"].shape[0] for p in qparams]
         interior += [p["wq"].shape[1] for p in qparams[:-1]]

@@ -109,6 +109,11 @@ def get_library() -> ctypes.CDLL | None:
         if _attempted and mode != "require":
             return None
         _attempted = True
+        # Imported here: the obs package is not worth loading for a
+        # process that only ever parses a model file.
+        from tpu_dist_nn.obs.log import get_logger
+
+        slog = get_logger(__name__)
         try:
             missing = [s for s in _SRCS if not s.exists()]
             stale = not _LIB.exists() or any(
@@ -122,8 +127,12 @@ def get_library() -> ctypes.CDLL | None:
                     )
                 _build()
             _lib = _bind(ctypes.CDLL(str(_LIB)))
+            slog.info("native.codec", loaded=True, built=stale)
             return _lib
-        except Exception:
+        except Exception as e:
             if mode == "require":
                 raise
+            # Said once per process, so a missing compiler is seen.
+            slog.warning("native.codec", loaded=False,
+                         error=f"{type(e).__name__}: {e}"[:300])
             return None

@@ -31,8 +31,8 @@ is the accounting half:
   jax-independent host-BLAS anchor) moved here FROM bench.py, and
   bench.py now imports them back — offline ``mfu`` and the runtime
   ``tdn_mfu_ratio`` resolve their peak through the same code, so the
-  two can never use divergent peaks. Off-accelerator the measured host
-  anchor is the peak (an honest CPU-fallback MFU instead of null).
+  two can never use divergent peaks. A CPU process uses the measured
+  host anchor; an accelerator the table does not know is an error.
 
 Exports (docs/OBSERVABILITY.md "Goodput & MFU"):
 
@@ -90,9 +90,8 @@ def host_calibration_gflops(reps: int = 5) -> float:
     jax-independent, so it measures the BOX, not the framework. Records
     in bench JSON so cross-round deltas can separate machine drift from
     code drift (docs/PERF.md "Cross-round drift"), and doubles as the
-    measured peak for CPU-fallback MFU: off-accelerator the best this
-    host can do at a dense matmul IS the denominator utilization should
-    be judged against.
+    utilization denominator of a process that runs on the host CPU: the
+    best this host can do at a dense matmul.
     """
     import numpy as np
 
@@ -112,8 +111,8 @@ _HOST_PEAK_LOCK = threading.Lock()
 
 
 def measured_host_peak_flops() -> float:
-    """One-shot cached host-BLAS peak in FLOP/s (the CPU-fallback MFU
-    denominator). Measured at configure time, never on a sampler tick."""
+    """One-shot cached host-BLAS peak in FLOP/s (the denominator of a
+    CPU process). Measured at configure time, never on a sampler tick."""
     with _HOST_PEAK_LOCK:
         if not _HOST_PEAK_CACHE:
             _HOST_PEAK_CACHE.append(host_calibration_gflops() * 1e9)
@@ -121,14 +120,23 @@ def measured_host_peak_flops() -> float:
 
 
 def resolve_peak(device_kind: str | None = None) -> tuple[float, str]:
-    """``(peak_flops, source)``: the table entry for ``device_kind``
-    when it names a known accelerator, else the measured host anchor.
-    ``source`` records which, so an artifact diff can tell a real MFU
-    change from a peak-resolution change."""
+    """``(peak_flops, source)`` for one device.
+
+    ``None`` means the host CPU and resolves to the measured host
+    anchor. An accelerator kind resolves through :data:`PEAK_FLOPS`; one
+    the table does not know is an error, never a host figure — an MFU
+    against the wrong denominator looks like a number. ``source``
+    records which, so an artifact diff can tell a real MFU change from
+    a peak-resolution change."""
+    if device_kind is None:
+        return measured_host_peak_flops(), "measured-host-blas"
     peak = device_peak_flops(device_kind)
-    if peak is not None:
-        return peak, f"table:{device_kind}"
-    return measured_host_peak_flops(), "measured-host-blas"
+    if peak is None:
+        raise ValueError(
+            f"no peak FLOP/s entry for accelerator kind {device_kind!r}: "
+            "add it to obs/goodput.PEAK_FLOPS with its source"
+        )
+    return peak, f"table:{device_kind}"
 
 
 # ---------------------------------------------------------------- models
@@ -278,8 +286,8 @@ class GoodputTracker:
             "tdn_mfu_ratio",
             "useful model FLOPs per second over the last sampler "
             "window, divided by the resolved hardware peak (table for "
-            "a known accelerator, measured host-BLAS anchor on the "
-            "CPU fallback); 0 while idle",
+            "an accelerator, measured host-BLAS anchor for a CPU "
+            "process); 0 while idle",
         )
         self._g_pad = reg.gauge(
             "tdn_pad_ratio",
@@ -302,35 +310,31 @@ class GoodputTracker:
         """Resolve the peak: the table entry for the active accelerator
         times the DEVICE COUNT the workload launches over (the ledger
         records whole multi-device launches, so a one-chip denominator
-        would overstate MFU by the shard count), else the measured host
-        anchor (the CPU fallback's virtual devices are slices of one
-        box — no multiplier). Callers pass their placement's count
-        (``Engine`` its mesh size); probing defaults to every visible
-        accelerator device. The LARGEST peak configured so far wins —
-        MFU is conservative, never overstated by a smaller later
-        placement. Called at CONFIGURE time (engine/scheduler
-        construction) — the host measurement is a real matmul and must
-        never ride a tick."""
+        would overstate MFU by the shard count), or the measured host
+        anchor when the process runs on the CPU (its virtual devices
+        are slices of one box — no multiplier). An accelerator missing
+        from the table raises (:func:`resolve_peak`). Callers pass
+        their placement's count (``Engine`` its mesh size); probing
+        defaults to every visible accelerator device. The LARGEST peak
+        configured so far wins — MFU is conservative, never overstated
+        by a smaller later placement. Called at CONFIGURE time
+        (engine/scheduler construction) — the host measurement is a
+        real matmul and must never ride a tick."""
         kind = device_kind
         if kind is None:
-            try:
-                import jax
+            import jax
 
-                devs = jax.devices()
-                if devs and devs[0].platform != "cpu":
-                    kind = devs[0].device_kind
-                    if device_count is None:
-                        device_count = len(devs)
-            except Exception:  # noqa: BLE001 — no backend: host anchor
-                kind = None
-        per_device = device_peak_flops(kind)
-        if per_device is not None:
+            devs = jax.devices()
+            if devs[0].platform != "cpu":
+                kind = devs[0].device_kind
+                if device_count is None:
+                    device_count = len(devs)
+        peak, source = resolve_peak(kind)
+        if kind is not None:
             n = max(int(device_count or 1), 1)
-            peak = per_device * n
-            source = f"table:{kind}" + (f" x{n}" if n > 1 else "")
-        else:
-            peak = measured_host_peak_flops()
-            source = "measured-host-blas"
+            peak *= n
+            if n > 1:
+                source += f" x{n}"
         with self._lock:
             if self._peak is not None and peak <= self._peak:
                 return self._peak

@@ -3,7 +3,7 @@
 Every metric the registry serves is a point-in-time scrape — "what is
 pending_rows NOW" — with no history unless an external Prometheus is
 running, which on the boxes this framework actually runs on (CI
-containers, tunneled TPU hosts) it never is. This module is the
+containers, sealed TPU hosts) it never is. This module is the
 embedded alternative: a bounded in-memory ring the
 :class:`~tpu_dist_nn.obs.runtime.RuntimeSampler` tick snapshots
 selected metric families into, at a configurable resolution and

@@ -153,7 +153,7 @@ def make_interleaved_1f1b(
     def device_fn(xs, chunk_params, chunk_static, tail_params, aux):
         def mark_varying(z, axes):
             # Idempotent "mark varying over `axes`" (one_f_one_b.py).
-            have = getattr(jax.typeof(z), "vma", frozenset())
+            have = jax.typeof(z).vma
             need = tuple(a for a in axes if a not in have)
             return lax.pcast(z, need, to="varying") if need else z
 
@@ -166,7 +166,7 @@ def make_interleaved_1f1b(
         # one_f_one_b.py's note).
         sp0 = jax.tree.map(lambda a: a[0], chunk_params)
         sp_shard_axes = jax.tree.map(
-            lambda a: getattr(jax.typeof(a), "vma", frozenset()), sp0
+            lambda a: jax.typeof(a).vma, sp0
         )
         sp = jax.tree.map(lambda a: mark_varying(a, data_like), sp0)
         st = jax.tree.map(lambda a: a[0], chunk_static)
@@ -185,7 +185,7 @@ def make_interleaved_1f1b(
             # lax.switch branch-type check at the first bwd tick.
             return mark_varying(
                 jnp.zeros(ref.shape, ref.dtype),
-                getattr(jax.typeof(ref), "vma", frozenset()),
+                jax.typeof(ref).vma,
             )
 
         tp = jax.tree.map(lambda a: vcast(jnp.asarray(a)), tail_params)
@@ -606,7 +606,7 @@ def make_interleaved_forward(
         dt = xs.dtype
 
         def vcast(z):
-            have = getattr(jax.typeof(z), "vma", frozenset())
+            have = jax.typeof(z).vma
             need = tuple(a for a in vary if a not in have)
             return lax.pcast(z, need, to="varying") if need else z
 

@@ -34,6 +34,21 @@ class HostTopology:
         return self.num_processes > 1
 
 
+def multihost_environment() -> bool:
+    """Whether the environment describes a job of several processes: a
+    coordinator address, or a TPU slice whose worker list names more
+    than one host.
+
+    ``TPU_WORKER_ID`` alone does not: every TPU VM sets it, a one-host
+    one too, and joining a job there makes JAX ask the cloud metadata
+    server for its peers — which fails on a host with no network, for a
+    job that has none."""
+    if "COORDINATOR_ADDRESS" in os.environ:
+        return True
+    hosts = os.environ.get("TPU_WORKER_HOSTNAMES", "")
+    return sum(bool(h.strip()) for h in hosts.split(",")) > 1
+
+
 def initialize_multihost(
     coordinator_address: str | None = None,
     num_processes: int | None = None,
@@ -48,10 +63,7 @@ def initialize_multihost(
     process into the job before any backend use.
     """
     explicit = coordinator_address is not None
-    auto_env = any(
-        v in os.environ
-        for v in ("COORDINATOR_ADDRESS", "CLOUD_TPU_TASK_ID", "TPU_WORKER_ID")
-    )
+    auto_env = multihost_environment()
     # NB: nothing before this point may touch the backend (even
     # jax.process_count() initializes it, which would make
     # jax.distributed.initialize fail with "must be called before any

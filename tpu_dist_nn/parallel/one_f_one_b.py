@@ -185,7 +185,7 @@ def make_1f1b(
             # Idempotent "mark varying over `axes`": zeros_like of an
             # already-varying tracer is itself varying, and pcast
             # rejects re-adding axes.
-            have = getattr(jax.typeof(z), "vma", frozenset())
+            have = jax.typeof(z).vma
             need = tuple(a for a in axes if a not in have)
             return lax.pcast(z, need, to="varying") if need else z
 
@@ -205,7 +205,7 @@ def make_1f1b(
         # slice), so remember every leaf's own pre-mark sharding.
         sp0 = jax.tree.map(lambda a: a[0], stage_params)
         sp_shard_axes = jax.tree.map(
-            lambda a: getattr(jax.typeof(a), "vma", frozenset()), sp0
+            lambda a: jax.typeof(a).vma, sp0
         )
         sp = jax.tree.map(lambda a: mark_varying(a, data_like), sp0)
         st = jax.tree.map(lambda a: a[0], stage_static)
@@ -225,7 +225,7 @@ def make_1f1b(
             # first add.
             return mark_varying(
                 jnp.zeros(ref.shape, ref.dtype),
-                getattr(jax.typeof(ref), "vma", frozenset()),
+                jax.typeof(ref).vma,
             )
 
         zeros_wire = vcast(jnp.zeros(mb_shape, dt))

@@ -126,7 +126,7 @@ def make_pipeline_generate(mesh, cfg: TransformerConfig, num_stages: int,
             # Scan carries become (stage, data)-varying after the first
             # tick (ppermute + stage-predicated selects); mark the
             # initial values to match (idempotent — one_f_one_b.py).
-            have = getattr(jax.typeof(z), "vma", frozenset())
+            have = jax.typeof(z).vma
             need = tuple(a for a in vary if a not in have)
             return lax.pcast(z, need, to="varying") if need else z
 
@@ -308,7 +308,7 @@ def make_pipeline_generate_overlapped(mesh, cfg: TransformerConfig,
         vary = (AXIS_STAGE, *data_axes)
 
         def vcast(z):
-            have = getattr(jax.typeof(z), "vma", frozenset())
+            have = jax.typeof(z).vma
             need = tuple(a for a in vary if a not in have)
             return lax.pcast(z, need, to="varying") if need else z
 

@@ -383,6 +383,8 @@ class ReplicaPool:
         # choreography itself is an incident trigger without the
         # detector having to diff per-replica states.
         self.transitions_total = 0  # guarded-by: _lock
+        # spawn_local calls still waiting for their child's ports.
+        self._booting = 0  # guarded-by: _lock
         # Integrity plane (serving/integrity.py). canary: a
         # CanaryProber ridden on the scrape loop (None = probing off).
         # on_quarantine(target, reason, evidence): the incident hook —
@@ -1191,38 +1193,62 @@ class ReplicaPool:
 
     def spawn_local(self, config: str, *, grpc_port: int = 0,
                     metrics_port: int = 0, extra_args=(),
-                    startup_timeout: float = 180.0) -> Replica:
+                    startup_timeout: float = 180.0,
+                    platform: str = "auto") -> Replica:
         """Spawn one local engine replica (``tdn up --grpc-port``) as a
         subprocess and add it to the pool. Ports default to ephemeral;
         the child prints its bound ports as JSON lines (the CLI's
         port-in-stdout convention) and this blocks until both appear.
+
+        ``platform`` is the child's ``tdn --platform``. A child that
+        reports an accelerator must be the only one this pool runs: a
+        chip belongs to one process at a time and nothing here assigns
+        chips to children, so a second child would open the chips the
+        first one owns. That spawn is refused, loudly.
         """
         if self._stop.is_set():
             raise RuntimeError("pool is closed; refusing to spawn a replica")
-        argv = [
-            sys.executable, "-m", "tpu_dist_nn.cli", "up",
-            "--config", config,
-            "--grpc-port", str(grpc_port),
-            "--metrics-port", str(metrics_port),
-            *extra_args,
+        head = [
+            sys.executable, "-m", "tpu_dist_nn.cli", "--platform", platform,
+            "up", "--config", config,
         ]
-        proc = subprocess.Popen(
-            argv, stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
-            text=True,
-        )
-        ports = _read_child_ports(proc, startup_timeout)
-        target = f"127.0.0.1:{ports['grpc_port']}"
-        rep = self.add(target, f"127.0.0.1:{ports['metrics_port']}")
+        with self._lock:
+            self._booting += 1
+        try:
+            proc = subprocess.Popen(
+                [*head, "--grpc-port", str(grpc_port),
+                 "--metrics-port", str(metrics_port), *extra_args],
+                stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
+                text=True,
+            )
+            try:
+                info = _read_child_ports(proc, startup_timeout)
+            except RuntimeError as e:
+                # The usual way a second accelerator child ends: the
+                # chips were taken and it died before reporting.
+                if platform != "cpu" and self._spawned_siblings():
+                    raise RuntimeError(f"{e} — {_UNASSIGNED_CHIPS}") from e
+                raise
+            siblings = self._spawned_siblings()
+            if info["platform"] != "cpu" and siblings:
+                _terminate_child(proc)
+                raise RuntimeError(
+                    f"spawned replica runs on {info['platform']!r} next "
+                    f"to {siblings} other spawned replica(s): "
+                    f"{_UNASSIGNED_CHIPS}"
+                )
+        finally:
+            with self._lock:
+                self._booting -= 1
+        target = f"127.0.0.1:{info['grpc_port']}"
+        rep = self.add(target, f"127.0.0.1:{info['metrics_port']}")
         with self._lock:
             rep.proc = proc
             # Remember the exact argv WITH the now-known ports so a
             # rolling restart respawns on the same (reused) addresses.
             rep.spawn_argv = [
-                sys.executable, "-m", "tpu_dist_nn.cli", "up",
-                "--config", config,
-                "--grpc-port", str(ports["grpc_port"]),
-                "--metrics-port", str(ports["metrics_port"]),
-                *extra_args,
+                *head, "--grpc-port", str(info["grpc_port"]),
+                "--metrics-port", str(info["metrics_port"]), *extra_args,
             ]
             closing = self._stop.is_set()
         if closing:
@@ -1233,6 +1259,14 @@ class ReplicaPool:
             self.remove(target)
             raise RuntimeError("pool closed during spawn_local")
         return rep
+
+    def _spawned_siblings(self) -> int:
+        """Other replicas this pool spawned, booting or live, as seen
+        from inside one ``spawn_local`` call."""
+        with self._lock:
+            return self._booting - 1 + sum(
+                r.proc is not None for r in self._replicas.values()
+            )
 
     def restart_replica(self, target: str, *, grace: float = 30.0,
                         startup_timeout: float = 180.0) -> bool:
@@ -1303,6 +1337,14 @@ def _retire_replica_series(target: str) -> None:
         requests.remove_matching(replica=target)
 
 
+_UNASSIGNED_CHIPS = (
+    "a chip belongs to one process at a time and spawned replicas are "
+    "not assigned chips, so each would open every chip of the host. "
+    "Spawn with --platform cpu, or start one pinned replica per chip "
+    "yourself and list them with --replicas"
+)
+
+
 def _terminate_child(proc) -> None:
     """Best-effort SIGTERM (the child's own GracefulDrain) → bounded
     wait → SIGKILL. Duck-typed: tests park fakes on ``rep.proc``."""
@@ -1318,11 +1360,13 @@ def _terminate_child(proc) -> None:
 
 
 def _read_child_ports(proc: subprocess.Popen,
-                      timeout: float) -> dict[str, int]:
+                      timeout: float) -> dict:
     """Read a spawned replica's JSON stdout lines until both its
     metrics and gRPC ports are known (a reader thread bounds the wait —
-    a wedged child must raise, not hang the router bring-up)."""
-    ports: dict[str, int] = {}
+    a wedged child must raise, not hang the router bring-up). Returns
+    ``{"metrics_port", "grpc_port", "platform"}`` — the platform from
+    the child's ``ready`` line, which precedes its port lines."""
+    info: dict = {}
     done = threading.Event()
     err: list[str] = []
 
@@ -1336,10 +1380,12 @@ def _read_child_ports(proc: subprocess.Popen,
                     doc = json.loads(line)
                 except ValueError:
                     continue
+                if "device" in doc:
+                    info["platform"] = doc["device"]["platform"]
                 for key in ("metrics_port", "grpc_port"):
                     if key in doc:
-                        ports[key] = int(doc[key])
-                if "metrics_port" in ports and "grpc_port" in ports:
+                        info[key] = int(doc[key])
+                if "metrics_port" in info and "grpc_port" in info:
                     done.set()
                     return
             err.append("child exited before printing its ports")
@@ -1349,10 +1395,10 @@ def _read_child_ports(proc: subprocess.Popen,
             done.set()
 
     threading.Thread(target=reader, daemon=True).start()
-    if not done.wait(timeout) or "grpc_port" not in ports:
+    if not done.wait(timeout) or "grpc_port" not in info:
         _terminate_child(proc)
         raise RuntimeError(
             "spawned replica did not report its ports within "
             f"{timeout}s" + (f": {err[0]}" if err else "")
         )
-    return ports
+    return info
