@@ -112,6 +112,14 @@ QUICK_TESTS = {
         "test_bench_gate_incident_ratio_skip_and_fail"],
     "test_forward_parity": ["test_forward_matches_oracle_small",
                             "test_softmax_stability"],
+    "test_gen_loop_clock": [
+        # ISSUE 24: the loop's phases partition its wall time, the
+        # iteration ring is bounded, a request's decode span says what
+        # it rode.
+        "test_phase_totals_sum_to_the_loops_wall_time",
+        "test_ring_is_bounded_and_keeps_the_newest",
+        "test_decode_span_says_what_the_request_rode_and_no_step_spans",
+    ],
     "test_generate": ["test_greedy_generation_matches_teacher_forced_oracle",
                       "test_pipeline_generate_matches_single_chip",
                       "test_tp_generate_greedy_matches_single_chip"],
@@ -290,6 +298,8 @@ QUICK_TESTS = {
     "test_trace": ["test_chrome_trace_export_schema",
                    "test_loopback_round_trip_is_one_trace_tree",
                    "test_sampling_rate_edge_cases"],
+    "test_trace_gaps": ["test_gaps_are_put_down_to_the_phases_that_cover_them",
+                        "test_device_seconds_by_scope_from_event_metadata"],
     "test_train": ["test_single_chip_training_learns",
                    "test_train_lm_does_not_invalidate_caller_params"],
     "test_transformer": ["test_loss_descends_on_copy_task",

@@ -253,7 +253,10 @@ def test_traced_request_records_prefill_and_decode_spans():
         s.name for s in TRACER.snapshot()
         if s.trace_id == span.ctx.trace_id
     }
-    assert {"queue_wait", "prefill", "decode.step", "decode"} <= names
+    assert {"queue_wait", "prefill", "decode"} <= names
+    # One span a request, none a step: the launches it rode are
+    # attributes of its `decode` span.
+    assert "decode.step" not in names
 
 
 # ------------------------------------------------------------ admission
@@ -604,8 +607,7 @@ def test_prefill_chunk_spans_recorded_and_profiled():
         s for s in TRACER.snapshot() if s.trace_id == span.ctx.trace_id
     ]
     names = {s.name for s in mine}
-    assert {"queue_wait", "prefill", "prefill.chunk", "decode.step",
-            "decode"} <= names
+    assert {"queue_wait", "prefill", "prefill.chunk", "decode"} <= names
     # ceil(8 / 3) chunks, each its own span, joined to the request trace.
     assert sum(1 for s in mine if s.name == "prefill.chunk") == 3
     # The /profile stage table picks the new span up as a stage.

@@ -1,0 +1,394 @@
+"""The generation scheduler's loop on the record (ISSUE 24): phase
+accounting that partitions the loop thread's wall time, the bounded
+process-global ring of iteration records, queue wait / prefill wait /
+stream-out lag as cumulative counters, the `decode` span's ride
+attributes in place of per-step `decode.step` spans, the
+`tdn_gen_loop_*` families, and the `named_scope`s of the step programs.
+
+Driven with stub kernels on the CPU: what is asserted is where time is
+booked, never how much a device would take.
+"""
+
+import re
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from tpu_dist_nn.obs.registry import Registry
+from tpu_dist_nn.obs.runtime import RuntimeSampler
+from tpu_dist_nn.obs.trace import (
+    ITER_FIELDS,
+    ITERATIONS,
+    LOOP_HOST_PHASES,
+    LOOP_PHASES,
+    LOOP_WAIT_PHASES,
+    TRACER,
+    IterationRing,
+)
+from tpu_dist_nn.serving.continuous import ContinuousScheduler
+
+T, N = 8, 10
+CUMULATIVE = ITER_FIELDS[ITER_FIELDS.index("active_slots") + 1:]
+
+
+def _fake_sched(step_cost=0.0, **kw):
+    def fake_prefill(params, cache, slot, tokens, start, key):
+        return np.int32(1), cache
+
+    def fake_step(params, cache, pos, active, tok, key):
+        if step_cost:
+            time.sleep(step_cost)
+        return np.asarray(tok) + 1, cache
+
+    kw.setdefault("slots", 2)
+    kw.setdefault("prompt_len", T)
+    kw.setdefault("max_new_tokens", N)
+    return ContinuousScheduler(
+        None, None, prefill_fn=fake_prefill, step_fn=fake_step, **kw
+    )
+
+
+def _prompts(n, seed=0):
+    return np.random.default_rng(seed).integers(0, 64, (n, T))
+
+
+def _mine(sched):
+    """This scheduler's iteration records in the process-global ring."""
+    me = sched.loop_totals()["sched"]
+    return [dict(zip(ITER_FIELDS, r)) for r in ITERATIONS.snapshot()
+            if r[ITER_FIELDS.index("sched")] == me]
+
+
+# ------------------------------------------------------------ phases
+
+
+def test_phase_names_partition_into_host_and_wait():
+    assert set(LOOP_HOST_PHASES) | set(LOOP_WAIT_PHASES) == set(LOOP_PHASES)
+    assert not set(LOOP_HOST_PHASES) & set(LOOP_WAIT_PHASES)
+    assert LOOP_WAIT_PHASES == ("idle", "prefill.fetch", "step.fetch")
+    assert set(LOOP_PHASES) < set(ITER_FIELDS)
+
+
+def test_phase_totals_sum_to_the_loops_wall_time():
+    t0 = time.monotonic()
+    sched = _fake_sched(step_cost=0.004, slots=2)
+    sched.submit(_prompts(5))
+    time.sleep(0.05)  # some idle belongs to the partition too
+    sched.submit(_prompts(2, seed=1))
+    sched.close()
+    wall = time.monotonic() - t0
+    totals = sched.loop_totals()
+    booked = sum(totals[p] for p in LOOP_PHASES) / 1e9
+    assert booked == pytest.approx(wall, rel=0.02)
+    assert totals["idle"] / 1e9 >= 0.04
+    # The stub step sleeps inside the call into it: that is dispatch.
+    assert totals["step.dispatch"] / 1e9 >= 0.004 * totals["seq"] * 0.5
+    # The loop thread's CPU time is its host phases' (it sleeps in its
+    # waits; the stub step's sleep is inside dispatch): never more than
+    # their wall time, the clocks' granularity apart.
+    host = sum(totals[p] for p in LOOP_HOST_PHASES)
+    assert 0 < totals["cpu_ns"] <= host * 1.05
+
+
+@pytest.fixture(scope="module")
+def sampled_run():
+    """Totals read from another thread while a scheduler works, then
+    its records: [loop_totals() ...], [record ...]."""
+    sched = _fake_sched(step_cost=0.002, slots=2)
+    samples = []
+    stop = threading.Event()
+
+    def sample():
+        while not stop.is_set():
+            samples.append(sched.loop_totals())
+            time.sleep(0.001)
+
+    th = threading.Thread(target=sample)
+    th.start()
+    try:
+        stream = sched.submit_stream(_prompts(1, seed=3))
+        sched.submit(_prompts(4))
+        while stream.next_event(5.0)[0] != "end":
+            pass
+        sched.submit(_prompts(1, seed=4))
+    finally:
+        stop.set()
+        th.join(10)
+        sched.close()
+    assert not th.is_alive() and len(samples) > 20
+    samples.append(sched.loop_totals())
+    return samples, _mine(sched)
+
+
+@pytest.mark.parametrize("field", CUMULATIVE)
+def test_every_total_is_monotone(sampled_run, field):
+    samples, records = sampled_run
+    for series in (samples, records):
+        values = [s[field] for s in series]
+        assert all(b >= a for a, b in zip(values, values[1:])), field
+    if field not in ("stream_lag_ns", "stream_frames"):
+        assert samples[-1][field] > 0, field
+
+
+def test_records_carry_sequence_time_and_a_window_is_last_minus_first(
+        sampled_run):
+    _, records = sampled_run
+    assert [r["seq"] for r in records] == list(
+        range(records[0]["seq"], records[0]["seq"] + len(records)))
+    ends = [r["t_end"] for r in records]
+    assert ends == sorted(ends)
+    assert any(r["prefilled"] for r in records)
+    assert max(r["active_slots"] for r in records) == 2
+    first, last = records[0], records[-1]
+    # Phases partition the time between two records exactly.
+    booked = sum(last[p] - first[p] for p in LOOP_PHASES) / 1e9
+    assert booked == pytest.approx(last["t_end"] - first["t_end"], rel=1e-6)
+    inside = ITERATIONS.window(first["t_end"], last["t_end"])
+    assert len([r for r in inside if r[0] == first["sched"]]) \
+        == len(records) - 1
+
+
+# -------------------------------------------------------------- ring
+
+
+def test_ring_is_bounded_and_keeps_the_newest():
+    ring = IterationRing(capacity=8)
+    for i in range(20):
+        ring.append((1, i, float(i)) + (0,) * (len(ITER_FIELDS) - 3))
+    assert len(ring) == ring.capacity == 8
+    assert [r[1] for r in ring.snapshot()] == list(range(12, 20))
+    assert [r[1] for r in ring.window(14.0, 16.0)] == [14, 15]
+    assert 1000 <= ITERATIONS.capacity <= 10000
+
+
+def test_ring_survives_close_and_tells_schedulers_apart():
+    a, b = _fake_sched(), _fake_sched()
+    a.submit(_prompts(1))
+    b.submit(_prompts(2))
+    a.close()
+    b.close()
+    del a.__dict__["_thread"], b.__dict__["_thread"]
+    ra, rb = _mine(a), _mine(b)
+    assert ra and rb and ra[0]["sched"] != rb[0]["sched"]
+    assert ra[-1]["binds"] == 1 and rb[-1]["binds"] == 2
+
+
+# ------------------------------------------------- waits and stream lag
+
+
+def test_queue_wait_counts_a_request_held_back_by_a_full_slot_set():
+    sched = _fake_sched(step_cost=0.02, slots=1)
+    done = []
+
+    def call():
+        t0 = time.monotonic()
+        sched.submit(_prompts(1))
+        done.append(time.monotonic() - t0)
+
+    first = threading.Thread(target=call)
+    first.start()
+    while sched.loop_totals()["binds"] < 1:
+        time.sleep(0.001)
+    t_second = time.monotonic()
+    sched.submit(_prompts(1, seed=1))
+    second_total = time.monotonic() - t_second
+    first.join(10)
+    sched.close()
+    totals = sched.loop_totals()
+    assert totals["binds"] == 2 and totals["first_tokens"] == 2
+    waited = totals["queue_wait_ns"] / 1e9
+    # The second request stood in the queue while the first decoded its
+    # nine steps of 20 ms; the first hardly waited at all.
+    assert 0.1 < waited < second_total
+    assert totals["prefill_wait_ns"] / 1e9 < 0.05
+
+
+def test_sleeping_fetch_hook_lands_in_step_fetch():
+    sched = _fake_sched(slots=2)
+    sched.fetch_hook = lambda toks: time.sleep(0.005)
+    sched.submit(_prompts(2))
+    sched.close()
+    totals = sched.loop_totals()
+    steps = sched.steps_total
+    assert steps >= N - 1
+    assert totals["step.fetch"] / 1e9 >= 0.005 * steps
+    others = sum(totals[p] for p in LOOP_HOST_PHASES) / 1e9
+    assert others < totals["step.fetch"] / 1e9
+
+
+@pytest.mark.parametrize("consumer_sleep, lo_ms, hi_ms",
+                         [(0.0, 0.0, 5.0), (0.03, 10.0, 100.0)])
+def test_stream_consumer_delay_lands_in_stream_lag(consumer_sleep, lo_ms,
+                                                   hi_ms):
+    sched = _fake_sched(step_cost=0.01, slots=1, max_new_tokens=24)
+    stream = sched.submit_stream(_prompts(1))
+    got = []
+    while True:
+        kind, data = stream.next_event(10.0)
+        if kind == "end":
+            break
+        got += data
+        time.sleep(consumer_sleep)
+    sched.close()
+    totals = sched.loop_totals()
+    assert len(got) == 24
+    # Frames taken after the last publish are never folded in.
+    assert 0 < totals["stream_frames"] < 24
+    mean_ms = totals["stream_lag_ns"] / totals["stream_frames"] / 1e6
+    assert lo_ms <= mean_ms < hi_ms
+
+
+# -------------------------------------------------------------- spans
+
+
+def test_decode_span_says_what_the_request_rode_and_no_step_spans():
+    root = TRACER.start("rpc.Generate")
+    assert root.ctx.sampled
+    sched = _fake_sched(step_cost=0.002, slots=2)
+    sched.fetch_hook = lambda toks: time.sleep(0.001)
+    try:
+        sched.submit(_prompts(1), ctx=root.ctx)
+    finally:
+        root.end()
+        sched.close()
+    spans = [s for s in TRACER.snapshot()
+             if s.trace_id == root.ctx.trace_id]
+    names = [s.name for s in spans]
+    assert {"queue_wait", "prefill", "decode"} <= set(names)
+    assert "decode.step" not in names
+    decode = next(s for s in spans if s.name == "decode")
+    attrs = decode.attrs
+    assert attrs["tokens"] == N and attrs["steps"] == N - 1
+    assert attrs["step_fetch_s"] >= 0.001 * (N - 1)
+    assert 0 < attrs["host_s"] < decode.dur
+    # It joins the iteration records by sequence number: the iteration
+    # that prefilled it stepped it too.
+    assert attrs["iter_last"] - attrs["iter_first"] + 1 == attrs["steps"]
+    seqs = {r["seq"] for r in _mine(sched)}
+    assert set(range(attrs["iter_first"], attrs["iter_last"] + 1)) <= seqs
+
+
+def test_cancelled_stream_leaves_a_decode_span_too():
+    """The wire carries no budget, so most streams end by the client's
+    cancel; the loop's reap pass records their decode phase."""
+    root = TRACER.start("rpc.GenerateStream")
+    sched = _fake_sched(step_cost=0.002, slots=1, max_new_tokens=64)
+    try:
+        stream = sched.submit_stream(_prompts(1), ctx=root.ctx)
+        got = []
+        while len(got) < 5:
+            got += stream.next_event(5.0)[1]
+        stream.cancel()
+        deadline = time.monotonic() + 5
+        while sched.slots_active and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert sched.slots_active == 0
+    finally:
+        root.end()
+        sched.close()
+    decode = [s for s in TRACER.snapshot()
+              if s.trace_id == root.ctx.trace_id and s.name == "decode"]
+    assert len(decode) == 1
+    attrs = decode[0].attrs
+    assert attrs["reason"] == "cancelled" and attrs["tokens"] >= 5
+    assert attrs["steps"] >= 4 and attrs["iter_last"] > attrs["iter_first"]
+
+
+# ------------------------------------------------------------ /metrics
+
+
+@pytest.fixture(scope="module")
+def scraped():
+    reg = Registry()
+    sampler = RuntimeSampler(registry=reg)
+    sched = _fake_sched(step_cost=0.001, slots=2)
+    sampler.add_generation_scheduler(sched)
+    stream = sched.submit_stream(_prompts(1))
+    while stream.next_event(5.0)[0] != "end":
+        pass
+    sched.submit(_prompts(3))
+    sampler.sample_once()
+    once = {m.name: {k: c.value for k, c in m.samples()}
+            for m in reg.collect()}
+    sampler.sample_once()  # nothing ran since: counters must not move
+    twice = {m.name: {k: c.value for k, c in m.samples()}
+             for m in reg.collect()}
+    sched.close()
+    return once, twice, sched.loop_totals()
+
+
+@pytest.mark.parametrize("family, field", [
+    ("tdn_gen_loop_iterations_total", "seq"),
+    ("tdn_gen_loop_cpu_seconds_total", "cpu_ns"),
+    ("tdn_gen_queue_wait_seconds_total", "queue_wait_ns"),
+    ("tdn_gen_queue_wait_requests_total", "binds"),
+    ("tdn_gen_prefill_wait_seconds_total", "prefill_wait_ns"),
+    ("tdn_gen_prefill_wait_requests_total", "first_tokens"),
+    ("tdn_gen_stream_lag_seconds_total", "stream_lag_ns"),
+    ("tdn_gen_stream_lag_frames_total", "stream_frames"),
+])
+def test_loop_totals_are_scraped_as_counters(scraped, family, field):
+    once, twice, totals = scraped
+    value = once[family][()]
+    assert value > 0 and twice[family][()] == value
+    scale = 1e9 if field.endswith("_ns") else 1
+    assert value <= totals[field] / scale + 1e-9
+
+
+@pytest.mark.parametrize("phase", [p for p in LOOP_PHASES if p != "idle"])
+def test_loop_seconds_has_a_series_per_phase(scraped, phase):
+    once, twice, totals = scraped
+    series = once["tdn_gen_loop_seconds_total"]
+    assert 0 < series[(phase,)] <= totals[phase] / 1e9 + 1e-9
+    assert twice["tdn_gen_loop_seconds_total"][(phase,)] == series[(phase,)]
+
+
+# ------------------------------------------------------- named scopes
+
+
+@pytest.fixture(scope="module")
+def lowered_programs():
+    """The scheduler's own step and prefill programs as lowered, with
+    the names `jax.named_scope` gave their operations (the compiler
+    keeps them as `op_name` metadata, which a device capture shows per
+    operation). Lowered, not compiled: a compile may come from the
+    persistent cache, whose key leaves names out."""
+    import jax
+
+    from tpu_dist_nn.models.transformer import (
+        TransformerConfig,
+        init_transformer,
+    )
+
+    cfg = TransformerConfig(vocab_size=64, d_model=32, n_heads=4,
+                            n_layers=2, d_ff=64, max_seq_len=24)
+    sched = ContinuousScheduler(
+        init_transformer(jax.random.key(0), cfg), cfg, slots=2,
+        prompt_len=T, max_new_tokens=N)
+    try:
+        key = sched._next_key()
+        step = sched._step.lower(
+            sched._params, sched._cache, sched._pos, sched._active,
+            sched._tok, key).as_text(debug_info=True)
+        prefill = sched._prefill.lower(
+            sched._params, sched._cache, np.int32(0),
+            np.zeros((1, T), np.int32), np.int32(0), key,
+        ).as_text(debug_info=True)
+    finally:
+        sched.close()
+    return {"step": step, "prefill": prefill}
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+@pytest.mark.parametrize("scope", [
+    "embed", "kv.write", "attn.scores", "attn.softmax", "attn.values",
+    "ffn", "unembed", "sample"])
+def test_step_programs_name_their_scopes(lowered_programs, program, scope):
+    assert re.search(rf'[/"]{re.escape(scope)}[/"]',
+                     lowered_programs[program])
+
+
+def test_guard_scope_is_in_the_step_program(lowered_programs):
+    assert re.search(r'[/"]guard[/"]', lowered_programs["step"])

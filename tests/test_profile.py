@@ -248,7 +248,7 @@ def test_loopback_profile_generate_shares_sum_to_wall():
     doc = _profile_over_http()
     _assert_shares_match_walls(doc, "Generate")
     stages = {s["stage"] for s in doc["methods"]["Generate"]["stages"]}
-    assert {"queue_wait", "prefill", "decode", "decode.step",
+    assert {"queue_wait", "prefill", "prefill.chunk", "decode",
             HANDLER_STAGE} <= stages, stages
 
 

@@ -416,15 +416,17 @@ def prefill_chunk_into_cache(params: dict, cfg: TransformerConfig,
     final chunk's logits are sampled from (they are the prompt's
     last-position logits).
     """
-    params = cfg.cast_params(params)
+    with jax.named_scope("params.cast"):
+        params = cfg.cast_params(params)
     Lc, S, M, H, Dh = cache["k"].shape
     C = tokens.shape[1]
     D = cfg.d_model
     slot = jnp.asarray(slot, jnp.int32)
     start = jnp.asarray(start, jnp.int32)
-    x = params["tok_embed"][tokens] + lax.dynamic_slice(
-        params["pos_embed"], (start, 0), (C, D)
-    )[None]
+    with jax.named_scope("embed"):
+        x = params["tok_embed"][tokens] + lax.dynamic_slice(
+            params["pos_embed"], (start, 0), (C, D)
+        )[None]
     # Key position j is visible to chunk-local query i iff j <= start+i
     # (the causal mask, offset into the slot's timeline); everything
     # beyond the chunk's own frontier is future space.
@@ -445,28 +447,39 @@ def prefill_chunk_into_cache(params: dict, cfg: TransformerConfig,
         h = layer_norm(x, block["ln1_g"], block["ln1_b"])
         qkv = h @ block["w_qkv"] + block["b_qkv"]
         q, k, v = jnp.split(qkv.reshape(1, C, 3 * H, Dh), 3, axis=2)
-        k_cache = lax.dynamic_update_slice(
-            k_cache, k.astype(k_cache.dtype), (0, start, 0, 0)
-        )
-        v_cache = lax.dynamic_update_slice(
-            v_cache, v.astype(v_cache.dtype), (0, start, 0, 0)
-        )
-        scores = jnp.einsum(
-            "bqhd,bkhd->bhqk", q.astype(jnp.float32),
-            k_cache.astype(jnp.float32),
-        ) / np.sqrt(Dh)
-        scores = jnp.where(allowed[None, None, :, :], scores, -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        o = jnp.einsum("bhqk,bkhd->bqhd", probs, v_cache).reshape(1, C, H * Dh)
-        x = x + o @ block["w_o"] + block["b_o"]
-        return ffn_sublayer(block, x), (k_cache, v_cache)
+        with jax.named_scope("kv.write"):
+            k_cache = lax.dynamic_update_slice(
+                k_cache, k.astype(k_cache.dtype), (0, start, 0, 0)
+            )
+            v_cache = lax.dynamic_update_slice(
+                v_cache, v.astype(v_cache.dtype), (0, start, 0, 0)
+            )
+        with jax.named_scope("attn.scores"):
+            scores = jnp.einsum(
+                "bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                k_cache.astype(jnp.float32),
+            ) / np.sqrt(Dh)
+        with jax.named_scope("attn.softmax"):
+            scores = jnp.where(allowed[None, None, :, :], scores, -jnp.inf)
+            probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+        with jax.named_scope("attn.values"):
+            o = jnp.einsum(
+                "bhqk,bkhd->bqhd", probs, v_cache
+            ).reshape(1, C, H * Dh)
+            x = x + o @ block["w_o"] + block["b_o"]
+        with jax.named_scope("ffn"):
+            x = ffn_sublayer(block, x)
+        return x, (k_cache, v_cache)
 
     x, (ks, vs) = lax.scan(body, x, (params["blocks"], k_rows, v_rows))
-    cache = {
-        "k": lax.dynamic_update_slice(cache["k"], ks, (0, slot, 0, 0, 0)),
-        "v": lax.dynamic_update_slice(cache["v"], vs, (0, slot, 0, 0, 0)),
-    }
-    return unembed(params, x)[:, C - 1], cache
+    with jax.named_scope("kv.write"):
+        cache = {
+            "k": lax.dynamic_update_slice(cache["k"], ks, (0, slot, 0, 0, 0)),
+            "v": lax.dynamic_update_slice(cache["v"], vs, (0, slot, 0, 0, 0)),
+        }
+    with jax.named_scope("unembed"):
+        logits = unembed(params, x)[:, C - 1]
+    return logits, cache
 
 
 def decode_blocks_slots(blocks: dict, cache: dict, pos: jnp.ndarray,
@@ -499,17 +512,25 @@ def decode_blocks_slots(blocks: dict, cache: dict, pos: jnp.ndarray,
         h = layer_norm(x, block["ln1_g"], block["ln1_b"])
         qkv = h @ block["w_qkv"] + block["b_qkv"]
         q, k, v = jnp.split(qkv.reshape(S, 1, 3 * H, Dh), 3, axis=2)
-        k_cache = jnp.where(write, k.astype(k_cache.dtype), k_cache)
-        v_cache = jnp.where(write, v.astype(v_cache.dtype), v_cache)
-        scores = jnp.einsum(
-            "bqhd,bkhd->bhqk", q.astype(jnp.float32),
-            k_cache.astype(jnp.float32),
-        ) / np.sqrt(Dh)
-        scores = jnp.where(live[:, None, None, :], scores, -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        o = jnp.einsum("bhqk,bkhd->bqhd", probs, v_cache).reshape(S, 1, H * Dh)
-        x = x + o @ block["w_o"] + block["b_o"]
-        return ffn_sublayer(block, x), (k_cache, v_cache)
+        with jax.named_scope("kv.write"):
+            k_cache = jnp.where(write, k.astype(k_cache.dtype), k_cache)
+            v_cache = jnp.where(write, v.astype(v_cache.dtype), v_cache)
+        with jax.named_scope("attn.scores"):
+            scores = jnp.einsum(
+                "bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                k_cache.astype(jnp.float32),
+            ) / np.sqrt(Dh)
+        with jax.named_scope("attn.softmax"):
+            scores = jnp.where(live[:, None, None, :], scores, -jnp.inf)
+            probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+        with jax.named_scope("attn.values"):
+            o = jnp.einsum(
+                "bhqk,bkhd->bqhd", probs, v_cache
+            ).reshape(S, 1, H * Dh)
+            x = x + o @ block["w_o"] + block["b_o"]
+        with jax.named_scope("ffn"):
+            x = ffn_sublayer(block, x)
+        return x, (k_cache, v_cache)
 
     x, (ks, vs) = lax.scan(body, x, (blocks, cache["k"], cache["v"]))
     return x, {"k": ks, "v": vs}
@@ -530,16 +551,20 @@ def decode_step_slots(params: dict, cache: dict, pos: jnp.ndarray,
 
     Returns ``(logits (S, V), cache)``.
     """
-    params = cfg.cast_params(params)
+    with jax.named_scope("params.cast"):
+        params = cfg.cast_params(params)
     if active is None:
         active = jnp.ones(token.shape, bool)
     pos = jnp.asarray(pos, jnp.int32)
     # Clip so a retired slot's stale position can never over-index the
     # positional table (its logits are masked out by `active` anyway).
     safe = jnp.clip(pos, 0, params["pos_embed"].shape[0] - 1)
-    x = params["tok_embed"][token][:, None, :] \
-        + params["pos_embed"][safe][:, None, :]
+    with jax.named_scope("embed"):
+        x = params["tok_embed"][token][:, None, :] \
+            + params["pos_embed"][safe][:, None, :]
     x, cache = decode_blocks_slots(
         params["blocks"], cache, safe, x, cfg, active
     )
-    return unembed(params, x)[:, 0], cache
+    with jax.named_scope("unembed"):
+        logits = unembed(params, x)[:, 0]
+    return logits, cache

@@ -13,8 +13,8 @@ completed spans into a rolling per-stage profile:
   double-counting every level of the tree.
 * **Innermost-cover sweep, not parent links.** The serving pipeline
   records spans that are *siblings by parent id* but *nested in time*
-  (every ``decode.step`` hangs off the handler span but runs inside
-  the request's ``decode`` phase span), and siblings that PARTIALLY
+  (every ``prefill.chunk`` hangs off the handler span but runs inside
+  the request's ``prefill`` phase span), and siblings that PARTIALLY
   overlap (two rows of one Generate request decoding in different
   slots). A parent-link tree would double-count both shapes. Instead,
   each instant of a trace is attributed to the innermost span covering
@@ -86,8 +86,8 @@ def compute_self_times(records) -> dict[str, float]:
     started latest (shortest on ties) — the innermost one. This
     partitions covered wall time exactly, for every interleaving the
     recorders produce: strict nesting (``fetch`` inside
-    ``rpc.Process``), time-nested siblings (``decode.step`` inside the
-    request's ``decode`` phase but parented to the handler), and
+    ``rpc.Process``), time-nested siblings (``prefill.chunk`` inside the
+    request's ``prefill`` phase but parented to the handler), and
     PARTIALLY overlapping siblings (two rows of one Generate request
     decoding concurrently in different slots) — the case a parent-link
     tree would double-count.

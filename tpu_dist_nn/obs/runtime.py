@@ -115,6 +115,61 @@ class RuntimeSampler:
             "the tdn_prefix_cache_* families)",
         )
         self._gen_scheds: list[object] = []
+        # Where the scheduler loop's time goes (serving/continuous.py
+        # _LoopClock): the loop thread keeps plain int totals, and the
+        # counters tick here by DELTA, so the loop does no registry work.
+        self._c_gen_phase = reg.counter(
+            "tdn_gen_loop_seconds_total",
+            "seconds the continuous scheduler's loop thread spent in "
+            "each phase of its iteration (idle and the two fetches "
+            "are waits, the rest is host time between launches)",
+            labels=("phase",),
+        )
+        # Loop-total field (obs.trace.ITER_FIELDS) -> its counter.
+        self._c_gen_loop = {
+            "seq": reg.counter(
+                "tdn_gen_loop_iterations_total",
+                "scheduler loop iterations that launched a prefill chunk "
+                "or a decode step",
+            ),
+            "cpu_ns": reg.counter(
+                "tdn_gen_loop_cpu_seconds_total",
+                "CPU seconds of the loop thread (it burns none while it "
+                "waits, so they are its host phases'); their wall "
+                "seconds minus this is time the loop wanted to run and "
+                "could not (GIL, a lock, a blocking call, descheduled)",
+            ),
+            "queue_wait_ns": reg.counter(
+                "tdn_gen_queue_wait_seconds_total",
+                "seconds requests waited from submit to their slot bind",
+            ),
+            "binds": reg.counter(
+                "tdn_gen_queue_wait_requests_total",
+                "fresh slot binds counted in "
+                "tdn_gen_queue_wait_seconds_total",
+            ),
+            "prefill_wait_ns": reg.counter(
+                "tdn_gen_prefill_wait_seconds_total",
+                "seconds bound requests waited from their slot bind to "
+                "their first token (the loop prefills one slot an "
+                "iteration)",
+            ),
+            "first_tokens": reg.counter(
+                "tdn_gen_prefill_wait_requests_total",
+                "first tokens counted in "
+                "tdn_gen_prefill_wait_seconds_total",
+            ),
+            "stream_lag_ns": reg.counter(
+                "tdn_gen_stream_lag_seconds_total",
+                "seconds streamed frames stood between the scheduler's "
+                "publish and the handler thread taking them for gRPC",
+            ),
+            "stream_frames": reg.counter(
+                "tdn_gen_stream_lag_frames_total",
+                "frames counted in tdn_gen_stream_lag_seconds_total",
+            ),
+        }
+        self._gen_loop_seen: list[dict] = []
         # Router replica pools (serving/pool.py): the fleet-state
         # gauges nobody increments — per-replica outstanding requests
         # and the blended load view the placement policy compares.
@@ -190,6 +245,24 @@ class RuntimeSampler:
         slot gauges (its queue/counter families ride :meth:`add_batcher`
         — the scheduler satisfies the batcher attribute contract)."""
         self._gen_scheds.append(sched)
+        self._gen_loop_seen.append({})
+
+    def _tick_gen_loop(self, totals: dict, seen: dict) -> None:
+        """Advance the tdn_gen_loop_* counters by what one scheduler's
+        loop totals (``obs.trace.ITER_FIELDS``) grew since last tick."""
+        from tpu_dist_nn.obs.trace import LOOP_PHASES
+
+        for field in (*LOOP_PHASES, *self._c_gen_loop):
+            delta = totals[field] - seen.get(field, 0)
+            seen[field] = totals[field]
+            if delta <= 0:
+                continue
+            if field in LOOP_PHASES:
+                self._c_gen_phase.labels(phase=field).inc(delta / 1e9)
+            elif field.endswith("_ns"):
+                self._c_gen_loop[field].inc(delta / 1e9)
+            else:
+                self._c_gen_loop[field].inc(delta)
 
     def add_pool(self, pool) -> None:
         """Register a router :class:`~tpu_dist_nn.serving.pool
@@ -322,6 +395,8 @@ class RuntimeSampler:
                     for s in self._gen_scheds
                 )
             )
+            for s, seen in zip(self._gen_scheds, self._gen_loop_seen):
+                self._tick_gen_loop(s.loop_totals(), seen)
         if self._pools:
             seen: set[str] = set()
             for pool in self._pools:

@@ -27,10 +27,18 @@ Design constraints (same discipline as the registry):
   thread (submit) and finishes it on another (dispatch/drain), so the
   recorder accepts retroactive ``record_span(name, parent, t0, dur)``
   in addition to the ``with``-style live span.
+
+Beside the per-request spans the module keeps the generation
+scheduler's own account of its loop: :data:`ITERATIONS`, a bounded ring
+of one record per loop iteration (:data:`ITER_FIELDS`) with the
+cumulative time in each loop phase — what no request's span can say,
+because a decode step belongs to every resident request at once. A
+request's ``decode`` span names the iterations it rode.
 """
 
 from __future__ import annotations
 
+import collections
 import json
 import os
 import random
@@ -547,3 +555,82 @@ class Tracer:
 # The process-wide tracer every built-in instrumentation site records
 # into and the ``/trace`` route exports from (mirrors REGISTRY).
 TRACER = Tracer()
+
+
+# ------------------------------------------------- scheduler iterations
+
+# The phases one iteration of the continuous scheduler's loop
+# (serving/continuous.py ``_loop``) is cut into, in the order they run.
+# Each is a cumulative-nanosecond column of an iteration record, a
+# ``phase`` label of ``tdn_gen_loop_seconds_total`` and, prefixed with
+# ``tdn.gen.``, a ``TraceAnnotation`` on the profiler's clock.
+LOOP_PHASES = (
+    "idle", "reap", "admit", "bind",
+    "prefill.dispatch", "prefill.fetch", "prefill.post",
+    "step.dispatch", "step.fetch", "step.account", "step.publish",
+)
+# Phases in which the loop thread waits by design: for work (``idle``)
+# or for the device (the two fetches). Every other phase is host time.
+LOOP_WAIT_PHASES = ("idle", "prefill.fetch", "step.fetch")
+LOOP_HOST_PHASES = tuple(p for p in LOOP_PHASES if p not in LOOP_WAIT_PHASES)
+
+# One iteration record, a flat tuple in this order. ``sched`` tells the
+# schedulers of one process apart, ``seq`` counts that scheduler's
+# iterations (a request's ``decode`` span names the ones it rode as
+# ``iter_first``/``iter_last``), ``t_end`` is ``time.monotonic()`` at
+# the iteration's end. Everything after ``active_slots`` is CUMULATIVE
+# since the scheduler started, so a window's mean of anything is
+# last-minus-first of the records inside it and one iteration's share
+# is a difference of neighbours.
+ITER_FIELDS = (
+    "sched", "seq", "t_end", "prefilled", "active_slots",
+    *LOOP_PHASES,                      # ns spent in each phase
+    "cpu_ns",                          # loop thread's own CPU time
+    "queue_wait_ns", "binds",          # submit -> bind, fresh binds
+    "prefill_wait_ns", "first_tokens",  # bind -> first token
+    "stream_lag_ns", "stream_frames",  # publish -> handler took the frame
+)
+
+
+class IterationRing:
+    """Bounded ring of scheduler-iteration records (:data:`ITER_FIELDS`).
+
+    The loop thread appends one tuple at the end of each iteration and
+    takes no lock for it (a ``deque`` append is atomic under the GIL);
+    readers copy. The ring belongs to the process, not to a scheduler:
+    it is still there to be read after the scheduler is closed.
+    """
+
+    def __init__(self, capacity: int = 4096):
+        self._buf: collections.deque[tuple] = collections.deque(
+            maxlen=int(capacity)
+        )
+
+    @property
+    def capacity(self) -> int:
+        return self._buf.maxlen
+
+    def append(self, record: tuple) -> None:
+        self._buf.append(record)
+
+    def __len__(self) -> int:
+        return len(self._buf)
+
+    def snapshot(self) -> list[tuple]:
+        """The records held, oldest first."""
+        while True:
+            try:
+                return list(self._buf)
+            except RuntimeError:  # appended to mid-copy: copy again
+                continue
+
+    def window(self, t0: float, t1: float) -> list[tuple]:
+        """Records whose iteration ended in ``[t0, t1)`` on the
+        ``time.monotonic()`` clock."""
+        at = ITER_FIELDS.index("t_end")
+        return [r for r in self.snapshot() if t0 <= r[at] < t1]
+
+
+# Where every continuous scheduler of the process records its
+# iterations (mirrors TRACER).
+ITERATIONS = IterationRing()

@@ -157,6 +157,107 @@ _PREFIX_EVICTIONS = REGISTRY.counter(
     "refcount-0 prefix blocks evicted (LRU) to admit a new prefix",
 )
 
+_SCHED_IDS = itertools.count(1)
+(_IDLE, _REAP, _ADMIT, _BIND, _PREFILL_DISPATCH, _PREFILL_FETCH,
+ _PREFILL_POST, _STEP_DISPATCH, _STEP_FETCH, _STEP_ACCOUNT,
+ _STEP_PUBLISH) = range(len(_trace.LOOP_PHASES))
+_HOST = tuple(i for i, p in enumerate(_trace.LOOP_PHASES)
+              if p in _trace.LOOP_HOST_PHASES)
+_SPAN_NAMES = tuple("tdn.gen." + p for p in _trace.LOOP_PHASES)
+
+
+class _LoopClock:
+    """Where the scheduler loop's time goes, always on: the loop thread
+    calls :meth:`mark` at each phase boundary, which closes the phase
+    that was running into its cumulative total and opens the next, so
+    the phases partition the thread's wall time exactly. Each phase is
+    also a ``tdn.gen.<phase>`` annotation on the profiler's clock
+    (``utils.profiling.host_span``), recorded only while a
+    ``jax.profiler`` capture runs.
+
+    The thread's own CPU time is kept beside them, read once an
+    iteration (the read is a system call, 6 µs where the chip's host
+    runs it). The thread burns none while it waits (``idle``, the two
+    fetches), so it is the CPU time of the host phases, and their wall
+    time minus it is time the loop wanted to run and could not (the
+    GIL, a lock, a blocking call, descheduled).
+
+    One writer, no lock: every field is written by the loop thread
+    alone and read as plain ints by anyone. At the end of an iteration
+    that launched something the totals go to ``obs.trace.ITERATIONS``
+    as one record (``obs.trace.ITER_FIELDS``).
+    """
+
+    def __init__(self):
+        from tpu_dist_nn.utils.profiling import host_span
+
+        self._host_span = host_span
+        self.sched = next(_SCHED_IDS)
+        self.seq = 0                 # iterations recorded
+        self.ns = [0] * len(_trace.LOOP_PHASES)
+        self.cpu_ns = 0
+        self.queue_wait_ns = self.binds = 0
+        self.prefill_wait_ns = self.first_tokens = 0
+        self.stream_lag_ns = self.stream_frames = 0
+        # Of the iteration in progress.
+        self.prefilled = False
+        self.active_slots = 0
+        self.launched = False
+        self._phase = _IDLE
+        self._t = time.monotonic_ns()
+
+    def start(self) -> None:
+        """On the loop thread, before its first iteration."""
+        self._t = time.monotonic_ns()
+        self._cpu0 = time.thread_time_ns()
+        self._span = self._host_span(_SPAN_NAMES[_IDLE])
+        self._span.__enter__()
+
+    def mark(self, phase: int) -> None:
+        prev = self._phase
+        now = time.monotonic_ns()
+        self.ns[prev] += now - self._t
+        self._t = now
+        # A new annotation each time: one made before a capture began
+        # records nothing in it.
+        self._span.__exit__(None, None, None)
+        self._span = self._host_span(_SPAN_NAMES[phase])
+        self._span.__enter__()
+        self._phase = phase
+
+    def stop(self) -> None:
+        self.mark(_IDLE)
+        self._span.__exit__(None, None, None)
+
+    def host_ns(self) -> int:
+        ns = self.ns
+        return sum(ns[i] for i in _HOST)
+
+    def record(self) -> tuple:
+        """The totals as one iteration record (ITER_FIELDS)."""
+        return (
+            self.sched, self.seq, self._t / 1e9, self.prefilled,
+            self.active_slots, *self.ns, self.cpu_ns,
+            self.queue_wait_ns, self.binds,
+            self.prefill_wait_ns, self.first_tokens,
+            self.stream_lag_ns, self.stream_frames,
+        )
+
+    def end_iteration(self) -> None:
+        """Close the iteration at the top of the next (``reap`` is its
+        first phase) and put it on the record if it fed the device."""
+        self.mark(_REAP)
+        if self.launched:
+            self.seq += 1
+            self.cpu_ns = time.thread_time_ns() - self._cpu0
+            _trace.ITERATIONS.append(self.record())
+            self.prefilled = self.launched = False
+
+    def ride(self) -> tuple:
+        """What a request's ``decode`` span is later told it rode: the
+        iteration in progress and the totals so far."""
+        return (self.seq + 1, self.ns[_STEP_FETCH], self.host_ns())
+
 
 class PrefixCachePool:
     """Host-side bookkeeping for the reserved prefix region of the slot
@@ -452,6 +553,7 @@ class ContinuousScheduler:
             maxlen=1024
         )
         self._m_rows = _BATCH_ROWS.labels(method=self.method)
+        self._clock = _LoopClock()
         self._thread = threading.Thread(
             target=self._loop, name="tdn-gen-continuous", daemon=True
         )
@@ -488,6 +590,7 @@ class ContinuousScheduler:
         top_k = None if top_k is None else int(top_k)
         top_p = None if top_p is None else float(top_p)
 
+        @jax.named_scope("sample")
         def sample(logits, key):
             if temperature == 0:
                 return jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -536,7 +639,8 @@ class ContinuousScheduler:
             # riding the step's existing device->host sync — always
             # computed so the compiled kernel never depends on the
             # runtime GUARD toggle; acting on it is a host decision).
-            ok = jnp.isfinite(logits).all(axis=-1)
+            with jax.named_scope("guard"):
+                ok = jnp.isfinite(logits).all(axis=-1)
             return sample(logits, key), ok, cache
 
         self._step = step
@@ -669,6 +773,15 @@ class ContinuousScheduler:
         IS a decode step here)."""
         return self.batches_total
 
+    def loop_totals(self) -> dict:
+        """The loop's cumulative accounting as of its last phase
+        boundary, keyed by ``obs.trace.ITER_FIELDS``: nanoseconds per
+        phase, the loop thread's CPU time, and the
+        queue-wait, prefill-wait and stream-out-lag sums with their
+        counts (the ``tdn_gen_loop_*`` families, the benchmark's
+        ``sched_*`` metrics)."""
+        return dict(zip(_trace.ITER_FIELDS, self._clock.record()))
+
     # Prefix-cache accounting (None-safe: 0 with the pool off, so the
     # sampler/bench read one shape regardless of configuration).
     @property
@@ -792,7 +905,7 @@ class ContinuousScheduler:
                 f"max_new_tokens must be in [1, {self._N}], got {budget}"
             )
         resume = [int(t) for t in resume_tokens] if resume_tokens else None
-        stream = TokenStream(max_buffer)
+        stream = TokenStream(max_buffer, lag_sink=self._clock)
         if resume is not None:
             # The client already holds the whole replayed prefix.
             stream.seed(len(resume))
@@ -872,6 +985,16 @@ class ContinuousScheduler:
             _RETIRED.labels(reason="cancelled").inc()
             _TOKENS.inc(len(occ["tokens"]))
             self._sched_core.note_drained(1)
+            if item["ctx"] is not None and occ["t_first"] is not None:
+                # Most streams end here (the wire carries no budget, so
+                # a client cancels when it has what it wants): without
+                # this their traces would show no decode phase at all.
+                _trace.TRACER.record_span(
+                    "decode", item["ctx"], occ["t_first"],
+                    time.monotonic() - occ["t_first"],
+                    attrs={"slot": s, "tokens": len(occ["tokens"]),
+                           "reason": "cancelled", **self._ride_attrs(occ)},
+                )
             item["remaining"] -= 1
             slog.info(
                 "gen.stream_cancelled", slot=s,
@@ -948,11 +1071,37 @@ class ContinuousScheduler:
             _trace.TRACER.record_span(
                 "decode", item["ctx"], occ["t_first"],
                 time.monotonic() - occ["t_first"],
-                attrs={"slot": slot, "steps": len(toks), "reason": reason},
+                attrs={"slot": slot, "tokens": len(toks), "reason": reason,
+                       **self._ride_attrs(occ)},
             )
         item["remaining"] -= 1
         if item["remaining"] == 0 and not item["abandoned"]:
             item["done"].set()
+
+    def _note_first_token(self, occ: dict, now: float) -> None:
+        """The occupant has its first token: stamp it, and for a traced
+        request keep what the loop had counted by now, so that its
+        ``decode`` span can say what it rode (:meth:`_ride_attrs`) at no cost
+        per step."""
+        occ["t_first"] = now
+        if occ["item"]["ctx"] is not None:
+            occ["rode"] = (self.batches_total, *self._clock.ride())
+
+    def _ride_attrs(self, occ: dict) -> dict:
+        """Attributes of a ``decode`` span: the step launches the
+        request rode since its first token, the seconds of them the
+        loop spent blocked on the device (``step_fetch_s``) and in its
+        host phases (``host_s``), and the first and last loop iteration
+        (``seq`` of ``obs.trace.ITERATIONS``), by which the request
+        joins the iteration records."""
+        steps0, iter0, fetch0, host0 = occ["rode"]
+        iter1, fetch1, host1 = self._clock.ride()
+        return {
+            "steps": self.batches_total - steps0,
+            "step_fetch_s": (fetch1 - fetch0) / 1e9,
+            "host_s": (host1 - host0) / 1e9,
+            "iter_first": iter0, "iter_last": iter1,
+        }
 
     def _tier_keys(self, row: np.ndarray):
         """The prompt's cacheable-prefix candidates, longest first —
@@ -992,11 +1141,14 @@ class ContinuousScheduler:
         }
         self._occupant[slot] = occ
         self.rows_total += 1
-        if item["ctx"] is not None and resume is None:
-            _trace.TRACER.record_span(
-                "queue_wait", item["ctx"], item["t_submit"],
-                now - item["t_submit"],
-            )
+        if resume is None:
+            self._clock.queue_wait_ns += int((now - item["t_submit"]) * 1e9)
+            self._clock.binds += 1
+            if item["ctx"] is not None:
+                _trace.TRACER.record_span(
+                    "queue_wait", item["ctx"], item["t_submit"],
+                    now - item["t_submit"],
+                )
         if self._pool is None:
             return
         hit = self._pool.lookup(self._tier_keys(item["x"][row]))
@@ -1073,6 +1225,9 @@ class ContinuousScheduler:
         freezing the resident decode streams. The final chunk yields
         the prompt's last-position sample: the request's first token
         (TTFT), after which the slot joins the decode frontier."""
+        clock = self._clock
+        clock.mark(_PREFILL_DISPATCH)
+        clock.prefilled = clock.launched = True
         occ = self._occupant[slot]
         item = occ["item"]
         start = occ["fill"]
@@ -1100,6 +1255,7 @@ class ContinuousScheduler:
             self._device_fault(e)
             return
         self._cache = cache
+        clock.mark(_PREFILL_FETCH)
         try:
             tok = int(tok)  # the token fetch (host sync)
         except Exception as e:  # noqa: BLE001 — donated cache: global
@@ -1110,6 +1266,7 @@ class ContinuousScheduler:
             # failure is unreachable, so nothing is lost by escalating).
             self._device_fault(e)
             return
+        clock.mark(_PREFILL_POST)
         occ["fill"] = start + size
         self.prefill_chunks_total += 1
         if self._gp_model is not None:
@@ -1143,7 +1300,7 @@ class ContinuousScheduler:
             occ["resume"] = None
             occ["replay"] = known[1:]
             first = int(known[0])
-            occ["t_first"] = now
+            self._note_first_token(occ, now)
             if item["ctx"] is not None:
                 _trace.TRACER.record_span(
                     "prefill", item["ctx"], occ["t_bind"],
@@ -1165,7 +1322,9 @@ class ContinuousScheduler:
         ttft = now - item["t_submit"]
         _TTFT.observe(ttft)
         self.ttft_recent.append(ttft)
-        occ["t_first"] = now
+        clock.prefill_wait_ns += int((now - occ["t_bind"]) * 1e9)
+        clock.first_tokens += 1
+        self._note_first_token(occ, now)
         if item["ctx"] is not None:
             _trace.TRACER.record_span(
                 "prefill", item["ctx"], occ["t_bind"], now - occ["t_bind"],
@@ -1188,11 +1347,10 @@ class ContinuousScheduler:
         """One compiled step over every decoding slot; retire/refill
         happens on the host between steps (the iteration-level
         boundary)."""
-        t0 = time.monotonic()
-        traced = [
-            self._occupant[s] for s in range(self._S)
-            if self._active[s] and self._occupant[s]["item"]["ctx"] is not None
-        ]
+        clock = self._clock
+        clock.mark(_STEP_DISPATCH)
+        clock.launched = True
+
         def fail(e: Exception, kernel: bool) -> None:
             # Rate-limited: a wedged backend fails every subsequent
             # step too — the first few stack traces are the signal,
@@ -1221,6 +1379,7 @@ class ContinuousScheduler:
             fail(e, kernel=True)
             return
         self._cache = cache
+        clock.mark(_STEP_FETCH)
         if self.fetch_hook is not None:
             try:
                 self.fetch_hook(toks)
@@ -1237,6 +1396,7 @@ class ContinuousScheduler:
             # pre-sync hook fault above which leaves the cache intact.
             fail(e, kernel=True)
             return
+        clock.mark(_STEP_ACCOUNT)
         # Act on the in-kernel numeric guard (host decision — the
         # runtime opt-out never reshapes the compiled kernel): a slot
         # whose logits went non-finite fails over ALONE with INTEGRITY
@@ -1264,7 +1424,7 @@ class ContinuousScheduler:
                     f"of shipping a garbage token"
                 ))
         self.batches_total += 1
-        active = int(self._active.sum())
+        active = clock.active_slots = int(self._active.sum())
         self.slot_steps_total += active
         self._m_rows.observe(active)
         if self._gp_model is not None:
@@ -1292,14 +1452,7 @@ class ContinuousScheduler:
                 self._gp_model, active_pos, idle, mid,
                 replay_slots=replay,
             )
-        dur = time.monotonic() - t0
-        for occ in traced:
-            if occ["item"]["err"] is not None:
-                continue
-            _trace.TRACER.record_span(
-                "decode.step", occ["item"]["ctx"], t0, dur,
-                attrs={"active_slots": active},
-            )
+        clock.mark(_STEP_PUBLISH)
         for s in range(self._S):
             if not self._active[s]:
                 continue
@@ -1440,8 +1593,8 @@ class ContinuousScheduler:
             _trace.TRACER.record_span(
                 "decode", item["ctx"], occ["t_first"],
                 now - occ["t_first"],
-                attrs={"slot": slot, "steps": len(occ["tokens"]),
-                       "reason": "preempted"},
+                attrs={"slot": slot, "tokens": len(occ["tokens"]),
+                       "reason": "preempted", **self._ride_attrs(occ)},
             )
         slog.info(
             "gen.preempted", slot=slot, slo_class=cls,
@@ -1473,17 +1626,24 @@ class ContinuousScheduler:
 
     def _loop(self) -> None:
         core = self._sched_core
+        clock = self._clock
+        clock.start()
         while True:
             # Cancel propagation first: slots freed by dead streams are
             # bindable THIS iteration (satellite 2 — a cancel storm must
             # not strand slots for even one extra step).
+            clock.end_iteration()  # the last one; this one opens in reap
             self._reap_cancelled()
+            clock.mark(_ADMIT)
             admits = []
             with self._cond:
                 while (not core.closed and not core.has_pending()
                        and not self._resume and not self._resident()):
+                    clock.mark(_IDLE)
                     self._cond.wait()
+                    clock.mark(_ADMIT)
                 if core.closed and not self._resident():
+                    clock.stop()
                     return  # close() sweeps whatever is still pending
                 if not core.closed:
                     free = sum(1 for o in self._occupant if o is None)
@@ -1493,6 +1653,7 @@ class ContinuousScheduler:
                             break
                         admits.append(got)
             core.drain_deferred()
+            clock.mark(_BIND)
             # Device work OUTSIDE the lock: submitters must never block
             # behind a block copy, a prefill chunk, or a step.
             for bindable in admits:

@@ -75,9 +75,19 @@ _INTERTOKEN = REGISTRY.histogram(
 
 class TokenStream:
     """Bounded single-producer/single-consumer token channel for one
-    GenerateStream request."""
+    GenerateStream request.
 
-    def __init__(self, max_buffer: int = 4096):
+    ``lag_sink`` (the scheduler's loop clock) is told the stream-out
+    lag: how long each frame stood between the producer's ``publish``
+    and the consumer taking it to hand to gRPC. The consumer sums its
+    lag here under the lock it already holds; the producer moves the
+    sums into the sink's ``stream_lag_ns`` / ``stream_frames`` at its
+    next ``publish`` of this stream, so the sink keeps one writer and
+    no lock is added. (The frames a consumer takes after the stream's
+    last publish are therefore never counted: one of a request's ~190.)
+    """
+
+    def __init__(self, max_buffer: int = 4096, lag_sink=None):
         self._cond = threading.Condition()
         self._max = int(max_buffer)
         self._pending: list[int] = []  # guarded-by: _cond
@@ -85,6 +95,10 @@ class TokenStream:
         self._terminal: dict | None = None  # guarded-by: _cond
         self._cancelled = False  # guarded-by: _cond
         self._last_publish: float | None = None  # guarded-by: _cond
+        self._lag_sink = lag_sink
+        self._pending_since = 0.0  # guarded-by: _cond
+        self._lag_s = 0.0  # guarded-by: _cond
+        self._lag_frames = 0  # guarded-by: _cond
         _STREAM_REQUESTS.inc()
 
     # ---------------------------------------------------- producer side
@@ -114,6 +128,12 @@ class TokenStream:
             if self._last_publish is not None:
                 _INTERTOKEN.observe(now - self._last_publish)
             self._last_publish = now
+            if self._lag_frames and self._lag_sink is not None:
+                self._lag_sink.stream_lag_ns += int(self._lag_s * 1e9)
+                self._lag_sink.stream_frames += self._lag_frames
+                self._lag_s, self._lag_frames = 0.0, 0
+            if not self._pending:
+                self._pending_since = now
             self._sent += len(fresh)
             self._pending.extend(int(t) for t in fresh)
             if len(self._pending) > self._max:
@@ -175,6 +195,8 @@ class TokenStream:
                 if self._pending:
                     batch = self._pending
                     self._pending = []
+                    self._lag_s += time.monotonic() - self._pending_since
+                    self._lag_frames += 1
                     _STREAM_FRAMES.labels(kind="tokens").inc()
                     return "tokens", batch
                 if self._terminal is not None:

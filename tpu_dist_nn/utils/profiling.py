@@ -116,11 +116,14 @@ def annotate(name: str):
     return jax.named_scope(name)
 
 
-@contextlib.contextmanager
-def host_span(name: str) -> Iterator[None]:
-    """Host-side annotation for un-traced code (client loops, data feed)."""
-    with jax.profiler.TraceAnnotation(name):
-        yield
+def host_span(name: str) -> jax.profiler.TraceAnnotation:
+    """Host-side annotation for un-traced code (client loops, data feed,
+    the generation scheduler's loop phases): a span in the host plane of
+    any ``jax.profiler`` capture, on the clock the device operations
+    use. Near free while no capture runs. The object is a context
+    manager; make a new one for each span (one made before a capture
+    began records nothing in it)."""
+    return jax.profiler.TraceAnnotation(name)
 
 
 @contextlib.contextmanager
