@@ -50,6 +50,8 @@ import pytest  # noqa: E402
 # tests/test_quick_tier.py asserts every module has an entry and every
 # entry resolves, so the list cannot rot silently.
 QUICK_TESTS = {
+    # PR 25: the AOT listing's HLO reader on the recorded parent step.
+    "test_aot_step_ops": ["test_big_ops_keeps_layout_scope_and_target"],
     "test_autoscale": [
         # ISSUE 12 acceptance smokes: the 2->3->2 loopback scale
         # drill under a faults.py-paced burst (zero dropped), the
@@ -121,6 +123,7 @@ QUICK_TESTS = {
         "test_decode_span_says_what_the_request_rode_and_no_step_spans",
     ],
     "test_generate": ["test_greedy_generation_matches_teacher_forced_oracle",
+                      "test_slot_step_never_carries_or_selects_the_cache",
                       "test_pipeline_generate_matches_single_chip",
                       "test_tp_generate_greedy_matches_single_chip"],
     # ISSUE 14: goodput conservation on the loopback wire (odd rows
@@ -163,6 +166,9 @@ QUICK_TESTS = {
     "test_interop_keras": ["test_keras_forward_parity",
                            "test_keras_round_trip"],
     "test_kernels": ["test_matches_jnp[relu]", "test_shape_mismatch_raises"],
+    # PR 25: the in-place K/V row write, partial last lane block.
+    "test_kv_write": [
+        "test_write_rows_lands_rows_and_nothing_else[partial_lane_block]"],
     "test_multihost_real": ["test_two_process_collectives"],
     "test_native_codec": ["test_examples_roundtrip_and_parity",
                           "test_fuzz_model_roundtrip_native_vs_python"],

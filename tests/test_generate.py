@@ -241,10 +241,17 @@ def test_generate_eos_validated():
 
 def test_decode_step_slots_matches_scalar_decode_step():
     # With a uniform position vector and every slot active, the
-    # slot-wise step IS the batched scalar step: identical logits and
-    # identical cache writes (the masked-select write lands the same
-    # values dynamic_update_slice does).
-    from tpu_dist_nn.models.generate import decode_step_slots
+    # slot-wise step IS the batched scalar step: the rows it writes are
+    # bit-identical to dynamic_update_slice's and every other byte of
+    # the cache is untouched. The logits agree to rounding, not to the
+    # bit: the slot step's softmax takes the token's own key as one
+    # more column after the stored ones instead of at index `pos`, so
+    # the same pos + 1 terms are summed in another order.
+    from tpu_dist_nn.models.generate import (
+        decode_step_slots,
+        rows_to_slots,
+        slots_to_rows,
+    )
 
     params = init_transformer(jax.random.key(0), CFG)
     prompts = _prompt(4, 8, seed=3)
@@ -254,17 +261,32 @@ def test_decode_step_slots_matches_scalar_decode_step():
         params, cache, jnp.int32(8), tok, CFG
     )
     got_logits, got_cache = decode_step_slots(
-        params, cache, jnp.full((4,), 8, jnp.int32), tok, CFG
+        params, {p: rows_to_slots(a) for p, a in cache.items()},
+        jnp.full((4,), 8, jnp.int32), tok, CFG,
+    )
+    np.testing.assert_allclose(
+        np.asarray(ref_logits), np.asarray(got_logits), rtol=1e-5, atol=1e-5
     )
     np.testing.assert_array_equal(
-        np.asarray(ref_logits), np.asarray(got_logits)
+        np.asarray(ref_logits).argmax(-1), np.asarray(got_logits).argmax(-1)
     )
-    np.testing.assert_array_equal(
-        np.asarray(ref_cache["k"]), np.asarray(got_cache["k"])
+    np.testing.assert_allclose(  # layer 0's row is exact, deeper to rounding
+        np.asarray(ref_cache["k"]), np.asarray(slots_to_rows(got_cache["k"])),
+        rtol=1e-5, atol=1e-5,
     )
-    np.testing.assert_array_equal(
-        np.asarray(ref_cache["v"]), np.asarray(got_cache["v"])
+    np.testing.assert_allclose(
+        np.asarray(ref_cache["v"]), np.asarray(slots_to_rows(got_cache["v"])),
+        rtol=1e-5, atol=1e-5,
     )
+    for part in ("k", "v"):
+        got = np.asarray(slots_to_rows(got_cache[part]))
+        np.testing.assert_array_equal(  # everything but row 8: untouched
+            np.delete(got, 8, axis=2),
+            np.delete(np.asarray(cache[part]), 8, axis=2),
+        )
+        np.testing.assert_array_equal(
+            got[0, :, 8], np.asarray(ref_cache[part])[0, :, 8]
+        )
 
 
 def test_decode_step_slots_staggered_positions_match_oracle():
@@ -314,6 +336,157 @@ def test_decode_step_slots_staggered_positions_match_oracle():
         )
 
 
+# A head count that is no power of two (gpt2-large has 20), and a
+# position axis that is no multiple of anything.
+SLOT_CFG = TransformerConfig(
+    vocab_size=64, d_model=24, n_heads=3, n_layers=2, d_ff=48, max_seq_len=32
+)
+SLOT_M = 13
+
+
+@pytest.mark.parametrize(
+    "pos, active, pool",
+    [
+        pytest.param([3, 5, 2, 7], [1, 1, 1, 1], 0, id="all_active"),
+        pytest.param([3, 5, 2, 7], [1, 0, 1, 0], 0, id="some_inactive"),
+        pytest.param([0, 4, 0, 6], [1, 1, 0, 1], 0, id="pos_zero"),
+        pytest.param([SLOT_M - 1, 4, SLOT_M - 1, 6], [1, 1, 0, 1], 0,
+                     id="pos_last"),
+        pytest.param([0, SLOT_M - 1, 1, SLOT_M // 2], [1, 1, 1, 1], 0,
+                     id="positions_far_apart"),
+        pytest.param([3, 0, SLOT_M - 1, 7], [1, 1, 1, 0], 2,
+                     id="prefix_pool_through_scheduler_step"),
+    ],
+)
+def test_slot_step_against_plain_forward(pos, active, pool):
+    # The slot step against a plain no-cache f32 forward of each slot's
+    # own tokens: the logits of every active slot, the one row it
+    # writes, and every byte it must leave alone (other positions, an
+    # inactive slot — mid-prefill, say — and the prefix pool's slots
+    # behind the request region).
+    from tpu_dist_nn.models.generate import (
+        decode_step_slots,
+        init_slot_cache,
+        prefill_into_cache,
+        slots_to_rows,
+    )
+    from tpu_dist_nn.serving.continuous import slot_kernels
+
+    cfg, M, S = SLOT_CFG, SLOT_M, len(pos)
+    params = init_transformer(jax.random.key(3), cfg)
+    rng = np.random.default_rng(4)
+    seqs = [rng.integers(0, cfg.vocab_size, p + 1) for p in pos]
+    cache = init_slot_cache(cfg, S + pool, M)
+    for s, seq in enumerate(seqs):
+        if pos[s]:
+            _, cache = prefill_into_cache(
+                params, cfg, cache, s, jnp.asarray(seq[None, :-1], jnp.int32)
+            )
+    # Garbage wherever no key is stored yet (and all over the pool): the
+    # mask, not a zero, must keep it out.
+    stored = np.arange(M)[None, :] < np.asarray(pos + [0] * pool)[:, None]
+    junk = jnp.asarray(rng.normal(size=cache["k"].shape), jnp.float32)
+    cache = {
+        part: jnp.where(stored[None, :, None, None, :], a, junk + 7.5)
+        for part, a in cache.items()
+    }
+    before = {part: np.asarray(a) for part, a in cache.items()}
+    tok = jnp.asarray([seq[-1] for seq in seqs], jnp.int32)
+    pos_v = jnp.asarray(pos, jnp.int32)
+    active_v = jnp.asarray(active, bool)
+    if pool:
+        _, _, step = slot_kernels(cfg, 0.0, None, None)
+        toks, ok, cache = step(params, cache, pos_v, active_v, tok, None)
+        assert np.asarray(ok).all()
+    else:
+        logits, cache = decode_step_slots(
+            params, cache, pos_v, tok, cfg, active=active_v
+        )
+    for s, seq in enumerate(seqs):
+        if not active[s]:
+            continue
+        ref = forward(params, jnp.asarray(seq[None], jnp.int32), cfg)[0, -1]
+        if pool:
+            assert int(toks[s]) == int(jnp.argmax(ref))
+        else:
+            np.testing.assert_allclose(
+                np.asarray(logits[s]), np.asarray(ref), rtol=2e-5, atol=2e-5
+            )
+        _, want = prefill(params, jnp.asarray(seq[None], jnp.int32), cfg, M)
+        for part in ("k", "v"):
+            np.testing.assert_allclose(
+                np.asarray(slots_to_rows(cache[part]))[:, s, pos[s]],
+                np.asarray(want[part])[:, 0, pos[s]], rtol=2e-5, atol=2e-5,
+            )
+    written = np.zeros((S + pool, M), bool)
+    for s in range(S):
+        written[s, pos[s]] = bool(active[s])
+    for part in ("k", "v"):
+        keep = np.broadcast_to(
+            ~written[None, :, None, None, :], before[part].shape
+        )
+        np.testing.assert_array_equal(
+            np.asarray(cache[part])[keep], before[part][keep]
+        )
+
+
+def _walk_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold
+    (scan and while bodies, jitted calls), a Pallas kernel's own body
+    left out: what is in there works on one block in VMEM."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _walk_eqns(sub)
+
+
+def test_slot_step_never_carries_or_selects_the_cache():
+    # The structural guard against the two things that made the step
+    # move the whole cache every layer: the cache as the layer scan's
+    # xs -> ys (every layer sliced out, changed, written into a second
+    # cache) and a select over a layer's whole extent to change S rows.
+    # Nothing but the in-place write may produce a layer of the cache.
+    from tpu_dist_nn.models.generate import decode_step_slots, init_slot_cache
+
+    cfg, M, S = SLOT_CFG, SLOT_M, 4
+    params = init_transformer(jax.random.key(3), cfg)
+    cache = init_slot_cache(cfg, S, M)
+    layer = S * M * cfg.n_heads * cfg.head_dim
+    jaxpr = jax.make_jaxpr(
+        lambda params, cache, pos, tok, active: decode_step_slots(
+            params, cache, pos, tok, cfg, active=active
+        )
+    )(params, cache, jnp.zeros(S, jnp.int32), jnp.zeros(S, jnp.int32),
+      jnp.ones(S, bool)).jaxpr
+    big = {}
+    for eqn in _walk_eqns(jaxpr):
+        sizes = [int(np.prod(v.aval.shape)) for v in eqn.outvars
+                 if hasattr(v.aval, "shape")]
+        if any(n >= layer for n in sizes):
+            big.setdefault(eqn.primitive.name, []).append(sizes)
+    assert "scan" in {e.primitive.name for e in _walk_eqns(jaxpr)}
+    for name in ("scan", "while", "select_n"):
+        assert name not in big, (name, big[name])
+    # The one thing that yields the cache: the write of K and V, each
+    # aliased to its input (the layer reads are slices a layer large,
+    # which the compiler fuses into the scores and the values). It is
+    # there once for each platform it can be lowered for, under the
+    # `cond` that lax.platform_dependent leaves for lowering to resolve.
+    whole = cfg.n_layers * layer
+    yields_cache = {name: sizes for name, sizes in big.items()
+                    if any(n >= whole for ns in sizes for n in ns)}
+    assert yields_cache == {"pallas_call": [[whole, whole]] * 2,
+                            "cond": [[whole, whole]]}
+    for eqn in _walk_eqns(jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            assert dict(eqn.params["input_output_aliases"]) == {4: 0, 5: 1}
+
+
 def test_prefill_into_cache_lands_slot_and_clears_stale():
     # Admission into an arbitrary slot index: the chosen slot's FULL
     # extent is overwritten (a reused slot cannot leak its previous
@@ -322,6 +495,7 @@ def test_prefill_into_cache_lands_slot_and_clears_stale():
     from tpu_dist_nn.models.generate import (
         init_slot_cache,
         prefill_into_cache,
+        rows_to_slots,
     )
 
     params = init_transformer(jax.random.key(0), CFG)
@@ -333,9 +507,10 @@ def test_prefill_into_cache_lands_slot_and_clears_stale():
     # Parity with the batch prefill's row 1 — including the zero pad.
     _, ref = prefill(params, prompts, CFG, max_len=12)
     np.testing.assert_array_equal(
-        np.asarray(cache["k"][:, 1]), np.asarray(ref["k"][:, 1])
+        np.asarray(cache["k"][:, 1]),
+        np.asarray(rows_to_slots(ref["k"])[:, 1]),
     )
-    assert np.all(np.asarray(cache["k"][:, 1, 8:]) == 0)
+    assert np.all(np.asarray(cache["k"][:, 1, :, :, 8:]) == 0)
     # Slots 0 and 2 keep their garbage (untouched by the slot write).
     np.testing.assert_array_equal(np.asarray(cache["k"][:, 0]), before_k[:, 0])
     np.testing.assert_array_equal(np.asarray(cache["k"][:, 2]), before_k[:, 2])
@@ -374,7 +549,8 @@ def test_prefill_chunk_into_cache_bitwise_matches_monolithic():
     lg, c = prefill_chunk_into_cache(params, CFG, cache0, 1, prompts, 0)
     np.testing.assert_array_equal(np.asarray(lg), np.asarray(ref_logits))
     np.testing.assert_array_equal(
-        np.asarray(c["k"][:, 1, :T]), np.asarray(ref_cache["k"][:, 1, :T])
+        np.asarray(c["k"][:, 1, :, :, :T]),
+        np.asarray(ref_cache["k"][:, 1, :, :, :T]),
     )
     # Split 3 + 5: the second chunk attends to the first's K/V.
     lg2, c2 = prefill_chunk_into_cache(
@@ -383,10 +559,12 @@ def test_prefill_chunk_into_cache_bitwise_matches_monolithic():
     lg2, c2 = prefill_chunk_into_cache(params, CFG, c2, 1, prompts[:, 3:], 3)
     np.testing.assert_array_equal(np.asarray(lg2), np.asarray(ref_logits))
     np.testing.assert_array_equal(
-        np.asarray(c2["k"][:, 1, :T]), np.asarray(ref_cache["k"][:, 1, :T])
+        np.asarray(c2["k"][:, 1, :, :, :T]),
+        np.asarray(ref_cache["k"][:, 1, :, :, :T]),
     )
     np.testing.assert_array_equal(
-        np.asarray(c2["v"][:, 1, :T]), np.asarray(ref_cache["v"][:, 1, :T])
+        np.asarray(c2["v"][:, 1, :, :, :T]),
+        np.asarray(ref_cache["v"][:, 1, :, :, :T]),
     )
 
 
@@ -447,8 +625,8 @@ def test_prefill_chunk_after_copied_prefix_matches_monolithic():
     )
     np.testing.assert_array_equal(np.asarray(lg), np.asarray(ref_logits))
     np.testing.assert_array_equal(
-        np.asarray(c["k"][:, req_slot, :T]),
-        np.asarray(ref_cache["k"][:, req_slot, :T]),
+        np.asarray(c["k"][:, req_slot, :, :, :T]),
+        np.asarray(ref_cache["k"][:, req_slot, :, :, :T]),
     )
 
 

@@ -89,6 +89,59 @@ def _conv(S, jnp):
     return fn, (S((64, 16, 16, 32)), S((3, 3, 32, 64)), S((64,)))
 
 
+def _kv_write(S, jnp):
+    from tpu_dist_nn.kernels.kv_write import write_rows
+
+    # gpt2-large's heads, a partial last lane block (383 = 2 * 128 + 127),
+    # pool slots behind the request region.
+    cache = S((2, 64, 20, 64, 383), jnp.bfloat16)
+    new = S((2, 56, 20, 64), jnp.bfloat16)
+    return write_rows, (cache, cache, new, new, S((56,), jnp.int32),
+                        S((56,), jnp.bool_))
+
+
+def _slot_step(S, jnp):
+    """The scheduler's step program at gpt2-large's widths, three layers
+    deep, with a prefix pool: a Mosaic call writes the rows, and nothing
+    else in the compiled program yields a layer of the cache."""
+    import jax
+
+    from tools.aot_step_ops import big_ops
+    from tpu_dist_nn.models.generate import init_slot_cache
+    from tpu_dist_nn.models.transformer import (
+        TransformerConfig,
+        init_transformer,
+    )
+    from tpu_dist_nn.serving.continuous import slot_kernels
+
+    cfg = TransformerConfig(
+        vocab_size=1024, d_model=1280, n_heads=20, n_layers=3, d_ff=5120,
+        max_seq_len=1024, compute_dtype="bfloat16",
+    )
+    slots, pool, extent = 56, 8, 383
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: S(a.shape, a.dtype), tree)
+
+    params = shaped(jax.eval_shape(
+        lambda: init_transformer(jax.random.key(0), cfg)))
+    cache = shaped(jax.eval_shape(
+        lambda: init_slot_cache(cfg, slots + pool, extent)))
+    key = shaped(jax.eval_shape(lambda: jax.random.key(0)))
+    _, _, step = slot_kernels(cfg, 0.0, None, None)
+    ints = S((slots,), jnp.int32)
+
+    def check(text):
+        layer = slots * extent * cfg.n_heads * cfg.head_dim
+        found = big_ops(text, layer)["ops"]
+        return [f"{op['name']} ({op['opcode']}) {op['shapes']}"
+                for op in found
+                if op["opcode"] != "custom-call tpu_custom_call"]
+
+    args = (params, cache, ints, S((slots,), jnp.bool_), ints, key)
+    return step, args, check
+
+
 CASES = {
     "fused_dense_256x784x128_relu": _fused_dense(256, 784, 128, "relu"),
     "fused_dense_256x64x10_softmax": _fused_dense(256, 64, 10, "softmax"),
@@ -107,6 +160,8 @@ CASES = {
     "flash_ceiling_d64_bf16_fwd": _flash(None, 2, 64, "bfloat16", False),
     "flash_ceiling_d64_bf16_grad": _flash(None, 2, 64, "bfloat16", True),
     "flash_ceiling_d256_f32_grad": _flash(None, 2, 256, "float32", True),
+    "kv_write_h20_d64_m383_bf16": _kv_write,
+    "slot_step_h20_d1280_in_place": _slot_step,
 }
 
 
@@ -140,12 +195,18 @@ def _compile(case: str) -> dict:
     def S(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    fn, args = CASES[case](S, jnp)
+    fn, args, *check = CASES[case](S, jnp)
+    # A program that is jitted already (with its donation) lowers as it is.
+    fn = fn if hasattr(fn, "lower") else jax.jit(fn)
     try:
-        text = jax.jit(fn).lower(*args).compile().as_text()
+        text = fn.lower(*args).compile().as_text()
     except Exception as e:  # noqa: BLE001 — reported, the parent fails
         return {"error": f"{type(e).__name__}: {e}"[:2000]}
-    return {"custom_call": "tpu_custom_call" in text}
+    out = {"custom_call": "tpu_custom_call" in text}
+    problems = check[0](text) if check else []
+    if problems:
+        out["problems"] = problems
+    return out
 
 
 @pytest.mark.parametrize("case", sorted(CASES))

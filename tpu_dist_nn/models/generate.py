@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from tpu_dist_nn.kernels.kv_write import write_rows
 from tpu_dist_nn.models.transformer import (
     TransformerConfig,
     attn_sublayer,
@@ -77,10 +78,12 @@ def decode_blocks(blocks: dict, cache: dict, pos, x: jnp.ndarray,
     decoder. Attention masks positions ``> pos`` (the rest of the
     buffer is zero-filled future space).
 
-    Numerics here and in :func:`decode_blocks_slots` must stay in
-    lockstep (same casts, same softmax/einsum order): the continuous
-    scheduler's bit-parity contract with the static decode rides on it
-    (CI: test_continuous_matches_static_greedy_tokens).
+    Numerics here and in :func:`decode_blocks_slots` must stay in step
+    (same casts, f32 scores and softmax, one rounding of the values):
+    the slot step sums the same ``pos + 1`` terms with the token's own
+    key last instead of at index ``pos``, so logits agree to rounding,
+    and the continuous scheduler's greedy tokens are held equal to the
+    static decode's (CI: test_continuous_matches_static_greedy_tokens).
     """
     B = x.shape[0]
     H, Dh = cfg.n_heads, cfg.head_dim
@@ -302,7 +305,7 @@ def _compiled_generate(cfg: TransformerConfig, T: int, max_new_tokens: int,
 
 # ---------------------------------------------------------------------------
 # Slot-wise decoding: the kernels under the continuous-batching scheduler
-# (serving/continuous.py). One fixed (L, S, max_len, H, Dh) cache holds S
+# (serving/continuous.py). One fixed (L, S, H, Dh, max_len) cache holds S
 # independent request slots; prefill lands a prompt's K/V into ANY free
 # slot, and one compiled step advances every slot at its OWN position.
 # ---------------------------------------------------------------------------
@@ -310,15 +313,21 @@ def _compiled_generate(cfg: TransformerConfig, T: int, max_new_tokens: int,
 
 def init_slot_cache(cfg: TransformerConfig, slots: int, max_len: int,
                     dtype=None) -> dict:
-    """A zeroed ``(L, S, max_len, H, Dh)`` slot KV cache.
+    """A zeroed ``(L, S, H, Dh, max_len)`` slot KV cache.
 
-    Same layout as :func:`prefill`'s batch cache with the batch axis
-    reinterpreted as slots — so every shape downstream of it
-    (``decode_step_slots``'s einsums, the masked writes) is identical
-    to the batched decode path. Static by construction: admission and
-    retirement never change its shape, only which slots the active
-    mask selects (the TPU-friendly answer to paged KV — see
-    docs/PERF.md "Continuous batching").
+    The POSITION axis is last, which is how a TPU stores such a buffer
+    whatever shape it is declared in: with ``Dh = 64`` it puts positions
+    in the 128 lanes and ``Dh`` in the sublanes rather than pad every
+    head to 128 lanes (a ``(L, S, M, H, Dh)`` array lives on a v5e as
+    ``{2,4,3,1,0:T(8,128)(2,1)}``). Declaring what is stored lets the
+    decode step read a layer where it lies and lets the three programs
+    that share the buffer (step, chunk prefill, slot copy) agree on one
+    layout by construction. :func:`prefill`'s batch cache keeps its
+    ``(L, B, max_len, H, Dh)``; :func:`rows_to_slots` turns one into
+    the other. Static by construction: admission and retirement never
+    change the shape, only which slots the active mask selects (the
+    TPU-friendly answer to paged KV — see docs/PERF.md "Continuous
+    batching").
     """
     if slots < 1:
         raise ValueError(f"slots must be >= 1, got {slots}")
@@ -327,8 +336,18 @@ def init_slot_cache(cfg: TransformerConfig, slots: int, max_len: int,
             f"max_len must be in [1, {cfg.max_seq_len}], got {max_len}"
         )
     dtype = jnp.dtype(cfg.compute_dtype) if dtype is None else dtype
-    shape = (cfg.n_layers, slots, max_len, cfg.n_heads, cfg.head_dim)
+    shape = (cfg.n_layers, slots, cfg.n_heads, cfg.head_dim, max_len)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
+def rows_to_slots(rows: jnp.ndarray) -> jnp.ndarray:
+    """Batch-cache axes ``(L, B, M, H, Dh)`` -> slot-cache axes."""
+    return rows.transpose(0, 1, 3, 4, 2)
+
+
+def slots_to_rows(slots: jnp.ndarray) -> jnp.ndarray:
+    """Slot-cache axes ``(L, S, H, Dh, M)`` -> batch-cache axes."""
+    return slots.transpose(0, 1, 4, 2, 3)
 
 
 def prefill_into_cache(params: dict, cfg: TransformerConfig, cache: dict,
@@ -346,17 +365,16 @@ def prefill_into_cache(params: dict, cfg: TransformerConfig, cache: dict,
     logits (the caller samples the first generated token from them)
     and the updated slot cache.
     """
-    M = cache["k"].shape[2]
+    M = cache["k"].shape[-1]
     logits, row = prefill(params, tokens, cfg, max_len=M)
     slot = jnp.asarray(slot, jnp.int32)
     at = (0, slot, 0, 0, 0)
     cache = {
-        "k": lax.dynamic_update_slice(
-            cache["k"], row["k"].astype(cache["k"].dtype), at
-        ),
-        "v": lax.dynamic_update_slice(
-            cache["v"], row["v"].astype(cache["v"].dtype), at
-        ),
+        part: lax.dynamic_update_slice(
+            cache[part], rows_to_slots(row[part]).astype(cache[part].dtype),
+            at,
+        )
+        for part in ("k", "v")
     }
     return logits[:, tokens.shape[1] - 1], cache
 
@@ -376,12 +394,12 @@ def copy_cache_slot(cache: dict, src, dst) -> dict:
     beyond the decode frontier (the same argument that makes slot
     reuse safe).
     """
-    L, _, M, H, Dh = cache["k"].shape
+    L, _, H, Dh, M = cache["k"].shape
     src = jnp.asarray(src, jnp.int32)
     dst = jnp.asarray(dst, jnp.int32)
     at_src = (0, src, 0, 0, 0)
     at_dst = (0, dst, 0, 0, 0)
-    size = (L, 1, M, H, Dh)
+    size = (L, 1, H, Dh, M)
     return {
         "k": lax.dynamic_update_slice(
             cache["k"], lax.dynamic_slice(cache["k"], at_src, size), at_dst
@@ -406,9 +424,9 @@ def prefill_chunk_into_cache(params: dict, cfg: TransformerConfig,
     form) — the continuous scheduler routes EVERY admission through
     this kernel so cache-on and cache-off prefills share one numeric
     path and the greedy bit-parity anchor holds by construction.
-    Numerics deliberately mirror :func:`decode_blocks_slots` (same
-    casts, same f32 score/softmax order, reduction over the full
-    ``max_len`` key extent) for the same reason.
+    Numerics deliberately mirror :func:`decode_blocks` (same casts,
+    same f32 score/softmax order, reduction over the full ``max_len``
+    key extent) for the same reason.
 
     ``slot`` and ``start`` are traced: one compile per chunk LENGTH
     covers every slot and every chunk position. Returns
@@ -418,7 +436,7 @@ def prefill_chunk_into_cache(params: dict, cfg: TransformerConfig,
     """
     with jax.named_scope("params.cast"):
         params = cfg.cast_params(params)
-    Lc, S, M, H, Dh = cache["k"].shape
+    Lc, S, H, Dh, M = cache["k"].shape
     C = tokens.shape[1]
     D = cfg.d_model
     slot = jnp.asarray(slot, jnp.int32)
@@ -434,12 +452,14 @@ def prefill_chunk_into_cache(params: dict, cfg: TransformerConfig,
         jnp.arange(M)[None, :]
         <= (start + jnp.arange(C))[:, None]
     )  # (C, M)
-    k_rows = lax.dynamic_slice(
-        cache["k"], (0, slot, 0, 0, 0), (Lc, 1, M, H, Dh)
-    )
-    v_rows = lax.dynamic_slice(
-        cache["v"], (0, slot, 0, 0, 0), (Lc, 1, M, H, Dh)
-    )
+    # The slot's own extent, one prompt's worth, in the batch cache's
+    # axes: small enough that the compiler lays it out as it likes.
+    k_rows = slots_to_rows(lax.dynamic_slice(
+        cache["k"], (0, slot, 0, 0, 0), (Lc, 1, H, Dh, M)
+    ))
+    v_rows = slots_to_rows(lax.dynamic_slice(
+        cache["v"], (0, slot, 0, 0, 0), (Lc, 1, H, Dh, M)
+    ))
 
     def body(carry, inputs):
         x = carry
@@ -474,8 +494,12 @@ def prefill_chunk_into_cache(params: dict, cfg: TransformerConfig,
     x, (ks, vs) = lax.scan(body, x, (params["blocks"], k_rows, v_rows))
     with jax.named_scope("kv.write"):
         cache = {
-            "k": lax.dynamic_update_slice(cache["k"], ks, (0, slot, 0, 0, 0)),
-            "v": lax.dynamic_update_slice(cache["v"], vs, (0, slot, 0, 0, 0)),
+            "k": lax.dynamic_update_slice(
+                cache["k"], rows_to_slots(ks), (0, slot, 0, 0, 0)
+            ),
+            "v": lax.dynamic_update_slice(
+                cache["v"], rows_to_slots(vs), (0, slot, 0, 0, 0)
+            ),
         }
     with jax.named_scope("unembed"):
         logits = unembed(params, x)[:, C - 1]
@@ -486,54 +510,68 @@ def decode_blocks_slots(blocks: dict, cache: dict, pos: jnp.ndarray,
                         x: jnp.ndarray, cfg: TransformerConfig,
                         active: jnp.ndarray):
     """One decode step through a stacked block group with PER-SLOT
-    positions: ``x (S, 1, D)`` attends against each slot's cache,
-    updated at ``pos[s]`` for active slots only.
+    positions: ``x (S, 1, D)`` attends against the cache of slots
+    ``[0, S)`` (the cache may hold more: the scheduler's prefix pool
+    rides behind them and is neither read nor written here), and the
+    token's own key and value land at ``pos[s]`` for active slots only.
 
-    The scalar-``pos`` :func:`decode_blocks` writes with one
-    ``dynamic_update_slice`` because every row shares a position; here
-    each slot is at its own depth, so the write is a masked select
-    over the length axis (``pos[s]``'s one-hot ∧ ``active[s]``) — the
-    same static-shape, no-scatter idiom as the attention mask, and a
-    retired slot writes nothing at all. Attention masks positions
-    ``> pos[s]`` per slot, so stale K/V beyond a slot's frontier is
-    unreachable even before its next occupant's prefill overwrites it.
+    The cache is READ where it lies: the layer scan closes over it and
+    slices its layer out inside the body, so no layer is carried from
+    ``xs`` to ``ys`` (which the compiler can only do through a second
+    whole cache). A layer's attention is one softmax over the ``pos[s]``
+    stored keys (positions ``>= pos[s]`` masked: stale K/V beyond a
+    slot's frontier is unreachable) and, as one more column, the
+    token's own key — the same ``pos[s] + 1`` keys the scalar-``pos``
+    :func:`decode_blocks` sees after its write. The scan's ``ys`` are
+    the new ``(S, H, Dh)`` key and value of each layer, which
+    :func:`~tpu_dist_nn.kernels.kv_write.write_rows` lands after it, in
+    place; a retired slot's cache stays bit for bit.
     """
     S = x.shape[0]
     H, Dh = cfg.n_heads, cfg.head_dim
-    M = cache["k"].shape[2]
-    write = (
-        (jnp.arange(M)[None, :] == pos[:, None]) & active[:, None]
-    )[:, :, None, None]  # (S, M, 1, 1)
-    live = jnp.arange(M)[None, :] <= pos[:, None]  # (S, M)
+    k_cache, v_cache = cache["k"], cache["v"]
+    L, _, _, _, M = k_cache.shape
+    stored = jnp.arange(M)[None, :] < pos[:, None]  # (S, M)
 
     def body(carry, inputs):
         x = carry
-        block, k_cache, v_cache = inputs
+        block, layer = inputs
         h = layer_norm(x, block["ln1_g"], block["ln1_b"])
         qkv = h @ block["w_qkv"] + block["b_qkv"]
         q, k, v = jnp.split(qkv.reshape(S, 1, 3 * H, Dh), 3, axis=2)
-        with jax.named_scope("kv.write"):
-            k_cache = jnp.where(write, k.astype(k_cache.dtype), k_cache)
-            v_cache = jnp.where(write, v.astype(v_cache.dtype), v_cache)
+        k, v = k.astype(k_cache.dtype), v.astype(v_cache.dtype)
+        at, size = (layer, 0, 0, 0, 0), (1, S, H, Dh, M)
         with jax.named_scope("attn.scores"):
+            q = q.astype(jnp.float32)
             scores = jnp.einsum(
-                "bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                k_cache.astype(jnp.float32),
+                "bqhd,bhdk->bhqk", q,
+                lax.dynamic_slice(k_cache, at, size)[0].astype(jnp.float32),
             ) / np.sqrt(Dh)
+            own = jnp.einsum(
+                "bqhd,bqhd->bhq", q, k.astype(jnp.float32)
+            )[..., None] / np.sqrt(Dh)
         with jax.named_scope("attn.softmax"):
-            scores = jnp.where(live[:, None, None, :], scores, -jnp.inf)
-            probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+            scores = jnp.where(stored[:, None, None, :], scores, -jnp.inf)
+            probs = jax.nn.softmax(
+                jnp.concatenate([scores, own], axis=-1), axis=-1
+            ).astype(x.dtype)
         with jax.named_scope("attn.values"):
             o = jnp.einsum(
-                "bhqk,bkhd->bqhd", probs, v_cache
-            ).reshape(S, 1, H * Dh)
+                "bhqk,bhdk->bqhd", probs[..., :M],
+                lax.dynamic_slice(v_cache, at, size)[0],
+                preferred_element_type=jnp.float32,
+            ) + (probs[..., M:].transpose(0, 2, 1, 3).astype(jnp.float32)
+                 * v.astype(jnp.float32))
+            o = o.astype(x.dtype).reshape(S, 1, H * Dh)
             x = x + o @ block["w_o"] + block["b_o"]
         with jax.named_scope("ffn"):
             x = ffn_sublayer(block, x)
-        return x, (k_cache, v_cache)
+        return x, (k[:, 0], v[:, 0])
 
-    x, (ks, vs) = lax.scan(body, x, (blocks, cache["k"], cache["v"]))
-    return x, {"k": ks, "v": vs}
+    x, (ks, vs) = lax.scan(body, x, (blocks, jnp.arange(L)))
+    with jax.named_scope("kv.write"):
+        k_cache, v_cache = write_rows(k_cache, v_cache, ks, vs, pos, active)
+    return x, {"k": k_cache, "v": v_cache}
 
 
 def decode_step_slots(params: dict, cache: dict, pos: jnp.ndarray,
@@ -544,10 +582,12 @@ def decode_step_slots(params: dict, cache: dict, pos: jnp.ndarray,
 
     The slot-cache analogue of :func:`decode_step` (with
     ``pos = full(S, p)`` and all slots active it computes the same
-    logits and cache). Retired slots cost nothing correctness-wise:
-    their cache is not written, their logits are garbage the scheduler
-    never samples from, and their (clipped) position only bounds the
-    attention mask of a slot nobody reads.
+    logits, to rounding, and writes the same rows). Retired slots cost
+    nothing correctness-wise: their cache is not written, their logits
+    are garbage the scheduler never samples from, and their (clipped)
+    position only bounds the attention mask of a slot nobody reads.
+    ``cache`` may hold more slots than ``token`` has entries: the step
+    is over the first ``S``.
 
     Returns ``(logits (S, V), cache)``.
     """

@@ -10,7 +10,7 @@ next batch start — a 4-token request pays for its 32-token neighbor,
 and late arrivals convoy behind the whole batch. This module schedules
 at DECODE-STEP granularity instead:
 
-* One fixed ``(L, S + P, max_len, H, Dh)`` slot KV cache
+* One fixed ``(L, S + P, H, Dh, max_len)`` slot KV cache
   (:func:`~tpu_dist_nn.models.generate.init_slot_cache`) holds ``S``
   independent request slots plus ``P`` reserved PREFIX-POOL blocks
   (``--prefix-cache-blocks``). Shapes never change — admission and
@@ -164,6 +164,70 @@ _SCHED_IDS = itertools.count(1)
 _HOST = tuple(i for i, p in enumerate(_trace.LOOP_PHASES)
               if p in _trace.LOOP_HOST_PHASES)
 _SPAN_NAMES = tuple("tdn.gen." + p for p in _trace.LOOP_PHASES)
+
+
+def slot_kernels(cfg, temperature, top_k, top_p):
+    """The three jitted programs that share one slot cache (request
+    slots, then the prefix pool's): ``(prefill_chunk, copy, step)``. Module-level so a tool can
+    compile exactly what the scheduler launches without building one
+    (``tools/aot_step_ops.py``). The cache is LINEAR through the
+    scheduler (one owner, always rebound to the kernel's output), so
+    every kernel donates it. Donation lets a program write into the
+    buffer it was given; it does not make it do so — the step did not
+    until it stopped carrying the cache through its layer scan — so
+    ``tools/aot_step_ops.py`` lists what each compiled program still
+    does at cache size, and tests/test_tpu_compile.py holds the step
+    to its in-place write.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_dist_nn.models.generate import (
+        _truncate_logits,
+        copy_cache_slot,
+        decode_step_slots,
+        prefill_chunk_into_cache,
+    )
+
+    top_k = None if top_k is None else int(top_k)
+    top_p = None if top_p is None else float(top_p)
+
+    @jax.named_scope("sample")
+    def sample(logits, key):
+        if temperature == 0:
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        logits = _truncate_logits(logits, top_k, top_p)
+        return jax.random.categorical(
+            key, logits / temperature, axis=-1
+        ).astype(jnp.int32)
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def prefill_chunk(params, cache, slot, tokens, start, key):
+        logits, cache = prefill_chunk_into_cache(
+            params, cfg, cache, slot, tokens, start
+        )
+        return sample(logits, key)[0], cache
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def step(params, cache, pos, active, tok, key):
+        # Decode advances the REQUEST region only: `pos`, `active` and
+        # `tok` have S entries, and the step reads and writes slots
+        # [0, S) of the one buffer where they lie. The pool blocks past
+        # slot S hold cached prefixes, not decoding sequences, and are
+        # not touched.
+        logits, cache = decode_step_slots(
+            params, cache, pos, tok, cfg, active=active
+        )
+        # Numeric guard folded into the SAME launch: one fused
+        # isfinite reduction over the logits per slot (an (S,) bool
+        # riding the step's existing device->host sync — always
+        # computed so the compiled kernel never depends on the
+        # runtime GUARD toggle; acting on it is a host decision).
+        with jax.named_scope("guard"):
+            ok = jnp.isfinite(logits).all(axis=-1)
+        return sample(logits, key), ok, cache
+
+    return prefill_chunk, jax.jit(copy_cache_slot, donate_argnums=(0,)), step
 
 
 class _LoopClock:
@@ -562,16 +626,7 @@ class ContinuousScheduler:
     # ------------------------------------------------------------ kernels
 
     def _build_kernels(self, cfg, temperature, top_k, top_p) -> None:
-        import jax
-        import jax.numpy as jnp
-
-        from tpu_dist_nn.models.generate import (
-            _truncate_logits,
-            copy_cache_slot,
-            decode_step_slots,
-            init_slot_cache,
-            prefill_chunk_into_cache,
-        )
+        from tpu_dist_nn.models.generate import init_slot_cache
 
         # The last decode writes position T + N - 2 (generate()'s cache
         # sizing rule), so the slot extent is total - 1. The prefix
@@ -581,69 +636,15 @@ class ContinuousScheduler:
         self._make_cache = lambda: init_slot_cache(cfg, self._S + self._P, M)
         self._cache = self._make_cache()
         # Goodput FLOP model at the kernels' static shapes: the decode
-        # step runs the REQUEST region only (pool blocks are sliced out
-        # — decode_step_slots sees S slots, extent M), so the model's
-        # extent is M regardless of prefix_cache_blocks. Peak resolves
-        # here, at configure time, never on a sampler tick.
+        # step runs the REQUEST region only (S slots of extent M; the
+        # pool blocks behind them are not read), so the model's extent
+        # is M regardless of prefix_cache_blocks. Peak resolves here,
+        # at configure time, never on a sampler tick.
         self._gp_model = LMFlopModel.from_config(cfg, M)
         GOODPUT.ensure_peak(device_count=1)  # slot cache is single-chip
-        top_k = None if top_k is None else int(top_k)
-        top_p = None if top_p is None else float(top_p)
-
-        @jax.named_scope("sample")
-        def sample(logits, key):
-            if temperature == 0:
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            logits = _truncate_logits(logits, top_k, top_p)
-            return jax.random.categorical(
-                key, logits / temperature, axis=-1
-            ).astype(jnp.int32)
-
-        # The cache is LINEAR through the scheduler (one owner, always
-        # rebound to the kernel's output), so its buffer is DONATED to
-        # every kernel: XLA updates it in place instead of copying the
-        # whole (L, S+P, M, H, Dh) pytree per launch — per-launch cost
-        # that would otherwise dwarf a small chunk's compute.
-        @functools.partial(jax.jit, donate_argnums=(1,))
-        def prefill_chunk(params, cache, slot, tokens, start, key):
-            logits, cache = prefill_chunk_into_cache(
-                params, cfg, cache, slot, tokens, start
-            )
-            return sample(logits, key)[0], cache
-
-        self._prefill = prefill_chunk
-        self._copy = jax.jit(copy_cache_slot, donate_argnums=(0,))
-        S, P = self._S, self._P
-
-        @functools.partial(jax.jit, donate_argnums=(1,))
-        def step(params, cache, pos, active, tok, key):
-            # Decode advances the REQUEST region only: the pool blocks
-            # past slot S hold cached prefixes, not decoding sequences
-            # — running them through the step kernel would burn FLOPs
-            # on dead slots every token.
-            if P:
-                head = {"k": cache["k"][:, :S], "v": cache["v"][:, :S]}
-                logits, head = decode_step_slots(
-                    params, head, pos, tok, cfg, active=active
-                )
-                cache = {
-                    "k": cache["k"].at[:, :S].set(head["k"]),
-                    "v": cache["v"].at[:, :S].set(head["v"]),
-                }
-            else:
-                logits, cache = decode_step_slots(
-                    params, cache, pos, tok, cfg, active=active
-                )
-            # Numeric guard folded into the SAME launch: one fused
-            # isfinite reduction over the logits per slot (an (S,) bool
-            # riding the step's existing device->host sync — always
-            # computed so the compiled kernel never depends on the
-            # runtime GUARD toggle; acting on it is a host decision).
-            with jax.named_scope("guard"):
-                ok = jnp.isfinite(logits).all(axis=-1)
-            return sample(logits, key), ok, cache
-
-        self._step = step
+        self._prefill, self._copy, self._step = slot_kernels(
+            cfg, temperature, top_k, top_p
+        )
 
     def _next_key(self):
         """A fresh fold of the base key per sampling event (prefill or
