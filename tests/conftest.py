@@ -167,6 +167,10 @@ QUICK_TESTS = {
                            "test_keras_round_trip"],
     "test_kernels": ["test_matches_jnp[relu]", "test_shape_mismatch_raises"],
     # PR 25: the in-place K/V row write, partial last lane block.
+    # PR 26: the second block family against its plain reference.
+    "test_sala": ["test_forward_matches_reference",
+                  "test_prefill_then_decode_matches_full_forward[37]",
+                  "test_rebinding_a_slot_leaves_no_state_behind"],
     "test_kv_write": [
         "test_write_rows_lands_rows_and_nothing_else[partial_lane_block]"],
     "test_multihost_real": ["test_two_process_collectives"],

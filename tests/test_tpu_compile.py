@@ -142,6 +142,53 @@ def _slot_step(S, jnp):
     return step, args, check
 
 
+def _sala_step(S, jnp):
+    """The scheduler's step program for the MiniCPM-SALA family at the
+    published attention widths (32 query heads on 2 K/V heads of 128, 32
+    lightning heads of 128) and the benchmark cell's 16 slots of extent
+    33 023, four layers deep on a narrow residual, with a prefix pool:
+    a Mosaic call writes the K/V rows, and nothing else in the compiled
+    program yields a layer of K or of V (no gather, slice or re-layout
+    of the rows the selection and the attention read where they lie)."""
+    import jax
+
+    from tools.aot_step_ops import big_ops
+    from tpu_dist_nn.models import sala
+    from tpu_dist_nn.serving.continuous import slot_kernels
+
+    cfg = sala.SalaConfig(
+        vocab_size=1024, hidden_size=1024, intermediate_size=2048,
+        n_heads=32, n_kv_heads=2, head_dim=128, lightning_heads=32,
+        lightning_head_dim=128,
+        mixer_types=(sala.SPARSE, sala.LIGHTNING, sala.LIGHTNING,
+                     sala.SPARSE),
+        layer_ids=(9, 10, 15, 16), published_layers=32, max_seq_len=524288,
+        scale_emb=12.0, scale_depth=1.4,
+    )
+    slots, pool, extent = 16, 2, 33023
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: S(a.shape, a.dtype), tree)
+
+    params = shaped(jax.eval_shape(
+        lambda: sala.init_sala(jax.random.key(0), cfg)))
+    cache = shaped(jax.eval_shape(
+        lambda: sala.init_slot_cache(cfg, slots + pool, extent)))
+    key = shaped(jax.eval_shape(lambda: jax.random.key(0)))
+    _, _, step = slot_kernels(cfg, 0.0, None, None)
+    ints = S((slots,), jnp.int32)
+
+    def check(text):
+        layer = slots * cfg.n_kv_heads * cfg.head_dim * 33024
+        found = big_ops(text, layer)["ops"]
+        return [f"{op['name']} ({op['opcode']}) {op['shapes']}"
+                for op in found
+                if op["opcode"] != "custom-call tpu_custom_call"]
+
+    args = (params, cache, ints, S((slots,), jnp.bool_), ints, key)
+    return step, args, check
+
+
 CASES = {
     "fused_dense_256x784x128_relu": _fused_dense(256, 784, 128, "relu"),
     "fused_dense_256x64x10_softmax": _fused_dense(256, 64, 10, "softmax"),
@@ -162,6 +209,7 @@ CASES = {
     "flash_ceiling_d256_f32_grad": _flash(None, 2, 256, "float32", True),
     "kv_write_h20_d64_m383_bf16": _kv_write,
     "slot_step_h20_d1280_in_place": _slot_step,
+    "sala_step_g2_d128_m33023_in_place": _sala_step,
 }
 
 

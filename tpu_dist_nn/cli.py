@@ -1438,6 +1438,137 @@ def _lm_stream_demo(args) -> int:
         client.close()
 
 
+def _lm_serve_model_config(args) -> int:
+    """``tdn lm --model-config FILE --serve-generate PORT``: no training;
+    the architecture the file describes, with seeded random weights,
+    through ``serve_lm_generate`` and the continuous scheduler."""
+    import jax
+
+    from tpu_dist_nn.models.sala import (
+        init_sala,
+        load_model_config,
+        num_params,
+    )
+    from tpu_dist_nn.serving.resilience import GracefulDrain
+
+    if getattr(args, "serve_generate", None) is None:
+        raise ValueError(
+            "--model-config serves a model; give --serve-generate PORT")
+    _apply_trace_sample_rate(args)
+    _validate_slo_flags(args, needs="serve-generate")
+    _validate_incident_flags(args, needs="serve-generate")
+    cfg = load_model_config(args.model_config)
+    params = init_sala(jax.random.key(args.seed), cfg)
+    report = {"model_config": args.model_config,
+              "params": num_params(cfg), "param_dtype": cfg.param_dtype,
+              "layers": list(cfg.mixer_types)}
+    drain = GracefulDrain(grace_seconds=args.drain_grace_seconds)
+    metrics_server = _start_metrics_server(
+        args, health_fn=drain.wrap_health(None)
+    )
+    return _serve_generate_and_wait(
+        args, params, cfg, report, drain, metrics_server
+    )
+
+
+def _serve_generate_and_wait(args, params, cfg, report: dict, drain,
+                             metrics_server) -> int:
+    """Serve generation from ``params`` behind ``--serve-generate`` and
+    wait for termination: the tail of ``tdn lm``, shared by the trained
+    LM and a ``--model-config`` model."""
+    # Serve GENERATION from the just-trained params (VERDICT r4
+    # item 7: the continuous-batching decoder behind the serving
+    # layer). The port is printed in the JSON line BEFORE blocking
+    # so drivers/tests can connect.
+    from tpu_dist_nn.serving import serve_lm_generate
+
+    # (Flag combination fully validated pre-training, top of cmd_lm.)
+    server, bound = serve_lm_generate(
+        params, cfg, args.serve_generate,
+        max_new_tokens=args.serve_new_tokens,
+        prompt_len=args.serve_prompt_len,
+        num_stages=args.serve_stages,
+        num_groups=args.serve_groups,
+        temperature=args.temperature, top_k=args.top_k,
+        top_p=args.top_p, seed=args.seed,
+        max_pending_rows=args.max_pending_rows,
+        class_watermarks=_parse_class_watermarks(
+            getattr(args, "class_watermarks", None)
+        ),
+        scheduler=args.scheduler, gen_slots=args.gen_slots,
+        eos_id=args.eos_id,
+        prefix_cache_blocks=args.prefix_cache_blocks,
+        prefill_chunk=args.prefill_chunk,
+        # Continuous mode: open the port hot (warm compiles exactly
+        # the prefill-at-slot + step kernels). The static arm keeps
+        # its cold default — its bucket ladder warm is opt-in.
+        warm_rows=(
+            1 if args.scheduler == "continuous"
+            or (args.scheduler == "auto" and args.serve_stages == 1)
+            else 0
+        ),
+    )
+    # SIGTERM → graceful drain (healthz NOT_SERVING, stop
+    # accepting, finish in-flight) instead of hard-killing decodes.
+    drain.add_server(server)
+    drain.install_signal_handler()
+    report["serving"] = {
+        "port": bound,
+        "prompt_len": args.serve_prompt_len,
+        "max_new_tokens": args.serve_new_tokens,
+        "stages": args.serve_stages,
+        "scheduler": (
+            "continuous" if server.scheduler is not None else "static"
+        ),
+    }
+    if server.scheduler is not None:
+        report["serving"]["gen_slots"] = args.gen_slots
+        report["serving"]["prefix_cache_blocks"] = \
+            args.prefix_cache_blocks
+        report["serving"]["prefill_chunk"] = args.prefill_chunk
+    sampler = None
+    if metrics_server is not None and server.batcher is not None:
+        from tpu_dist_nn.obs import RuntimeSampler, TRACER
+
+        sampler = RuntimeSampler()
+        sampler.add_batcher(server.batcher, method="Generate")
+        if server.scheduler is not None:
+            sampler.add_generation_scheduler(server.scheduler)
+        sampler.add_tracer(TRACER)
+        # Fleet observability plane for the generation endpoint:
+        # the latency SLO covers submit -> retirement (the wire
+        # figure a client sees), availability the Generate aborts.
+        ring, tracker = _wire_fleet_obs(
+            args, metrics_server, sampler,
+            latency_family="tdn_batch_wait_seconds",
+            latency_match={"method": "Generate"},
+            availability_kwargs={
+                "total_family": "tdn_rpc_requests_total",
+                "bad_family": "tdn_rpc_errors_total",
+            },
+            scheduler=server.batcher,
+        )
+        # Flight recorder over the generation endpoint: a burn,
+        # shed storm, or crash mid-decode leaves its bundle.
+        _wire_incident_recorder(args, metrics_server, sampler,
+                                ring, tracker)
+        sampler.start()
+        _attach_metrics_sampler(metrics_server, sampler)
+    print(json.dumps(report), flush=True)
+    try:
+        if args.serve_seconds is not None:
+            # A SIGTERM-initiated drain ends the wait early.
+            drain.wait(args.serve_seconds)
+        else:
+            server.wait_for_termination()
+    except KeyboardInterrupt:
+        pass
+    drain.begin()
+    drain.wait(args.drain_grace_seconds + 10.0)
+    _stop_metrics_server(metrics_server, sampler)
+    return 0
+
+
 def cmd_lm(args) -> int:
     """Train + evaluate the Tiny-Transformer LM (BASELINE configs[4]).
 
@@ -1452,6 +1583,8 @@ def cmd_lm(args) -> int:
         # Client-only streaming demo: nothing below (training, model
         # construction) applies — bail before the heavy imports.
         return _lm_stream_demo(args)
+    if getattr(args, "model_config", None):
+        return _lm_serve_model_config(args)
     import jax
 
     from tpu_dist_nn.data.text import lm_sequences, load_corpus, encode
@@ -2341,97 +2474,9 @@ def cmd_lm(args) -> int:
                 sample_row = sample_row[:hits[0]]
         report["sample"] = decode_text(sample_row)
     if getattr(args, "serve_generate", None) is not None:
-        # Serve GENERATION from the just-trained params (VERDICT r4
-        # item 7: the continuous-batching decoder behind the serving
-        # layer). The port is printed in the JSON line BEFORE blocking
-        # so drivers/tests can connect.
-        from tpu_dist_nn.serving import serve_lm_generate
-
-        # (Flag combination fully validated pre-training, top of cmd_lm.)
-        server, bound = serve_lm_generate(
-            params, cfg, args.serve_generate,
-            max_new_tokens=args.serve_new_tokens,
-            prompt_len=args.serve_prompt_len,
-            num_stages=args.serve_stages,
-            num_groups=args.serve_groups,
-            temperature=args.temperature, top_k=args.top_k,
-            top_p=args.top_p, seed=args.seed,
-            max_pending_rows=args.max_pending_rows,
-            class_watermarks=_parse_class_watermarks(
-                getattr(args, "class_watermarks", None)
-            ),
-            scheduler=args.scheduler, gen_slots=args.gen_slots,
-            eos_id=args.eos_id,
-            prefix_cache_blocks=args.prefix_cache_blocks,
-            prefill_chunk=args.prefill_chunk,
-            # Continuous mode: open the port hot (warm compiles exactly
-            # the prefill-at-slot + step kernels). The static arm keeps
-            # its cold default — its bucket ladder warm is opt-in.
-            warm_rows=(
-                1 if args.scheduler == "continuous"
-                or (args.scheduler == "auto" and args.serve_stages == 1)
-                else 0
-            ),
+        return _serve_generate_and_wait(
+            args, params, cfg, report, drain, metrics_server
         )
-        # SIGTERM → graceful drain (healthz NOT_SERVING, stop
-        # accepting, finish in-flight) instead of hard-killing decodes.
-        drain.add_server(server)
-        drain.install_signal_handler()
-        report["serving"] = {
-            "port": bound,
-            "prompt_len": args.serve_prompt_len,
-            "max_new_tokens": args.serve_new_tokens,
-            "stages": args.serve_stages,
-            "scheduler": (
-                "continuous" if server.scheduler is not None else "static"
-            ),
-        }
-        if server.scheduler is not None:
-            report["serving"]["gen_slots"] = args.gen_slots
-            report["serving"]["prefix_cache_blocks"] = \
-                args.prefix_cache_blocks
-            report["serving"]["prefill_chunk"] = args.prefill_chunk
-        sampler = None
-        if metrics_server is not None and server.batcher is not None:
-            from tpu_dist_nn.obs import RuntimeSampler, TRACER
-
-            sampler = RuntimeSampler()
-            sampler.add_batcher(server.batcher, method="Generate")
-            if server.scheduler is not None:
-                sampler.add_generation_scheduler(server.scheduler)
-            sampler.add_tracer(TRACER)
-            # Fleet observability plane for the generation endpoint:
-            # the latency SLO covers submit -> retirement (the wire
-            # figure a client sees), availability the Generate aborts.
-            ring, tracker = _wire_fleet_obs(
-                args, metrics_server, sampler,
-                latency_family="tdn_batch_wait_seconds",
-                latency_match={"method": "Generate"},
-                availability_kwargs={
-                    "total_family": "tdn_rpc_requests_total",
-                    "bad_family": "tdn_rpc_errors_total",
-                },
-                scheduler=server.batcher,
-            )
-            # Flight recorder over the generation endpoint: a burn,
-            # shed storm, or crash mid-decode leaves its bundle.
-            _wire_incident_recorder(args, metrics_server, sampler,
-                                    ring, tracker)
-            sampler.start()
-            _attach_metrics_sampler(metrics_server, sampler)
-        print(json.dumps(report), flush=True)
-        try:
-            if args.serve_seconds is not None:
-                # A SIGTERM-initiated drain ends the wait early.
-                drain.wait(args.serve_seconds)
-            else:
-                server.wait_for_termination()
-        except KeyboardInterrupt:
-            pass
-        drain.begin()
-        drain.wait(args.drain_grace_seconds + 10.0)
-        _stop_metrics_server(metrics_server, sampler)
-        return 0
     print(json.dumps(report))
     _stop_metrics_server(metrics_server)
     return 0
@@ -3960,6 +4005,13 @@ def build_parser() -> argparse.ArgumentParser:
                         "probability >= p")
     p.add_argument("--temperature", type=float, default=0.8,
                    help="0 = greedy")
+    p.add_argument("--model-config", default=None, metavar="FILE.json",
+                   help="serve a published architecture instead of "
+                        "training the Tiny-Transformer: a config.json in "
+                        "the source's own keys (model_type: minicpm_sala), "
+                        "seeded random weights in its param_dtype, served "
+                        "by --serve-generate on the continuous scheduler "
+                        "(docs/MODEL_CONFIG.md)")
     p.add_argument("--serve-generate", type=int, default=None,
                    metavar="PORT",
                    help="after training, serve GENERATION on this port "

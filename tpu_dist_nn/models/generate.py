@@ -340,6 +340,12 @@ def init_slot_cache(cfg: TransformerConfig, slots: int, max_len: int,
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+def cache_bytes(cache: dict) -> dict:
+    """Bytes of the slot cache by kind (``tdn_gen_cache_bytes``)."""
+    return {"kv": sum(int(a.size) * a.dtype.itemsize
+                      for a in (cache["k"], cache["v"]))}
+
+
 def rows_to_slots(rows: jnp.ndarray) -> jnp.ndarray:
     """Batch-cache axes ``(L, B, M, H, Dh)`` -> slot-cache axes."""
     return rows.transpose(0, 1, 3, 4, 2)

@@ -72,6 +72,22 @@ class TransformerConfig:
         dtype = jnp.dtype(self.compute_dtype)
         return jax.tree.map(lambda a: a.astype(dtype), params)
 
+    def slot_model(self):
+        """This block's functions for the continuous scheduler (the
+        protocol of :mod:`tpu_dist_nn.models.slot_model`)."""
+        from tpu_dist_nn.models import generate
+        from tpu_dist_nn.models.slot_model import SlotModel
+        from tpu_dist_nn.obs.goodput import LMFlopModel
+
+        return SlotModel(
+            init_slot_cache=generate.init_slot_cache,
+            prefill_chunk_into_cache=generate.prefill_chunk_into_cache,
+            decode_step_slots=generate.decode_step_slots,
+            copy_cache_slot=generate.copy_cache_slot,
+            flop_model=LMFlopModel.from_config,
+            cache_bytes=generate.cache_bytes,
+        )
+
 
 def init_transformer(key: jax.Array, cfg: TransformerConfig, dtype=jnp.float32):
     """Params pytree; block leaves are stacked on a leading n_layers axis."""

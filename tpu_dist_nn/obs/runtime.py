@@ -170,6 +170,26 @@ class RuntimeSampler:
             ),
         }
         self._gen_loop_seen: list[dict] = []
+        # Which path served each position, and what the slot cache holds
+        # by kind of state (models/slot_model.py): plain ints on the
+        # scheduler, ticked here by delta like the loop totals.
+        self._c_gen_positions = {
+            "sparse_positions_total": reg.counter(
+                "tdn_gen_sparse_positions_total",
+                "prefilled and decoded positions served by the model's "
+                "block selection (at or past its dense length)",
+            ),
+            "dense_positions_total": reg.counter(
+                "tdn_gen_dense_positions_total",
+                "prefilled and decoded positions served by dense attention",
+            ),
+        }
+        self._g_gen_cache = reg.gauge(
+            "tdn_gen_cache_bytes",
+            "bytes of the slot cache by kind of state: kv rows, "
+            "compressed keys, recurrent state",
+            labels=("kind",),
+        )
         # Router replica pools (serving/pool.py): the fleet-state
         # gauges nobody increments — per-replica outstanding requests
         # and the blended load view the placement policy compares.
@@ -397,6 +417,17 @@ class RuntimeSampler:
             )
             for s, seen in zip(self._gen_scheds, self._gen_loop_seen):
                 self._tick_gen_loop(s.loop_totals(), seen)
+                for field, counter in self._c_gen_positions.items():
+                    total = int(getattr(s, field, 0))
+                    if total > seen.get(field, 0):
+                        counter.inc(total - seen.get(field, 0))
+                        seen[field] = total
+            kinds: dict = {}
+            for s in self._gen_scheds:
+                for kind, n in getattr(s, "cache_bytes", {}).items():
+                    kinds[kind] = kinds.get(kind, 0) + int(n)
+            for kind, n in kinds.items():
+                self._g_gen_cache.labels(kind=kind).set(float(n))
         if self._pools:
             seen: set[str] = set()
             for pool in self._pools:

@@ -55,6 +55,14 @@ at DECODE-STEP granularity instead:
   the same scheduler iteration while the remaining slots keep
   decoding. Finished rows stream back to their waiters immediately.
 
+What a layer is stays with the model. The three programs are built from
+the four functions a model's config hands over with ``cfg.slot_model()``
+(:mod:`tpu_dist_nn.models.slot_model`; the names above are GPT-2's, in
+:mod:`~tpu_dist_nn.models.generate`; :mod:`~tpu_dist_nn.models.sala`
+brings a cache of K/V rows, compressed keys and recurrent state). A
+model whose cache holds recurrent state has prefix tiers inserted only
+where a chunk ended (docs/MODEL_CONFIG.md).
+
 Resilience contract (docs/ROBUSTNESS.md): the admission/shed/close/
 drain machinery is the SHARED scheduling core
 (:mod:`~tpu_dist_nn.serving.sched_core` — one implementation with the
@@ -93,7 +101,7 @@ import time
 import numpy as np
 
 from tpu_dist_nn.obs import trace as _trace
-from tpu_dist_nn.obs.goodput import GOODPUT, LMFlopModel
+from tpu_dist_nn.obs.goodput import GOODPUT
 from tpu_dist_nn.obs.log import get_logger
 from tpu_dist_nn.obs.registry import POW2_BUCKETS, REGISTRY
 from tpu_dist_nn.serving import integrity as _integrity
@@ -182,13 +190,11 @@ def slot_kernels(cfg, temperature, top_k, top_p):
     import jax
     import jax.numpy as jnp
 
-    from tpu_dist_nn.models.generate import (
-        _truncate_logits,
-        copy_cache_slot,
-        decode_step_slots,
-        prefill_chunk_into_cache,
-    )
+    from tpu_dist_nn.models.generate import _truncate_logits
 
+    # What a layer is stays with the model: its config hands over the
+    # functions the three programs are built from (models/slot_model.py).
+    model = cfg.slot_model()
     top_k = None if top_k is None else int(top_k)
     top_p = None if top_p is None else float(top_p)
 
@@ -203,7 +209,7 @@ def slot_kernels(cfg, temperature, top_k, top_p):
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def prefill_chunk(params, cache, slot, tokens, start, key):
-        logits, cache = prefill_chunk_into_cache(
+        logits, cache = model.prefill_chunk_into_cache(
             params, cfg, cache, slot, tokens, start
         )
         return sample(logits, key)[0], cache
@@ -215,7 +221,7 @@ def slot_kernels(cfg, temperature, top_k, top_p):
         # [0, S) of the one buffer where they lie. The pool blocks past
         # slot S hold cached prefixes, not decoding sequences, and are
         # not touched.
-        logits, cache = decode_step_slots(
+        logits, cache = model.decode_step_slots(
             params, cache, pos, tok, cfg, active=active
         )
         # Numeric guard folded into the SAME launch: one fused
@@ -227,7 +233,8 @@ def slot_kernels(cfg, temperature, top_k, top_p):
             ok = jnp.isfinite(logits).all(axis=-1)
         return sample(logits, key), ok, cache
 
-    return prefill_chunk, jax.jit(copy_cache_slot, donate_argnums=(0,)), step
+    return (prefill_chunk,
+            jax.jit(model.copy_cache_slot, donate_argnums=(0,)), step)
 
 
 class _LoopClock:
@@ -524,6 +531,10 @@ class ContinuousScheduler:
                 f"{self._chunk}) or raise prompt_len"
             )
         self._pool = PrefixCachePool(self._P) if self._P else None
+        # What the model says of its cache (models/slot_model.py);
+        # injected kernels carry no model and keep these.
+        self._tier_at_fill, self._sparse_positions = False, lambda pos: 0
+        self.cache_bytes: dict = {}
         if prefill_fn is not None or step_fn is not None:
             if prefill_fn is None or step_fn is None:
                 raise ValueError(
@@ -571,6 +582,12 @@ class ContinuousScheduler:
             self._build_kernels(
                 cfg, float(temperature), top_k, top_p,
             )
+        # Positions prefilled or decoded, by how the model says it
+        # serves them (`SlotModel.sparse_positions`): its block
+        # selection or dense attention.  From `pos` on the host; no
+        # device fetch.
+        self.sparse_positions_total = 0
+        self.dense_positions_total = 0
         # Host-side slot state: the loop thread is the only writer.
         # _active marks DECODING slots; a bound slot whose prefill is
         # still chunking has an occupant but is not yet active.
@@ -626,25 +643,42 @@ class ContinuousScheduler:
     # ------------------------------------------------------------ kernels
 
     def _build_kernels(self, cfg, temperature, top_k, top_p) -> None:
-        from tpu_dist_nn.models.generate import init_slot_cache
-
+        model = cfg.slot_model()
+        if model.recurrent and self._P and self._chunk is None:
+            raise ValueError(
+                "prefix_cache_blocks needs prefill_chunk for this model: "
+                "its cache holds recurrent state, which is a prefix's "
+                "only where a chunk ended, so a tier can be copied out "
+                "of a slot only at a chunk boundary"
+            )
+        self._tier_at_fill = model.recurrent
+        self._sparse_positions = model.sparse_positions
         # The last decode writes position T + N - 2 (generate()'s cache
         # sizing rule), so the slot extent is total - 1. The prefix
         # pool rides the SAME cache as P extra slots past the request
         # region — one allocation, one shape, one copy kernel.
         M = self._T + self._N - 1 if self._N > 1 else self._T
-        self._make_cache = lambda: init_slot_cache(cfg, self._S + self._P, M)
+        self._make_cache = lambda: model.init_slot_cache(
+            cfg, self._S + self._P, M)
         self._cache = self._make_cache()
+        self.cache_bytes = model.cache_bytes(self._cache)
         # Goodput FLOP model at the kernels' static shapes: the decode
         # step runs the REQUEST region only (S slots of extent M; the
         # pool blocks behind them are not read), so the model's extent
         # is M regardless of prefix_cache_blocks. Peak resolves here,
         # at configure time, never on a sampler tick.
-        self._gp_model = LMFlopModel.from_config(cfg, M)
+        self._gp_model = model.flop_model(cfg, M)
         GOODPUT.ensure_peak(device_count=1)  # slot cache is single-chip
         self._prefill, self._copy, self._step = slot_kernels(
             cfg, temperature, top_k, top_p
         )
+
+    def _count_positions(self, pos) -> None:
+        """Book the query positions ``pos`` of a chunk or a step under
+        the path the model says serves them."""
+        sparse = self._sparse_positions(pos)
+        self.sparse_positions_total += sparse
+        self.dense_positions_total += len(pos) - sparse
 
     def _next_key(self):
         """A fresh fold of the base key per sampling event (prefill or
@@ -1206,6 +1240,10 @@ class ContinuousScheduler:
         for length in reversed(self._tiers):  # ascending
             if not start < length <= occ["fill"]:
                 continue
+            if self._tier_at_fill and length != occ["fill"]:
+                # Recurrent state is the prefix's only where the chunk
+                # that just landed ended.
+                continue
             block, evicted = self._pool.insert(row[:length].tobytes(), length)
             if evicted:
                 _PREFIX_EVICTIONS.inc()
@@ -1270,6 +1308,7 @@ class ContinuousScheduler:
         clock.mark(_PREFILL_POST)
         occ["fill"] = start + size
         self.prefill_chunks_total += 1
+        self._count_positions(np.arange(start, start + size))
         if self._gp_model is not None:
             # A resume re-prefill's last-position logits are DISCARDED
             # (the first generated token is already known), so its
@@ -1426,6 +1465,7 @@ class ContinuousScheduler:
                 ))
         self.batches_total += 1
         active = clock.active_slots = int(self._active.sum())
+        self._count_positions(self._pos[self._active])
         self.slot_steps_total += active
         self._m_rows.observe(active)
         if self._gp_model is not None:

@@ -1246,6 +1246,7 @@ def serve_lm_generate(params, cfg, port: int, *, max_new_tokens: int,
     import jax
 
     from tpu_dist_nn.models.generate import validate_generate_args
+    from tpu_dist_nn.models.transformer import TransformerConfig
 
     if scheduler not in ("auto", "static", "continuous"):
         raise ValueError(
@@ -1272,6 +1273,12 @@ def serve_lm_generate(params, cfg, port: int, *, max_new_tokens: int,
         # silently consumed by the continuous default.
         scheduler = (
             "static" if num_stages > 1 or not coalesce else "continuous"
+        )
+    if scheduler != "continuous" and not isinstance(cfg, TransformerConfig):
+        raise ValueError(
+            f"scheduler={scheduler!r} decodes the Tiny-Transformer block "
+            f"only; a {type(cfg).__name__} model is served by "
+            "scheduler='continuous' (models/slot_model.py)"
         )
     if scheduler != "continuous" and (
         prefix_cache_blocks or prefill_chunk is not None
