@@ -173,6 +173,10 @@ QUICK_TESTS = {
                   "test_rebinding_a_slot_leaves_no_state_behind"],
     "test_kv_write": [
         "test_write_rows_lands_rows_and_nothing_else[partial_lane_block]"],
+    # PR 28: the chunk's block-masked attention kernel against the loop.
+    "test_sparse_attend": [
+        "test_kernel_matches_the_loop[1024-bfloat16]",
+        "test_the_shapes_alone_decide_which_path_runs[ragged-chunk]"],
     "test_multihost_real": ["test_two_process_collectives"],
     "test_native_codec": ["test_examples_roundtrip_and_parity",
                           "test_fuzz_model_roundtrip_native_vs_python"],

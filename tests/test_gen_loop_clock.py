@@ -96,7 +96,7 @@ def test_phase_totals_sum_to_the_loops_wall_time():
 def sampled_run():
     """Totals read from another thread while a scheduler works, then
     its records: [loop_totals() ...], [record ...]."""
-    sched = _fake_sched(step_cost=0.002, slots=2)
+    sched = _fake_sched(step_cost=0.002, slots=2, prefill_chunk=3)
     samples = []
     stop = threading.Event()
 
@@ -148,6 +148,24 @@ def test_records_carry_sequence_time_and_a_window_is_last_minus_first(
     inside = ITERATIONS.window(first["t_end"], last["t_end"])
     assert len([r for r in inside if r[0] == first["sched"]]) \
         == len(records) - 1
+
+
+def test_an_iterations_growth_of_the_prefill_totals_is_its_chunk():
+    """A prompt of 8 in chunks of 3, one slot: the records' neighbour
+    differences of `prefill_tokens` and `prefill_starts` are each
+    chunk's size and first position, 0 where the iteration only
+    decoded."""
+    sched = _fake_sched(slots=1, prefill_chunk=3)
+    sched.submit(_prompts(2))
+    sched.close()
+    records = _mine(sched)
+    zero = dict.fromkeys(("prefill_tokens", "prefill_starts"), 0)
+    chunks = [(b["prefill_starts"] - a["prefill_starts"],
+               b["prefill_tokens"] - a["prefill_tokens"], b["prefilled"])
+              for a, b in zip([zero] + records, records)]
+    assert [c[:2] for c in chunks if c[2]] == [(0, 3), (3, 3), (6, 2)] * 2
+    assert all(c[:2] == (0, 0) for c in chunks if not c[2])
+    assert sched.loop_totals()["prefill_tokens"] == 2 * T
 
 
 # -------------------------------------------------------------- ring

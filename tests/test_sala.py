@@ -28,6 +28,7 @@ import numpy as np
 import pytest
 
 from benchmark.harness import lookup
+from tpu_dist_nn.kernels import sparse_attend
 from tpu_dist_nn.models import sala
 from tpu_dist_nn.serving.continuous import ContinuousScheduler
 
@@ -141,13 +142,14 @@ def test_prefill_then_decode_bf16(weights, rows, full):
 
 # ---------------------------------------------------------- (iii) selection
 
-def _qkv(seed=5, n=T + N):
+def _qkv(seed=5, n=T + N, dim=16):
     """bfloat16-representable q, k, v as a sparse layer would hold them."""
     rng = np.random.default_rng(seed)
     cut = lambda a: np.asarray(  # noqa: E731
         jnp.asarray(a, jnp.bfloat16).astype(jnp.float32))
-    return (cut(rng.normal(size=(n, 8, 16))), cut(rng.normal(size=(n, 2, 16))),
-            cut(rng.normal(size=(n, 2, 16))))
+    return (cut(rng.normal(size=(n, 8, dim))),
+            cut(rng.normal(size=(n, 2, dim))),
+            cut(rng.normal(size=(n, 2, dim))))
 
 
 def _program_selection(q, k, n):
@@ -189,21 +191,37 @@ def test_block_scores_and_selected_sets_match_reference():
     assert sparse.sum() > 100 and not got_sel[sparse].all()  # it does select
 
 
-def test_attention_with_the_references_selection_forced():
-    q, k, v = _qkv(7)
-    n = len(q)
-    s = ref.sizes(TOY)
+# The toy, whose heads of 16 no TPU tile fits (the XLA loop), and the
+# same family with heads of 128, blocks of 64 and an extent of three
+# 128-lane tiles, where the chunk's attention is the Pallas kernel
+# (kernels/sparse_attend.py; interpret mode here).
+WIDE = dict(TOY, head_dim=128, sparse_config=dict(
+    TOY["sparse_config"], kernel_size=32, kernel_stride=16, block_size=64,
+    topk=2, window_size=128, dense_len=128))
+
+
+@pytest.mark.parametrize("toy, n, kernel", [(TOY, T + N, False),
+                                            (WIDE, 384, True)],
+                         ids=["loop", "kernel"])
+def test_attention_with_the_references_selection_forced(toy, n, kernel):
+    q, k, v = _qkv(7, n, toy["head_dim"])
+    s = ref.sizes(toy)
+    cfg = sala.SalaConfig.from_dict(dict(toy, param_dtype="float32"))
     M = -(-n // s["blk"]) * s["blk"]
     kbar = ref.compressed_keys(jnp.asarray(k), s)
     sel, _ = ref.select(jnp.asarray(q), kbar, jnp.arange(n), s, M // s["blk"])
     want = np.asarray(ref.attend(jnp.asarray(q), jnp.asarray(k),
                                  jnp.asarray(v), sel, jnp.arange(n), s))
+    assert not np.asarray(sel)[-1].all()  # the selection does select
     pad = ((0, 0), (0, 0), (0, M - n))
-    got = sala._attend_chunk(
-        jnp.asarray(q).reshape(n, 2, 4, 16),
-        jnp.pad(jnp.asarray(k).transpose(1, 2, 0), pad),
-        jnp.pad(jnp.asarray(v).transpose(1, 2, 0), pad), sel, jnp.arange(n),
-        CFG32)
+    rows = (jnp.asarray(q).reshape(n, 2, 4, s["Dh"]),
+            jnp.pad(jnp.asarray(k).transpose(1, 2, 0), pad),
+            jnp.pad(jnp.asarray(v).transpose(1, 2, 0), pad), sel)
+    assert sala.attend_kernel_tiles(cfg, n, M) is kernel
+    if kernel:
+        got = sparse_attend.attend_chunk(*rows, 0, cfg.block_size)
+    else:
+        got = sala._attend_chunk(*rows, jnp.arange(n), cfg)
     np.testing.assert_allclose(np.asarray(got), want, atol=1e-5)
 
 
@@ -313,6 +331,7 @@ def test_scheduler_streams_complete_with_lengths_as_asked(served):
     prompts, budgets, out, sched = served
     assert [len(o) for o in out] == budgets
     assert sched.retired_total == 3 and sched.prefill_chunks_total == 12
+    assert sched.attend_kernel_chunks_total == 0  # heads of 16: the loop
     assert all(0 <= t < 512 for o in out for t in o)
 
 

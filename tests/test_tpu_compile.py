@@ -142,17 +142,14 @@ def _slot_step(S, jnp):
     return step, args, check
 
 
-def _sala_step(S, jnp):
-    """The scheduler's step program for the MiniCPM-SALA family at the
-    published attention widths (32 query heads on 2 K/V heads of 128, 32
-    lightning heads of 128) and the benchmark cell's 16 slots of extent
-    33 023, four layers deep on a narrow residual, with a prefix pool:
-    a Mosaic call writes the K/V rows, and nothing else in the compiled
-    program yields a layer of K or of V (no gather, slice or re-layout
-    of the rows the selection and the attention read where they lie)."""
+def _sala_programs(S, jnp):
+    """The MiniCPM-SALA family at the published attention widths (32
+    query heads on 2 K/V heads of 128, 32 lightning heads of 128) and the
+    benchmark cell's 16 slots of extent 33 023, four layers deep on a
+    narrow residual, with a prefix pool: the scheduler's three programs,
+    and the shapes of their parameters, cache and key."""
     import jax
 
-    from tools.aot_step_ops import big_ops
     from tpu_dist_nn.models import sala
     from tpu_dist_nn.serving.continuous import slot_kernels
 
@@ -175,7 +172,17 @@ def _sala_step(S, jnp):
     cache = shaped(jax.eval_shape(
         lambda: sala.init_slot_cache(cfg, slots + pool, extent)))
     key = shaped(jax.eval_shape(lambda: jax.random.key(0)))
-    _, _, step = slot_kernels(cfg, 0.0, None, None)
+    return cfg, slots, slot_kernels(cfg, 0.0, None, None), params, cache, key
+
+
+def _sala_step(S, jnp):
+    """The scheduler's step program for that family: a Mosaic call
+    writes the K/V rows, and nothing else in the compiled program yields
+    a layer of K or of V (no gather, slice or re-layout of the rows the
+    selection and the attention read where they lie)."""
+    from tools.aot_step_ops import big_ops
+
+    cfg, slots, (_, _, step), params, cache, key = _sala_programs(S, jnp)
     ints = S((slots,), jnp.int32)
 
     def check(text):
@@ -187,6 +194,47 @@ def _sala_step(S, jnp):
 
     args = (params, cache, ints, S((slots,), jnp.bool_), ints, key)
     return step, args, check
+
+
+def _sala_chunk(S, jnp):
+    """The scheduler's chunk program for that family at the cell's
+    shapes (a chunk of 2048, `start` traced): the sparse layers'
+    attention is the Mosaic call of kernels/sparse_attend.py, which
+    fits VMEM at the tiles it picks (the compile would refuse it), and
+    nothing is left in HBM of the XLA loop's float32 temporaries `(G 2,
+    group 16, C 2048, ...)`: a key tile's scores, or the accumulator
+    that loop carried."""
+    from tools.aot_step_ops import big_ops
+
+    cfg, _, (chunk, _, _), params, cache, key = _sala_programs(S, jnp)
+    assert cfg.attend_kernel(2048, 33023)
+
+    def check(text):
+        problems = [] if "sparse_attend" in text else [
+            "no custom call named sparse_attend"]
+        found = big_ops(text, 2 * 16 * 2048 * 128)["ops"]
+        return problems + [
+            f"{op['name']} ({op['opcode']}) {op['shapes']}" for op in found
+            if any(d == "f32" and dims[:3] == (2, 16, 2048)
+                   for d, dims, _, _ in op["shapes"])]
+
+    args = (params, cache, S((), jnp.int32), S((1, 2048), jnp.int32),
+            S((), jnp.int32), key)
+    return chunk, args, check
+
+
+def _sparse_attend_small_tiles(S, jnp):
+    """The kernel alone at the smallest tiles its dispatch keeps (query
+    128, key 384: an extent of 1152 that 768 does not divide); the cell's
+    512 x 768 compiles inside `sala_chunk_...` below."""
+    from tpu_dist_nn.kernels import sparse_attend
+
+    assert sparse_attend.tiles(128, 16, 128, 1152, 64) == (128, 384)
+    rows = S((2, 128, 1152), jnp.bfloat16)
+    return (lambda q, k, v, sel, start: sparse_attend.attend_chunk(
+        q, k, v, sel, start, 64)), (
+        S((128, 2, 16, 128), jnp.bfloat16), rows, rows,
+        S((128, 2, 18), jnp.bool_), S((), jnp.int32))
 
 
 CASES = {
@@ -210,6 +258,8 @@ CASES = {
     "kv_write_h20_d64_m383_bf16": _kv_write,
     "slot_step_h20_d1280_in_place": _slot_step,
     "sala_step_g2_d128_m33023_in_place": _sala_step,
+    "sala_chunk_c2048_g2_d128_m33023_attend_kernel": _sala_chunk,
+    "sparse_attend_q128_k384_smallest_tiles": _sparse_attend_small_tiles,
 }
 
 

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from tpu_dist_nn.kernels import sparse_attend
 from tpu_dist_nn.kernels.kv_write import write_rows
 from tpu_dist_nn.models.slot_model import SlotModel
 
@@ -170,6 +171,7 @@ class SalaConfig:
             cache_bytes=cache_bytes,
             recurrent=self.n_lightning > 0,
             sparse_positions=self.sparse_positions,
+            attend_kernel=self.attend_kernel,
         )
 
     def sparse_positions(self, pos) -> int:
@@ -178,6 +180,13 @@ class SalaConfig:
         if not self.n_sparse:
             return 0
         return int((np.asarray(pos) >= self.dense_len).sum())
+
+    def attend_kernel(self, size, max_len) -> bool:
+        """Whether a chunk of ``size`` positions into a slot cache made
+        for ``max_len`` runs the sparse layers' attention as the Pallas
+        kernel: the dispatch of :func:`_sparse_chunk_layer`, asked from
+        outside the program."""
+        return attend_kernel_tiles(self, int(size), _extent(self, max_len))
 
     # ---------------------------------------------------------- loading
     @classmethod
@@ -473,6 +482,14 @@ def _attend_chunk(q, k_rows, v_rows, sel, t, cfg):
     return o.transpose(2, 0, 1, 3).reshape(C, G * g * Dh)
 
 
+def attend_kernel_tiles(cfg: SalaConfig, C: int, M: int) -> bool:
+    """Whether a chunk of ``C`` positions over a slot of ``M`` key
+    positions runs its block-masked attention as the Pallas kernel
+    (:mod:`tpu_dist_nn.kernels.sparse_attend`): only the shapes decide."""
+    return cfg.n_sparse > 0 and sparse_attend.tiles(
+        C, cfg.group, cfg.head_dim, M, cfg.block_size) is not None
+
+
 def _ring_after_chunk(tail, k_chunk, start):
     """The ring of the slot's last keys ``(G, Dh, ksz)`` (lane =
     position mod ksz) after a chunk ``k_chunk (C, G, Dh)`` at ``start``:
@@ -507,7 +524,13 @@ def _sparse_chunk_layer(x, blk, k_rows, v_rows, ck_rows, tail, start, cfg):
             / np.sqrt(cfg.head_dim)
         sel = select_blocks(s, t, cfg, M)
     with jax.named_scope("sala.sparse.attend"):
-        o = _attend_chunk(q, k_rows, v_rows, sel, t, cfg)
+        # Which path runs is read from the shapes: the Pallas kernel
+        # where they tile, the XLA loop (its oracle) where they do not.
+        if attend_kernel_tiles(cfg, C, M):
+            o = sparse_attend.attend_chunk(
+                q, k_rows, v_rows, sel, start, cfg.block_size)
+        else:
+            o = _attend_chunk(q, k_rows, v_rows, sel, t, cfg)
     x = _residual(x, (o * gate) @ blk["wo"], cfg)
     return _mlp(x, blk, cfg), k_rows, v_rows, ck_rows, tail
 
@@ -641,6 +664,13 @@ def _lightning_step_layer(x, blk, rate, state, pos, active, cfg):
 
 # ----------------------------------------------------------- slot cache
 
+def _extent(cfg: SalaConfig, max_len: int) -> int:
+    """``max_len`` rounded up to whole blocks (and to a whole kernel)."""
+    blk = cfg.block_size
+    return max(-(-int(max_len) // blk) * blk,
+               -(-cfg.kernel_size // blk) * blk)
+
+
 def init_slot_cache(cfg: SalaConfig, slots: int, max_len: int) -> dict:
     """The zeroed slot cache: per sparse layer K and V ``(S, G, Dh,
     M)``, compressed keys ``(S, G, Dh, M / stride)`` and a ring of the
@@ -653,8 +683,7 @@ def init_slot_cache(cfg: SalaConfig, slots: int, max_len: int) -> dict:
     if max_len < 1 or max_len > cfg.max_seq_len:
         raise ValueError(
             f"max_len must be in [1, {cfg.max_seq_len}], got {max_len}")
-    blk = cfg.block_size
-    M = max(-(-max_len // blk) * blk, -(-cfg.kernel_size // blk) * blk)
+    M = _extent(cfg, max_len)
     dtype = jnp.dtype(cfg.param_dtype)
     G, Dh = cfg.n_kv_heads, cfg.head_dim
     H, Dl = cfg.lightning_heads, cfg.lightning_head_dim

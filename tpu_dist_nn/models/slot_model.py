@@ -22,6 +22,10 @@ def _all_dense(pos) -> int:
     return 0
 
 
+def _no_kernel(size, max_len) -> bool:
+    return False
+
+
 @dataclasses.dataclass(frozen=True)
 class SlotModel:
     # (cfg, slots, max_len) -> cache pytree, every leaf (layers, slots, ...)
@@ -43,3 +47,8 @@ class SlotModel:
     # serves by its block selection and not by dense attention.  It
     # reads positions, not what the device ran.
     sparse_positions: Callable = _all_dense
+    # (size, max_len) -> whether the program of a chunk of `size`
+    # positions into a cache made for `max_len` is built with the
+    # model's attention kernel (kernels/sparse_attend.py) and not its
+    # XLA loop.  It reads the shapes, as the model's dispatch does.
+    attend_kernel: Callable = _no_kernel

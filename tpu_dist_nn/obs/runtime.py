@@ -170,10 +170,11 @@ class RuntimeSampler:
             ),
         }
         self._gen_loop_seen: list[dict] = []
-        # Which path served each position, and what the slot cache holds
-        # by kind of state (models/slot_model.py): plain ints on the
-        # scheduler, ticked here by delta like the loop totals.
-        self._c_gen_positions = {
+        # Which path served each position and each chunk, and what the
+        # slot cache holds by kind of state (models/slot_model.py):
+        # plain ints on the scheduler, ticked here by delta like the
+        # loop totals.
+        self._c_gen_counts = {
             "sparse_positions_total": reg.counter(
                 "tdn_gen_sparse_positions_total",
                 "prefilled and decoded positions served by the model's "
@@ -182,6 +183,18 @@ class RuntimeSampler:
             "dense_positions_total": reg.counter(
                 "tdn_gen_dense_positions_total",
                 "prefilled and decoded positions served by dense attention",
+            ),
+            # The share of chunk launches whose program holds the
+            # model's attention kernel: the second over the first.
+            "prefill_chunks_total": reg.counter(
+                "tdn_gen_prefill_chunks_total",
+                "prefill chunk launches",
+            ),
+            "attend_kernel_chunks_total": reg.counter(
+                "tdn_gen_attend_kernel_chunks_total",
+                "prefill chunk launches whose shapes tile for the model's "
+                "Pallas attention kernel (kernels/sparse_attend.py); the "
+                "rest ran its XLA loop, or the model has none",
             ),
         }
         self._g_gen_cache = reg.gauge(
@@ -417,7 +430,7 @@ class RuntimeSampler:
             )
             for s, seen in zip(self._gen_scheds, self._gen_loop_seen):
                 self._tick_gen_loop(s.loop_totals(), seen)
-                for field, counter in self._c_gen_positions.items():
+                for field, counter in self._c_gen_counts.items():
                     total = int(getattr(s, field, 0))
                     if total > seen.get(field, 0):
                         counter.inc(total - seen.get(field, 0))

@@ -589,6 +589,9 @@ ITER_FIELDS = (
     "queue_wait_ns", "binds",          # submit -> bind, fresh binds
     "prefill_wait_ns", "first_tokens",  # bind -> first token
     "stream_lag_ns", "stream_frames",  # publish -> handler took the frame
+    # Positions prefilled, and the first position of every chunk summed:
+    # an iteration's growth of the two is its chunk's size and start.
+    "prefill_tokens", "prefill_starts",
 )
 
 

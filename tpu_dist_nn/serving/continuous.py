@@ -270,6 +270,9 @@ class _LoopClock:
         self.queue_wait_ns = self.binds = 0
         self.prefill_wait_ns = self.first_tokens = 0
         self.stream_lag_ns = self.stream_frames = 0
+        # Positions prefilled, and the first positions of their chunks
+        # summed: neighbours' difference is an iteration's chunk.
+        self.prefill_tokens = self.prefill_starts = 0
         # Of the iteration in progress.
         self.prefilled = False
         self.active_slots = 0
@@ -312,6 +315,7 @@ class _LoopClock:
             self.queue_wait_ns, self.binds,
             self.prefill_wait_ns, self.first_tokens,
             self.stream_lag_ns, self.stream_frames,
+            self.prefill_tokens, self.prefill_starts,
         )
 
     def end_iteration(self) -> None:
@@ -534,6 +538,7 @@ class ContinuousScheduler:
         # What the model says of its cache (models/slot_model.py);
         # injected kernels carry no model and keep these.
         self._tier_at_fill, self._sparse_positions = False, lambda pos: 0
+        self._attend_kernel = lambda size: False
         self.cache_bytes: dict = {}
         if prefill_fn is not None or step_fn is not None:
             if prefill_fn is None or step_fn is None:
@@ -630,6 +635,9 @@ class ContinuousScheduler:
         self.slot_steps_total = 0  # active slots summed over steps
         self.retired_total = 0     # rows retired (eos + max_tokens)
         self.prefill_chunks_total = 0  # chunk-kernel launches
+        # ... of which the model says its program holds its attention
+        # kernel (`SlotModel.attend_kernel`, asked once a chunk size).
+        self.attend_kernel_chunks_total = 0
         self.ttft_recent: collections.deque[float] = collections.deque(
             maxlen=1024
         )
@@ -658,6 +666,10 @@ class ContinuousScheduler:
         # pool rides the SAME cache as P extra slots past the request
         # region — one allocation, one shape, one copy kernel.
         M = self._T + self._N - 1 if self._N > 1 else self._T
+        # One program a chunk size, so one answer: the model's dispatch
+        # is asked when a size is first launched, not every chunk.
+        self._attend_kernel = functools.lru_cache(maxsize=None)(
+            lambda size: bool(model.attend_kernel(size, M)))
         self._make_cache = lambda: model.init_slot_cache(
             cfg, self._S + self._P, M)
         self._cache = self._make_cache()
@@ -1308,6 +1320,9 @@ class ContinuousScheduler:
         clock.mark(_PREFILL_POST)
         occ["fill"] = start + size
         self.prefill_chunks_total += 1
+        clock.prefill_tokens += size
+        clock.prefill_starts += start
+        self.attend_kernel_chunks_total += self._attend_kernel(size)
         self._count_positions(np.arange(start, start + size))
         if self._gp_model is not None:
             # A resume re-prefill's last-position logits are DISCARDED
