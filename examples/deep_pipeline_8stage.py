@@ -5,10 +5,10 @@ costs.
 
 The reference never recorded numbers for its deep-pipeline shape
 ("Fashion-MNIST 8-layer MLP, 8-stage pipeline (one layer per core)");
-this experiment closes that config with committed evidence
-(artifacts/deep_pipeline_r04/). Workload: the vendored real
-handwritten digits (64-dim — the zero-egress real-data anchor,
-tests/test_real_data.py), an 8-dense-layer MLP sized
+this experiment closes that config (tests/test_examples.py runs it).
+Workload: the vendored real handwritten digits (64-dim — the
+zero-egress real-data anchor, tests/test_real_data.py), an
+8-dense-layer MLP sized
 64-96-80-64-48-32-24-16-10, distribution [1]*8 so every layer is its
 own pipeline stage.
 

@@ -39,7 +39,8 @@ virtual-device box can show:
 Matched-granularity pairs (S=4, L=8): {1f1b(v=1), zb(v=1)} at 2
 blocks/chunk and {interleaved(v=2), zb-v} at 1 block/chunk.
 
-Writes artifacts/schedule_walltime_r05/RECORD.json. Run:
+Prints the whole record as JSON (`--out FILE` writes it there instead
+and prints the matched pairs only). Run:
     python examples/schedule_walltime.py [--fast]
 """
 
@@ -73,9 +74,6 @@ from tpu_dist_nn.parallel.mesh import MeshSpec, build_mesh  # noqa: E402
 from tpu_dist_nn.train.lm_trainer import (  # noqa: E402
     make_pipeline_lm_train_step,
 )
-
-ART = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                   "artifacts", "schedule_walltime_r05")
 
 S = 4           # pipeline stages
 L = 8           # transformer blocks
@@ -188,12 +186,12 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="fewer reps / one M (CI smoke)")
-    ap.add_argument("--out", default=os.path.join(ART, "RECORD.json"))
+    ap.add_argument("--out", default=None,
+                    help="write the record here, not to stdout")
     args = ap.parse_args()
     reps = 2 if args.fast else 5
     ms = (8,) if args.fast else (8, 16)
     cfg = _cfg()
-    os.makedirs(os.path.dirname(args.out), exist_ok=True)
 
     record = {
         "task": "schedule family wall-clock vs tick model "
@@ -300,7 +298,6 @@ def main() -> int:
         record["schedules"][name] = {
             "num_virtual": v, "blocks_per_chunk": chunk_w, **per_m,
         }
-        _write(record, args.out)
 
     # Ratios at the largest M, within MATCHED-GRANULARITY pairs only —
     # raw tick counts across different chunk widths are incomparable.
@@ -349,15 +346,14 @@ def main() -> int:
             ),
             "granularity_blocks_per_chunk": chunk_w,
         }
-    _write(record, args.out)
-    print(json.dumps(record["matched_pairs"], indent=2))
-    return 0
-
-
-def _write(record, out):
-    with open(out, "w") as f:
+    if args.out is None:
+        print(json.dumps(record, indent=2))
+        return 0
+    with open(args.out, "w") as f:
         json.dump(record, f, indent=2)
         f.write("\n")
+    print(json.dumps(record["matched_pairs"], indent=2))
+    return 0
 
 
 if __name__ == "__main__":

@@ -17,11 +17,11 @@ virtual devices share ONE physical core, so the pipelined endpoint's
 wall time reflects total compute + collective overhead, not parallel
 makespan — single-chip WINS here by construction. The pipelined row's
 evidentiary value is end-to-end function + coalescing into group
-slots; the decoder-level overlapped-vs-masked speedup on real parallel
-placement is artifacts/pp_decode_r04 (2.55x). Parity of every served
+slots; the decoder-level overlapped-vs-masked comparison is
+examples/pp_decode_throughput.py. Parity of every served
 token against models.generate is asserted inline.
 
-Writes artifacts/serving_generate_r05/RECORD.json.
+Prints the record as JSON.
 Run: python examples/serve_generate_throughput.py [--fast]
 """
 
@@ -50,11 +50,6 @@ from tpu_dist_nn.models.transformer import (  # noqa: E402
     init_transformer,
 )
 from tpu_dist_nn.serving import GrpcClient, serve_lm_generate  # noqa: E402
-
-ART = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "artifacts", "serving_generate_r05",
-)
 
 T, N = 16, 24
 
@@ -97,7 +92,6 @@ def main() -> int:
     ap.add_argument("--fast", action="store_true")
     args = ap.parse_args()
     clients, rpcs, rows = (4, 2, 2) if args.fast else (8, 4, 2)
-    os.makedirs(ART, exist_ok=True)
 
     cfg = TransformerConfig(
         vocab_size=64, d_model=64, n_heads=4, n_layers=4, d_ff=128,
@@ -116,8 +110,8 @@ def main() -> int:
         "scope_note": "1 physical core under 8 virtual devices: the "
                       "pipelined row evidences end-to-end function + "
                       "coalescing into group slots, not parallel "
-                      "speedup (see artifacts/pp_decode_r04 for the "
-                      "decoder-level overlapped 2.55x)",
+                      "speedup (examples/pp_decode_throughput.py "
+                      "compares the decoders)",
         "endpoints": {},
     }
 
@@ -138,10 +132,7 @@ def main() -> int:
             record["endpoints"][name] = m
         finally:
             server.stop(0)
-        with open(os.path.join(ART, "RECORD.json"), "w") as f:
-            json.dump(record, f, indent=2)
-            f.write("\n")
-    print(json.dumps(record["endpoints"], indent=2))
+    print(json.dumps(record, indent=2))
     return 0
 
 

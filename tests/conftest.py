@@ -57,29 +57,26 @@ QUICK_TESTS = {
         # drill under a faults.py-paced burst (zero dropped), the
         # one-tick burn->spawn control-loop anchor, hedging's
         # first-reply-wins contract + the loopback straggler rescue,
-        # the POST /router/scale override, and the bench_gate
-        # skip/fail contract for autoscale_replica_seconds_ratio.
+        # and the POST /router/scale override.
         "test_autoscale_smoke_fleet_scales_up_and_back_down",
         "test_synthetic_burn_scales_up_within_one_tick",
         "test_hedge_fires_once_first_reply_wins_loser_cancelled",
         "test_hedge_rescues_straggler_over_loopback_wire",
-        "test_manual_scale_override_via_post_route_and_status_route",
-        "test_bench_gate_autoscale_ratio_skip_and_fail"],
+        "test_manual_scale_override_via_post_route_and_status_route"],
     "test_batcher_pipeline": [
         "test_batches_launch_while_prior_fetch_in_flight",
-        "test_warm_buckets_ladder_gauge_and_no_misses_after_warm",
-        "test_bench_overlap_smoke_overlapped_at_least_serial"],
+        "test_warm_buckets_ladder_gauge_and_no_misses_after_warm"],
     "test_checkpoint": ["test_async_manager_saves_and_restores",
                         "test_manager_latest_and_retention",
                         "test_resume_noop_when_complete"],
     "test_continuous": [
         "test_continuous_matches_static_greedy_tokens",
         "test_serve_continuous_loopback_parity_and_counters",
-        "test_gen_ab_smoke_continuous_beats_static",
+        "test_mixed_budgets_take_fewer_steps_than_run_to_completion",
         # ISSUE 7: prefix-cache bit parity is the correctness anchor,
-        # the shared-prefix A/B smoke the perf gate.
+        # the prefilled-token counts what the pool is for.
         "test_prefix_cache_greedy_bit_parity_including_eos",
-        "test_gen_prefix_smoke_cache_on_beats_off"],
+        "test_warm_prefix_pool_hits_and_prefills_fewer_tokens"],
     "test_conv": ["test_conv_forward_matches_oracle",
                   "test_engine_routes_conv_model"],
     "test_conv_kernel": ["test_conv_matches_lax[stride1-same]",
@@ -104,14 +101,13 @@ QUICK_TESTS = {
     "test_incident": [
         # ISSUE 11 acceptance smokes: the loopback burn->bundle path,
         # the 2-replica stitched fleet drill (+ tdn incident/debug
-        # CLI), both crash-path subprocess proofs, and the armed-vs-
-        # disarmed overhead A/B with its bench_gate contract.
+        # CLI), both crash-path subprocess proofs, and the armed
+        # recorder's one detector pass a sampler tick.
         "test_burn_detector_captures_bundle_with_faulted_span",
         "test_fleet_drill_burn_trips_router_recorder_stitched_bundle",
         "test_crash_unhandled_exception_leaves_valid_bundle",
         "test_crash_sigabrt_leaves_valid_bundle_then_dies_by_signal",
-        "test_incident_overhead_smoke_armed_within_noise",
-        "test_bench_gate_incident_ratio_skip_and_fail"],
+        "test_sampler_ticks_an_armed_recorder_once_and_quiet_captures_nothing"],
     "test_forward_parity": ["test_forward_matches_oracle_small",
                             "test_softmax_stability"],
     "test_gen_loop_clock": [
@@ -130,19 +126,15 @@ QUICK_TESTS = {
     # forced into pow2 buckets, useful+pad==total exactly, /goodput
     # shares sum to 1), iteration-level continuous accounting + prefix
     # savings, the timeseries families across a counter reset, the tdn
-    # top MFU/pad column in both modes + the --iterations CI path, the
-    # bench_gate serving_mfu/serving_pad_ratio contract, and the
-    # armed-vs-disarmed accounting overhead A/B.
+    # top MFU/pad column in both modes + the --iterations CI path; the
+    # loopback smoke's disarmed case records nothing.
     "test_goodput": [
         "test_loopback_serving_pad_accounting_exact",
         "test_continuous_scheduler_conservation_and_prefix_savings",
         "test_static_generate_accounting_eos_frozen_exact",
         "test_timeseries_goodput_families_and_counter_reset",
         "test_top_renders_mfu_pad_columns_fleet_and_single",
-        "test_cli_top_iterations_reads_goodput_from_live_endpoint",
-        "test_bench_gate_serving_mfu_and_pad_ratio_skip_and_fail",
-        "test_goodput_overhead_smoke_accounting_within_noise",
-        "test_peak_calibration_is_shared_with_bench"],
+        "test_cli_top_iterations_reads_goodput_from_live_endpoint"],
     "test_graft_entry": ["test_entry_is_jittable",
                          "test_dryrun_multichip_odd_device_count"],
     "test_hetero_pipeline": ["test_forward_matches_single_program"],
@@ -203,16 +195,9 @@ QUICK_TESTS = {
     "test_pipeline_tp_sp": [
         "test_pp_tp_sp_1f1b_grads_match_single_chip[ulysses]"],
     "test_profile": [
-        # The ISSUE-6 quick-tier smokes: loopback /profile shares sum
-        # to the measured root wall, and tools/bench_gate.py runs the
-        # checked-in r04->r05 pair report-only plus a synthetic failing
-        # pair in enforce mode.
-        "test_loopback_profile_process_shares_sum_to_wall",
-        "test_bench_gate_report_only_on_checked_in_rounds",
-        "test_bench_gate_enforce_fails_synthetic_regression",
-        # ISSUE 10: best-of-history mode must fail the checked-in
-        # r02->r05 host-fed drift that pairwise diffing waved through.
-        "test_bench_gate_history_fails_checked_in_host_fed_drift"],
+        # The ISSUE-6 quick-tier smoke: loopback /profile shares sum
+        # to the measured root wall.
+        "test_loopback_profile_process_shares_sum_to_wall"],
     "test_profiling": ["test_latency_stats_summary",
                        "test_annotate_inside_jit"],
     "test_quantized": ["test_weight_quantization_roundtrip_error_bounded",
@@ -222,22 +207,19 @@ QUICK_TESTS = {
     # incident-bundle -> WorkloadTrace -> replay round trip (exact mix
     # + per-decile arrival fidelity over a live loopback fleet), the
     # seeded-probability fault mode, the stream-resume bound at its
-    # exact boundary, one quick-scaled scenario verdict, and the
-    # bench_gate scenario_pass_ratio skip/fail contract.
+    # exact boundary, and one quick-scaled scenario verdict.
     "test_replay": [
         "test_generators_deterministic_and_well_formed",
         "test_fault_plan_probability_mode_deterministic_under_seed",
         "test_bundle_round_trip_exact_mix_and_arrival_deciles",
         "test_stream_resume_bound_boundary_and_overflow_counter",
-        "test_scenario_quick_smoke_deterministic_verdict",
-        "test_bench_gate_scenario_pass_ratio_skip_and_fail"],
+        "test_scenario_quick_smoke_deterministic_verdict"],
     "test_router": [
         # ISSUE 8: the loopback p2c smoke (spread + tdn_router_*
-        # family on /metrics), the breaker-registry-eviction
-        # regression, and the router_rps gate skip/fail contract.
+        # family on /metrics) and the breaker-registry-eviction
+        # regression.
         "test_router_loopback_spreads_load_and_exposes_metrics",
-        "test_pool_remove_evicts_breaker_registry_for_reused_address",
-        "test_bench_gate_router_rps_skip_and_fail"],
+        "test_pool_remove_evicts_breaker_registry_for_reused_address"],
     "test_resilience": [
         "test_chaos_smoke_quick_tier_recovers_via_retries",
         "test_breaker_cycle_closed_open_half_open_closed",
@@ -246,16 +228,14 @@ QUICK_TESTS = {
     # (critical completes, best_effort absorbs the sheds), the
     # real-model preemption bit-parity anchor, class-watermark sheds
     # + deadline expiry on the shared core, the retry-after floor over
-    # a real loopback shed, the router class hop, and the bench_gate
-    # slo_class_critical_p99_ms skip/fail contract.
+    # a real loopback shed, and the router class hop.
     "test_sched_core": [
-        "test_overload_drill_critical_holds_best_effort_absorbs",
+        "test_overload_drill_critical_never_shed_best_effort_absorbs",
         "test_preempted_greedy_generate_bit_matches_unpreempted",
         "test_class_watermark_sheds_best_effort_first",
         "test_expired_entry_fails_deadline_exceeded_at_pop_without_launch",
         "test_shed_reply_carries_retry_after_and_client_honors_floor",
-        "test_router_forwards_class_and_server_labels_it",
-        "test_bench_gate_slo_class_critical_p99_skip_and_fail"],
+        "test_router_forwards_class_and_server_labels_it"],
     "test_real_data": ["test_real_digits_load_shapes_and_content",
                        "test_realtext_corpus_supports_valid_heldout_at_scale",
                        "test_cli_train_digits_end_to_end"],
@@ -279,13 +259,12 @@ QUICK_TESTS = {
     # ISSUE 13: the tdn lint gate in both directions — zero
     # non-baselined findings on the shipped tree, exit 1 on a planted
     # violation, each rule firing on its fixture with the exact id and
-    # line — plus the bench_gate report-header integration.
+    # line.
     "test_tdnlint": [
         "test_rule_fires_on_violating_fixture",
         "test_rule_silent_on_clean_twin",
         "test_shipped_tree_is_clean_via_tdn_lint_cli",
-        "test_tdn_lint_exits_nonzero_on_planted_violation",
-        "test_bench_gate_report_only_mentions_lint_status"],
+        "test_tdn_lint_exits_nonzero_on_planted_violation"],
     "test_tensor_parallel": ["test_forward_matches_single_chip[spec1]",
                              "test_shard_roundtrip"],
     # ISSUE 21: --platform is asserted and clients open no backend; one
@@ -301,13 +280,12 @@ QUICK_TESTS = {
     "test_tpu_hardware": ["*"],
     # ISSUE 10: the codec fast lane's correctness anchor (byte-exact
     # scalar/vectorized equivalence + fuzz agreement), the decode-into-
-    # staging path through a real batcher, the codec A/B perf smoke,
-    # and the loopback fast-path counter check.
+    # staging path through a real batcher, and the loopback fast-path
+    # counter check.
     "test_wire_codec": [
         "test_encode_vectorized_matches_scalar_bytes_exactly",
         "test_decode_fuzz_fast_and_scalar_agree_on_mutated_bytes",
         "test_batcher_stages_wire_matrices_straight_into_bucket_buffer",
-        "test_bench_wire_smoke_vectorized_beats_scalar",
         "test_loopback_serving_round_trip_rides_fast_path"],
     "test_trace": ["test_chrome_trace_export_schema",
                    "test_loopback_round_trip_is_one_trace_tree",
@@ -330,6 +308,9 @@ QUICK_TESTS = {
                          "test_zb_stash_grads_match_single_chip[2-1-4]"],
     "test_split_backward": ["*"],
     "test_quick_tier": ["*"],
+    # PR 29: the documents and the tools against the tree (static).
+    "test_docs": ["*"],
+    "test_tools": ["*"],
 }
 
 

@@ -1005,34 +1005,3 @@ def test_fleet_manifest_cli_emits_compose(capsys):
 # ------------------------------------------------------ bench gate
 
 
-def test_bench_gate_autoscale_ratio_skip_and_fail():
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_gate",
-        os.path.join(os.path.dirname(__file__), "..", "tools",
-                     "bench_gate.py"),
-    )
-    bench_gate = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench_gate)
-    base = {"backend": "cpu", "value": 100.0}
-    prev_no_section = dict(base, serving={"coalesced": {"rps": 50.0}})
-    cur = dict(base, serving={
-        "coalesced": {"rps": 50.0},
-        "autoscale": {"replica_seconds_ratio": 0.7},
-    })
-    verdict = bench_gate.compare(prev_no_section, cur)
-    rows = {r["metric"]: r for r in verdict["metrics"]}
-    assert "skipped" in rows["autoscale_replica_seconds_ratio"], \
-        "rounds predating ISSUE 12 must skip, not fail"
-    prev = dict(base, serving={"autoscale": {"replica_seconds_ratio": 0.7}})
-    cur_reg = dict(base,
-                   serving={"autoscale": {"replica_seconds_ratio": 0.8}})
-    verdict = bench_gate.compare(prev, cur_reg)
-    assert "autoscale_replica_seconds_ratio" in verdict["regressions"], \
-        "lower-is-better: the ratio rising >5% is a regression"
-    cur_ok = dict(base,
-                  serving={"autoscale": {"replica_seconds_ratio": 0.6}})
-    verdict = bench_gate.compare(prev, cur_ok)
-    assert "autoscale_replica_seconds_ratio" not in verdict["regressions"]

@@ -1,11 +1,12 @@
 """Continuous-batching decode scheduler (serving/continuous.py):
 static-scheduler output parity, slot reuse, per-request budgets,
 admission/close semantics, observability, the loopback endpoint, the
-staggered-arrival static-vs-continuous A/B smoke, and the KV-reuse
-layer — prefix-cache bit parity / refcount lifecycle / COW isolation,
-chunked-prefill parity, mid-prefill faults, drain with half-prefilled
-slots, and the shared-prefix A/B smoke."""
+KV-reuse layer — prefix-cache bit parity / refcount lifecycle / COW
+isolation, chunked-prefill parity, mid-prefill faults, drain with
+half-prefilled slots — and what iteration-level scheduling and the
+prefix pool save, counted in step launches and prefilled tokens."""
 
+import functools
 import threading
 import time
 
@@ -40,20 +41,26 @@ def _sched(**kw):
     return ContinuousScheduler(PARAMS, CFG, **kw)
 
 
-def _fake_sched(step_cost=0.0, chunk_cost=0.0, **kw):
+def _fake_sched(step_cost=0.0, chunk_cost=0.0, on_prefill=None,
+                on_step=None, **kw):
     """Cost-model scheduler (no device work): the deterministic arm of
     the admission/close/shed/prefix-lifecycle tests. ``chunk_cost`` is
     per prefill-chunk TOKEN (chunked prefill pays proportionally to
-    the tokens it actually runs)."""
+    the tokens it actually runs). ``on_prefill(tokens)`` / ``on_step()``
+    let a test count what a kernel was handed, or hold the device."""
 
     def fake_prefill(params, cache, slot, tokens, start, key):
         if chunk_cost:
             time.sleep(chunk_cost * tokens.shape[1])
+        if on_prefill is not None:
+            on_prefill(tokens)
         return np.int32(1), cache
 
     def fake_step(params, cache, pos, active, tok, key):
         if step_cost:
             time.sleep(step_cost)
+        if on_step is not None:
+            on_step()
         return np.asarray(tok) + 1, cache
 
     kw.setdefault("slots", 2)
@@ -729,65 +736,100 @@ def test_serve_loopback_with_prefix_cache_exact_and_accounted():
         server.stop(0)
 
 
-# ------------------------------------------------------------ A/B smoke
+# ------------------------------------- what the schedule saves, by counts
 
 
-def test_gen_ab_smoke_continuous_beats_static():
-    """The quick-tier CI gate for ISSUE 5's acceptance criterion, in
-    the controlled per-step-cost regime (both arms pay an identical
-    deterministic per-decode-step cost, so the measured delta is pure
-    scheduling policy): under staggered arrivals with mixed budgets,
-    continuous batching must beat the run-to-completion control arm on
-    throughput AND p99 latency — and report TTFT."""
-    from bench import gen_ab_bench
+def test_mixed_budgets_take_fewer_steps_than_run_to_completion():
+    """16 rows on 4 slots, every other one wanting 2 tokens of 32: all
+    retire, each slot-step yields a token somebody asked for, the step
+    kernel is launched fewer times than run-to-completion's fixed trip
+    count needs, and a row's first token is out before its last."""
+    slots, rows, budget, short = 4, 16, 32, 2
+    budgets = [short if i % 2 else budget for i in range(rows)]
+    go = threading.Event()
 
-    # Structural expectation (not a timing race): on a 4-wide device,
-    # run-to-completion needs >= ceil(16/4) batches x 33 step-costs
-    # = 528ms of decode, while iteration-level scheduling needs
-    # ~(8*2 + 8*32)/4 steps + 16 prefills ~ 84 step-costs = 336ms —
-    # a >= 1.5x structural margin before any convoy penalty, which is
-    # what makes the >= assertions robust to CI box jitter.
-    ab = gen_ab_bench(
-        None, slots=4, requests=16, prompt_len=T, max_new=32,
-        short_budget=2, arrival_gap_s=0.005, controlled_step_cost=0.004,
+    def wait_for_go():
+        assert go.wait(30.0)
+
+    sched = _fake_sched(on_step=wait_for_go, slots=slots,
+                        max_new_tokens=budget)
+    try:
+        streams = [
+            sched.submit_stream(_prompts(1, seed=i), max_new_tokens=b)
+            for i, b in enumerate(budgets)
+        ]
+        # The first row's first token is its prefill's: published while
+        # no step has run and nothing has retired.
+        assert streams[0].next_event(30.0) == ("tokens", [1])
+        assert sched.steps_total == 0 and sched.retired_total == 0
+        go.set()
+        got = []
+        for stream in streams:
+            tokens = [1] if stream is streams[0] else []
+            while True:
+                kind, data = stream.next_event(30.0)
+                if kind == "end":
+                    assert data["reason"] == "max_tokens", data
+                    break
+                tokens += data
+            got.append(tokens)
+    finally:
+        go.set()
+        sched.close()
+    assert got == [list(range(1, b + 1)) for b in budgets]
+    assert sched.retired_total == rows
+    # A prefill yields a row's first token and every slot-step one more.
+    assert sched.slot_steps_total == sum(b - 1 for b in budgets)
+    # Run-to-completion decodes every batch of `slots` rows for the
+    # whole budget (generate()'s scan has a fixed trip count).
+    assert sched.steps_total < -(-rows // slots) * budget
+    assert 0.0 < sched.slot_steps_total / (sched.steps_total * slots) <= 1.0
+
+
+@functools.lru_cache(maxsize=None)
+def _prefilled_tokens(prompt_len, pool):
+    """Tokens handed to the prefill kernel, and the pool's hit ratio,
+    over 12 prompts that share all but their last 4 tokens, one after
+    the other on a warm scheduler."""
+    handed = []
+
+    rng = np.random.default_rng(prompt_len)
+    header = rng.integers(0, 64, prompt_len - 4)
+    prompts = [
+        np.concatenate([header, rng.integers(0, 64, 4)])[None, :]
+        for _ in range(13)
+    ]
+    sched = _fake_sched(
+        on_prefill=lambda tokens: handed.append(tokens.shape[1]),
+        slots=4, prompt_len=prompt_len, max_new_tokens=8,
+        prefix_cache_blocks=4 if pool else 0,
+        prefill_chunk=8 if pool else None,
     )
-    c, s = ab["continuous"], ab["static"]
-    assert c["rps"] >= s["rps"], ab
-    assert c["p99_ms"] < s["p99_ms"], ab
-    # TTFT is measured and (continuous) decoupled from full latency.
-    assert c["ttft_p50_ms"] < c["p50_ms"]
-    assert s["ttft_p99_ms"] == s["p99_ms"]  # run-to-completion
-    assert c["retired"] == 16
-    assert 0.0 < c["slot_occupancy"] <= 1.0
+    try:
+        sched.submit(prompts[0])  # the header enters the pool
+        warm = len(handed)
+        hits0, misses0 = sched.prefix_hits_total, sched.prefix_misses_total
+        for prompt in prompts[1:]:
+            sched.submit(prompt)
+        hits = sched.prefix_hits_total - hits0
+        misses = sched.prefix_misses_total - misses0
+    finally:
+        sched.close()
+    return sum(handed[warm:]), hits / max(hits + misses, 1)
 
 
-def test_gen_prefix_smoke_cache_on_beats_off():
-    """The quick-tier CI gate for ISSUE 7's acceptance criterion, in
-    the controlled per-token-cost regime (prefill cost proportional to
-    the tokens actually run, identical on both arms, so the measured
-    delta is pure KV-reuse policy): on the shared-prefix workload,
-    prefix-cache + chunked-prefill ON must beat OFF on throughput AND
-    TTFT p99, serve a real hit ratio, and hold TTFT p99 FLATTER as
-    prompt length grows (the chunked-prefill claim — the uncached
-    remainder is constant by construction)."""
-    from bench import gen_prefix_bench
+@pytest.mark.parametrize("prompt_len", [16, 32])
+def test_warm_prefix_pool_hits_and_prefills_fewer_tokens(prompt_len):
+    on, hit_ratio = _prefilled_tokens(prompt_len, pool=True)
+    off, _ = _prefilled_tokens(prompt_len, pool=False)
+    assert hit_ratio > 0.5
+    assert off == 12 * prompt_len  # the whole prompt, every request
+    assert on < off
 
-    # Structural expectation (not a timing race): prompts share all but
-    # 4 tail tokens, so once the pool is warm a hit prefills <= chunk+
-    # tail tokens where the OFF arm prefills all T — at T=32 that is
-    # ~32 vs ~12 step-costs of prefill per request, a >= 2x margin on
-    # the prefill share before any decode-stall effect.
-    ab = gen_prefix_bench(
-        None, slots=4, requests=12, prompt_lens=(16, 32), tail_tokens=4,
-        chunk=8, blocks=4, max_new=8, arrival_gap_s=0.004,
-        controlled_cost_per_token=0.002,
-    )
-    assert ab["rps"] >= ab["off_rps"], ab
-    assert ab["ttft_p99_ms"] < ab["off_ttft_p99_ms"], ab
-    assert ab["prefix_hit_ratio"] > 0.5, ab
-    # Flatness: the ON arm's TTFT p99 grows STRICTLY slower with prompt
-    # length than the control's.
-    assert ab["ttft_growth_on"] < ab["ttft_growth_off"], ab
-    per = ab["per_prompt_len"]
-    for T_ in per:
-        assert per[T_]["on"]["prefix_hit_ratio"] > 0.5, per[T_]
+
+def test_prefilled_tokens_with_the_pool_do_not_grow_with_prompt_length():
+    # The uncached remainder is the tail's chunk whatever the header's
+    # length: chunked prefill keeps a hit's cost flat.
+    short, _ = _prefilled_tokens(16, pool=True)
+    long_, _ = _prefilled_tokens(32, pool=True)
+    assert long_ <= short

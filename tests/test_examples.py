@@ -48,7 +48,7 @@ def test_centralized_experiments_on_real_digits(tmp_path):
 
 
 def test_deep_pipeline_8stage_experiment(tmp_path):
-    # BASELINE configs[2] closure (artifacts/deep_pipeline_r04): the
+    # BASELINE configs[2] closure: the
     # 8-layer MLP trains THROUGH the one-layer-per-stage 8-device
     # pipeline on real digits, exports, re-serves at three placements,
     # and the deep placement's latency overhead tracks the tick model.
@@ -83,7 +83,7 @@ def test_deep_pipeline_8stage_experiment(tmp_path):
 
 
 def test_four_d_training_example(tmp_path, capsys, monkeypatch):
-    # The 4D composition example (artifacts/four_d_r04): PP x TP x SP
+    # The 4D composition example: PP x TP x SP
     # trains on real text under all four schedules and their
     # trajectories agree to float tolerance. Short step budget for CI.
     import runpy
@@ -108,10 +108,9 @@ def test_four_d_training_example(tmp_path, capsys, monkeypatch):
 
 
 def test_pp_decode_throughput_example(tmp_path, capsys, monkeypatch):
-    # Overlapped vs masked pipelined decode (artifacts/pp_decode_r04):
-    # identical outputs, overlapped faster or equal (wall-clock on a
-    # contended CI box is noisy, so the assertion is outputs + record
-    # shape; the committed artifact carries the measured 2.55x).
+    # Overlapped vs masked pipelined decode: identical outputs
+    # (wall-clock on a contended CI box is noisy, so the assertion is
+    # outputs + record shape).
     import runpy
 
     import pytest

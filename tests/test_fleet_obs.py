@@ -782,36 +782,3 @@ def test_cli_top_unreachable_is_user_error():
 # --------------------------------------------------------- bench gate
 
 
-def test_bench_gate_slo_metrics_skip_and_gate():
-    sys.path.insert(0, "/root/repo/tools")
-    try:
-        import bench_gate
-    finally:
-        sys.path.pop(0)
-
-    def round_doc(p99=None, avail=None):
-        doc = {"backend": "cpu", "value": 100000.0, "serving": {}}
-        if p99 is not None:
-            doc["serving"]["slo"] = {
-                "latency": {"measured_p99_ms": p99},
-                "availability": {"measured": avail},
-            }
-        return doc
-
-    # Pre-ISSUE-9 previous round: the slo rows skip, nothing fails.
-    verdict = bench_gate.compare(round_doc(), round_doc(16.0, 1.0))
-    rows = {m["metric"]: m for m in verdict["metrics"]}
-    assert "skipped" in rows["slo_process_p99_ms"]
-    assert "skipped" in rows["slo_availability"]
-    assert not verdict["regressions"]
-    # Regressed p99 and availability both fail the enforced gate.
-    verdict = bench_gate.compare(
-        round_doc(16.0, 1.0), round_doc(40.0, 0.9)
-    )
-    assert "slo_process_p99_ms" in verdict["regressions"]
-    assert "slo_availability" in verdict["regressions"]
-    # Improvement never fails.
-    verdict = bench_gate.compare(
-        round_doc(16.0, 0.99), round_doc(8.0, 1.0)
-    )
-    assert not verdict["regressions"]

@@ -33,13 +33,20 @@ T, N = 8, 10
 CUMULATIVE = ITER_FIELDS[ITER_FIELDS.index("active_slots") + 1:]
 
 
-def _fake_sched(step_cost=0.0, **kw):
+def _fake_sched(step_cost=0.0, readings=None, **kw):
+    """``readings``: a list that takes the loop thread's reading of the
+    clock inside every call into a stub kernel."""
+
     def fake_prefill(params, cache, slot, tokens, start, key):
+        if readings is not None:
+            readings.append(time.monotonic_ns())
         return np.int32(1), cache
 
     def fake_step(params, cache, pos, active, tok, key):
         if step_cost:
             time.sleep(step_cost)
+        if readings is not None:
+            readings.append(time.monotonic_ns())
         return np.asarray(tok) + 1, cache
 
     kw.setdefault("slots", 2)
@@ -72,16 +79,40 @@ def test_phase_names_partition_into_host_and_wait():
 
 
 def test_phase_totals_sum_to_the_loops_wall_time():
-    t0 = time.monotonic()
-    sched = _fake_sched(step_cost=0.004, slots=2)
+    # No reading here is compared with a time the test's own thread
+    # took while the loop ran: how soon a loaded host starts or joins a
+    # thread is not the loop's to answer for.
+    readings = []  # the loop thread's, inside the stub kernels
+    t0 = time.monotonic_ns()
+    sched = _fake_sched(step_cost=0.004, slots=2, readings=readings)
     sched.submit(_prompts(5))
     time.sleep(0.05)  # some idle belongs to the partition too
     sched.submit(_prompts(2, seed=1))
+    t_close = time.monotonic_ns()
     sched.close()
-    wall = time.monotonic() - t0
+    t1 = time.monotonic_ns()
     totals = sched.loop_totals()
-    booked = sum(totals[p] for p in LOOP_PHASES) / 1e9
-    assert booked == pytest.approx(wall, rel=0.02)
+
+    def booked(record):
+        return sum(record[p] for p in LOOP_PHASES)
+
+    def t_end(record):  # the clock's last mark: a float of seconds
+        return round(record["t_end"] * 1e9)
+
+    # The partition is exact: between the first iteration's end (a
+    # record the loop wrote itself) and stop(), every nanosecond that
+    # passed on the clock is booked to one phase. 1 us is what t_end
+    # loses as a float.
+    first = _mine(sched)[0]
+    assert (booked(totals) - booked(first)
+            == pytest.approx(t_end(totals) - t_end(first), abs=1000))
+    # And it covers the loop's whole life. Booked back from the last
+    # mark is where the clock started: after the scheduler was made,
+    # before the loop first called a kernel. It stopped after close()
+    # was called and before it returned.
+    started = t_end(totals) - booked(totals)
+    assert t0 - 1000 <= started <= readings[0] + 1000
+    assert t_close - 1000 <= t_end(totals) <= t1 + 1000
     assert totals["idle"] / 1e9 >= 0.04
     # The stub step sleeps inside the call into it: that is dispatch.
     assert totals["step.dispatch"] / 1e9 >= 0.004 * totals["seq"] * 0.5

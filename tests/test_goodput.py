@@ -4,14 +4,12 @@ The contract under test is CONSERVATION: every recorded launch's FLOPs
 split exactly into ``useful + pad == total`` (integer arithmetic, no
 float slop) across the batcher (bucket pad rows), the continuous
 scheduler (idle/mid-prefill slot lanes, attention tails), and the
-static run-to-completion decode (EOS-frozen steps) — plus the
-peak-calibration unification with bench.py, the ``/goodput`` endpoint,
-the timeseries/`tdn top`/bench_gate satellites, and the accounting
-overhead staying within noise.
+static run-to-completion decode (EOS-frozen steps) — plus the peak
+table, the ``/goodput`` endpoint, the timeseries and `tdn top`
+satellites, and a disarmed tracker recording nothing.
 """
 
 import json
-import time
 import urllib.error
 import urllib.request
 
@@ -23,10 +21,7 @@ from tpu_dist_nn.obs.goodput import (
     GOODPUT,
     GoodputTracker,
     LMFlopModel,
-    PEAK_FLOPS,
-    device_peak_flops,
     fcnn_flops_per_row,
-    host_calibration_gflops,
     resolve_peak,
 )
 from tpu_dist_nn.obs.registry import Registry
@@ -80,16 +75,6 @@ def test_lm_model_identities_are_exact_ints():
 
 
 # ------------------------------------------------ peak calibration
-
-
-def test_peak_calibration_is_shared_with_bench():
-    """Satellite 1: bench.py's calibration/peak table ARE goodput's —
-    identity, not copies, so the two can never diverge."""
-    import bench
-
-    assert bench._PEAK_FLOPS is PEAK_FLOPS
-    assert bench._host_calibration is host_calibration_gflops
-    assert bench._peak_flops is device_peak_flops
 
 
 def test_ensure_peak_scales_by_device_count_and_keeps_max():
@@ -245,10 +230,12 @@ def test_engine_direct_infer_counts_all_useful():
     assert g1["peak_flops"] and g1["peak_source"]
 
 
-def test_loopback_serving_pad_accounting_exact():
+@pytest.mark.parametrize("armed", [True, False])
+def test_loopback_serving_pad_accounting_exact(armed, monkeypatch):
     """The quick-tier smoke (acceptance): odd row counts force bucket
     pad on the loopback wire, useful + pad == total EXACTLY, and the
-    /goodput endpoint's shares sum to 1."""
+    /goodput endpoint's shares sum to 1. Disarmed, the same requests
+    are served and the ledger does not move."""
     import jax
 
     from tpu_dist_nn.api.engine import Engine
@@ -262,10 +249,15 @@ def test_loopback_serving_pad_accounting_exact():
     mserver = MetricsServer(0, host="127.0.0.1", goodput=GOODPUT)
     client = GrpcClient(f"127.0.0.1:{port}")
     try:
+        monkeypatch.setattr(GOODPUT, "enabled", armed)
         g0 = GOODPUT.snapshot()
         client.process(np.zeros((3, 16)))  # 3 rows -> pow2 bucket of 4
         client.process(np.zeros((5, 16)))  # 5 rows -> bucket of 8
         g1 = GOODPUT.snapshot()
+        if not armed:
+            assert g1["flops"] == g0["flops"]
+            assert g1["launches"] == g0["launches"]
+            return
         du = _delta(g1, g0, "flops", "useful")
         dp = _delta(g1, g0, "flops", "pad")
         assert du == 8 * fpr, "3 + 5 useful rows"
@@ -501,60 +493,3 @@ def test_fleet_goodput_merge_recomputes_from_sums():
     assert set(merged["sources"]) == {"replica a", "replica b"}
 
 
-def test_bench_gate_serving_mfu_and_pad_ratio_skip_and_fail():
-    """Satellite: rounds predating ISSUE 14 skip per-metric; a lower
-    mfu or a higher pad_ratio past threshold fails."""
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_gate",
-        os.path.join(os.path.dirname(__file__), "..", "tools",
-                     "bench_gate.py"),
-    )
-    bench_gate = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench_gate)
-    base = {"backend": "cpu", "value": 100.0}
-    prev_no_section = dict(base, serving={"coalesced": {"rps": 50.0}})
-    cur = dict(base, serving={
-        "goodput": {"mfu": 0.02, "pad_ratio": 0.2},
-    })
-    verdict = bench_gate.compare(prev_no_section, cur)
-    rows = {r["metric"]: r for r in verdict["metrics"]}
-    assert "skipped" in rows["serving_mfu"]
-    assert "skipped" in rows["serving_pad_ratio"]
-    prev = dict(base, serving={"goodput": {"mfu": 0.02, "pad_ratio": 0.2}})
-    cur_reg = dict(base,
-                   serving={"goodput": {"mfu": 0.015, "pad_ratio": 0.3}})
-    verdict = bench_gate.compare(prev, cur_reg)
-    assert "serving_mfu" in verdict["regressions"], \
-        "mfu is higher-is-better"
-    assert "serving_pad_ratio" in verdict["regressions"], \
-        "pad_ratio is lower-is-better"
-    cur_ok = dict(base,
-                  serving={"goodput": {"mfu": 0.021, "pad_ratio": 0.19}})
-    verdict = bench_gate.compare(prev, cur_ok)
-    assert verdict["regressions"] == []
-
-
-def test_goodput_overhead_smoke_accounting_within_noise():
-    """Acceptance: the armed-vs-disarmed accounting A/B — a few
-    integer adds per launch must stay within noise of free (the bench
-    targets >= 0.95; the CI bound is looser for shared-box jitter) and
-    the armed arm must actually have recorded launches."""
-    import jax
-
-    import bench
-    from tpu_dist_nn.api.engine import Engine
-    from tpu_dist_nn.models.fcnn import init_fcnn, spec_from_params
-
-    params = init_fcnn(jax.random.key(0), [16, 8, 4])
-    engine = Engine.up(spec_from_params(params, ["relu", "softmax"]))
-    res = bench.goodput_overhead_bench(
-        clients=4, rpcs_per_client=8, rows_per_rpc=3, repeats=2,
-        engine=engine,
-    )
-    assert GOODPUT.enabled, "the A/B must restore the armed default"
-    assert res["armed_launches_recorded"] > 0
-    assert res["ratio_raw"] >= 0.8, res
-    assert res["ratio"] <= 1.0

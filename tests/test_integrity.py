@@ -23,7 +23,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 @pytest.fixture(autouse=True)
 def _armed_guard():
-    """Every test here assumes the guard is armed (the bench A/B and a
+    """Every test here assumes the guard is armed (another test's A/B and a
     TDN_INTEGRITY_GUARD=0 environment may have disarmed the process
     singleton); restore whatever the session had."""
     prev = integrity.GUARD.enabled

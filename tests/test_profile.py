@@ -1,24 +1,18 @@
-"""Perf attribution + regression sentinel (ISSUE 6): per-stage
+"""Perf attribution (ISSUE 6): per-stage
 self-time breakdown (obs/profile.py + GET /profile + `tdn profile`),
 on-demand device capture (GET /debug/profile), structured JSON logging
-(obs/log.py), the int8 warmup payoff gauge, and tools/bench_gate.py.
+(obs/log.py) and the int8 warmup payoff gauge.
 
 The loopback acceptance path: a served engine hit through GrpcClient
 must yield a /profile breakdown whose stage shares sum to within 5% of
 the measured root-span wall time — for both the Process and Generate
-wire paths. The bench gate must fail a synthetic >5% host-fed
-regression, pass a -4% one, skip cleanly across backends, and exit
-zero on the checked-in r04->r05 pair only in report-only mode.
+wire paths.
 """
 
 import dataclasses
-import importlib.util
 import io
 import json
 import logging
-import os
-import subprocess
-import sys
 import time
 import urllib.error
 import urllib.request
@@ -35,17 +29,6 @@ from tpu_dist_nn.obs.profile import (
     profile_snapshot,
 )
 from tpu_dist_nn.obs.trace import TRACER, Tracer
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH_GATE = os.path.join(REPO_ROOT, "tools", "bench_gate.py")
-
-
-def _load_bench_gate():
-    spec = importlib.util.spec_from_file_location("bench_gate", BENCH_GATE)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
 
 def _get(url: str, timeout: float = 10.0) -> tuple[int, bytes]:
     try:
@@ -522,287 +505,3 @@ def test_int8_warm_measure_runs_once_and_honors_env_gate(monkeypatch):
         assert len(calls) == 1, "re-warm must not re-measure"
     finally:
         engine.down()
-
-
-# ---------------------------------------------------------- bench gate
-
-
-def _round(value=100000.0, *, backend="cpu", device=250000.0,
-           rps=1000.0, gen_rps=60.0, ttft=12.0, prefix_rps=70.0,
-           prefix_ttft=40.0) -> dict:
-    return {
-        "value": value,
-        "device_resident_samples_per_sec": device,
-        "backend": backend,
-        "serving": {
-            "coalesced": {"rps": rps},
-            "generate": {"requests_per_s": gen_rps,
-                         "ttft_p99_ms": ttft},
-            "generate_prefix": {"rps": prefix_rps,
-                                "ttft_p99_ms": prefix_ttft},
-        },
-    }
-
-
-def test_bench_gate_passes_small_regression_fails_big():
-    gate = _load_bench_gate()
-    prev = _round(100000.0)
-    ok = gate.compare(prev, _round(96000.0))       # -4%
-    assert ok["regressions"] == []
-    assert not any(r.get("failed") for r in ok["metrics"])
-    bad = gate.compare(prev, _round(94000.0))      # -6%
-    assert bad["regressions"] == ["host_fed_samples_per_sec"]
-    row = next(r for r in bad["metrics"]
-               if r["metric"] == "host_fed_samples_per_sec")
-    assert row["failed"] and row["regression"] == pytest.approx(0.06)
-
-
-def test_bench_gate_improvements_never_fail():
-    gate = _load_bench_gate()
-    v = gate.compare(_round(100000.0),
-                     _round(150000.0, device=500000.0, rps=2000.0,
-                            gen_rps=100.0, ttft=5.0))
-    assert v["regressions"] == []
-
-
-def test_bench_gate_ttft_gates_the_lower_is_better_direction():
-    gate = _load_bench_gate()
-    v = gate.compare(_round(ttft=10.0), _round(ttft=11.0))  # +10% TTFT
-    assert v["regressions"] == ["generate_ttft_p99_ms"]
-    # TTFT down 10% is an improvement, not a regression.
-    v = gate.compare(_round(ttft=10.0), _round(ttft=9.0))
-    assert v["regressions"] == []
-
-
-def test_bench_gate_skips_cleanly_when_backends_differ():
-    gate = _load_bench_gate()
-    v = gate.compare(_round(backend="cpu-fallback"),
-                     _round(50000.0, backend="tpu v4"))
-    assert "skipped" in v and "backend" in v["skipped"]
-    assert "metrics" not in v
-
-
-def test_bench_gate_skips_absent_metrics_per_metric():
-    gate = _load_bench_gate()
-    prev = _round()
-    cur = _round(96000.0)
-    del cur["serving"]["generate"]
-    v = gate.compare(prev, cur)
-    skipped = {r["metric"] for r in v["metrics"] if "skipped" in r}
-    assert {"generate_rps", "generate_ttft_p99_ms"} <= skipped
-    assert v["regressions"] == []
-
-
-def test_bench_gate_gates_shared_prefix_metrics_both_directions():
-    gate = _load_bench_gate()
-    prev = _round()
-    # The shared-prefix rps dropping >5% fails; its TTFT p99 RISING
-    # >5% fails (lower-is-better direction).
-    v = gate.compare(prev, _round(prefix_rps=60.0))
-    assert v["regressions"] == ["gen_prefix_rps"]
-    v = gate.compare(prev, _round(prefix_ttft=45.0))
-    assert v["regressions"] == ["gen_prefix_ttft_p99_ms"]
-    # Improvements on both never fail.
-    v = gate.compare(prev, _round(prefix_rps=90.0, prefix_ttft=30.0))
-    assert v["regressions"] == []
-    # Rounds that predate the generate_prefix section skip per-metric.
-    old = _round()
-    del old["serving"]["generate_prefix"]
-    v = gate.compare(old, _round())
-    skipped = {r["metric"] for r in v["metrics"] if "skipped" in r}
-    assert {"gen_prefix_rps", "gen_prefix_ttft_p99_ms"} <= skipped
-    assert v["regressions"] == []
-
-
-def test_bench_gate_attribution_folds_profile_into_report():
-    gate = _load_bench_gate()
-    verdict = gate.compare(_round(), _round(90000.0))
-    profile = {"methods": {"Process": {
-        "traces": 10,
-        "stages": [{"stage": "fetch", "share": 0.6, "p99_s": 0.004}],
-    }}}
-    report = gate.render_report(verdict, "cur.json", "prev.json", profile)
-    assert "REGRESSED" in report
-    assert "fetch 60.0%" in report
-
-
-def test_bench_gate_report_only_on_checked_in_rounds():
-    """The quick-tier smoke from the issue: the checked-in r04->r05
-    pair (which carries a real serving regression) exits ZERO in
-    report-only mode and NONZERO in enforce mode."""
-    base = [sys.executable, BENCH_GATE,
-            "--current", os.path.join(REPO_ROOT, "BENCH_r05.json"),
-            "--previous", os.path.join(REPO_ROOT, "BENCH_r04.json")]
-    report = subprocess.run(
-        base + ["--report-only", "--json"], capture_output=True, text=True,
-    )
-    assert report.returncode == 0, report.stderr
-    assert "host_fed_samples_per_sec" in report.stdout
-    verdict = json.loads(report.stdout.strip().splitlines()[-1])
-    assert verdict["report_only"] is True
-    enforced = subprocess.run(base, capture_output=True, text=True)
-    assert enforced.returncode == 1
-    assert "REGRESSED" in enforced.stdout
-
-
-def test_bench_gate_enforce_fails_synthetic_regression(tmp_path):
-    """Enforce mode on a synthetic >5% host-fed regression exits
-    nonzero; the same pair at -4% exits zero."""
-    prev = tmp_path / "BENCH_r01.json"
-    prev.write_text(json.dumps({"parsed": _round(100000.0)}))
-
-    def run(cur_value):
-        cur = tmp_path / "BENCH_r02.json"
-        cur.write_text(json.dumps({"parsed": _round(cur_value)}))
-        return subprocess.run(
-            [sys.executable, BENCH_GATE, "--dir", str(tmp_path)],
-            capture_output=True, text=True,
-        )
-
-    failing = run(90000.0)   # -10% host-fed
-    assert failing.returncode == 1, failing.stdout + failing.stderr
-    assert "host_fed_samples_per_sec" in failing.stdout
-    passing = run(96000.0)   # -4%
-    assert passing.returncode == 0, passing.stdout + passing.stderr
-
-
-def test_bench_gate_explicit_previous_needs_only_one_round(tmp_path):
-    """--previous pointing outside --dir must not demand a second
-    discoverable round (the CI-checkout-with-one-artifact case)."""
-    (tmp_path / "BENCH_r02.json").write_text(
-        json.dumps({"parsed": _round(96000.0)})
-    )
-    prev = tmp_path / "elsewhere_prev.json"
-    prev.write_text(json.dumps({"parsed": _round(100000.0)}))
-    proc = subprocess.run(
-        [sys.executable, BENCH_GATE, "--dir", str(tmp_path),
-         "--previous", str(prev)],
-        capture_output=True, text=True,
-    )
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert "host_fed_samples_per_sec" in proc.stdout
-
-
-def test_bench_gate_usage_errors_exit_two(tmp_path):
-    proc = subprocess.run(
-        [sys.executable, BENCH_GATE, "--current", "nope.json",
-         "--previous", "also_nope.json"],
-        capture_output=True, text=True,
-    )
-    assert proc.returncode == 2
-    proc = subprocess.run(
-        [sys.executable, BENCH_GATE, "--dir", str(tmp_path)],
-        capture_output=True, text=True,
-    )
-    assert proc.returncode == 2  # no rounds to discover
-
-
-# ------------------------------------------- best-of-history gate mode
-
-
-def test_bench_gate_history_fails_checked_in_host_fed_drift():
-    """The ISSUE-10 quick-tier smoke: r02->r05 host-fed drifted −3%/
-    round — under the pairwise 5% threshold every single time — and
-    compounded to −15% vs the r02 best. Best-of-history mode must fail
-    that trajectory on the CHECKED-IN rounds."""
-    # --current is PINNED to r05: once a later (recovered) round is
-    # checked in, discovery would gate that instead and the drift this
-    # smoke exists to reproduce would vanish.
-    proc = subprocess.run(
-        [sys.executable, BENCH_GATE, "--history", "BENCH_r*.json",
-         "--dir", REPO_ROOT, "--json",
-         "--current", os.path.join(REPO_ROOT, "BENCH_r05.json")],
-        capture_output=True, text=True,
-    )
-    assert proc.returncode == 1, proc.stdout + proc.stderr
-    verdict = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert verdict["mode"] == "best-of-history"
-    assert "host_fed_samples_per_sec" in verdict["regressions"]
-    row = next(r for r in verdict["metrics"]
-               if r["metric"] == "host_fed_samples_per_sec")
-    # The bar is the r02 high-water mark, not the r04 predecessor.
-    assert row["best_round"] == "BENCH_r02.json"
-    assert row["regression"] > 0.10
-    # r01 (a failed round with no payload) is no longer checked in.
-    assert "BENCH_r01.json" not in verdict["history_rounds"]
-    # Report-only still exits 0 on the same trajectory.
-    report = subprocess.run(
-        [sys.executable, BENCH_GATE, "--history", "BENCH_r*.json",
-         "--dir", REPO_ROOT, "--report-only",
-         "--current", os.path.join(REPO_ROOT, "BENCH_r05.json")],
-        capture_output=True, text=True,
-    )
-    assert report.returncode == 0, report.stdout + report.stderr
-
-
-def test_bench_gate_history_passes_flat_trajectory(tmp_path):
-    """A flat (or improving) trajectory with per-round jitter under
-    the threshold passes: best-of-history is a drift gate, not a
-    noise amplifier."""
-    for i, v in enumerate([100000.0, 99000.0, 101000.0, 99500.0], 1):
-        (tmp_path / f"BENCH_r{i:02d}.json").write_text(
-            json.dumps({"parsed": _round(v)})
-        )
-    proc = subprocess.run(
-        [sys.executable, BENCH_GATE, "--history", "BENCH_r*.json",
-         "--dir", str(tmp_path)],
-        capture_output=True, text=True,
-    )
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert "all gated metrics within threshold" in proc.stdout
-
-
-def test_bench_gate_history_compounding_drift_fails_where_pairwise_passes():
-    """The boiling-frog unit case: −3%/round for 5 rounds. Every
-    pairwise diff is green; best-of-history fails."""
-    gate = _load_bench_gate()
-    values = [100000.0]
-    for _ in range(4):
-        values.append(values[-1] * 0.97)
-    rounds = [(f"BENCH_r{i:02d}.json", _round(v))
-              for i, v in enumerate(values, 1)]
-    cur = rounds[-1][1]
-    # Pairwise: green.
-    pair = gate.compare(rounds[-2][1], cur)
-    assert pair["regressions"] == []
-    # Best-of-history: −11.5% vs r01's high-water mark — fails.
-    hist = gate.compare_history(rounds[:-1], cur)
-    assert "host_fed_samples_per_sec" in hist["regressions"]
-    row = next(r for r in hist["metrics"]
-               if r["metric"] == "host_fed_samples_per_sec")
-    assert row["best_round"] == "BENCH_r01.json"
-
-
-def test_bench_gate_history_skips_other_backend_rounds_per_round():
-    """History legitimately spans a backend flap: rounds from another
-    backend are excluded per-ROUND; only when NO same-backend history
-    exists does the whole gate skip."""
-    gate = _load_bench_gate()
-    history = [
-        ("BENCH_r01.json", _round(500000.0, backend="tpu v4")),
-        ("BENCH_r02.json", _round(100000.0, backend="cpu")),
-    ]
-    cur = _round(98000.0, backend="cpu")
-    v = gate.compare_history(history, cur)
-    assert v["history_rounds"] == ["BENCH_r02.json"]
-    assert v["regressions"] == []  # −2% vs the cpu best, tpu best ignored
-    all_tpu = [("BENCH_r01.json", _round(backend="tpu v4"))]
-    v = gate.compare_history(all_tpu, cur)
-    assert "skipped" in v and "backend" in v["skipped"]
-
-
-def test_bench_gate_history_lower_is_better_uses_min_as_best():
-    gate = _load_bench_gate()
-    history = [
-        ("BENCH_r01.json", _round(ttft=20.0)),
-        ("BENCH_r02.json", _round(ttft=10.0)),  # the TTFT high-water mark
-        ("BENCH_r03.json", _round(ttft=18.0)),
-    ]
-    v = gate.compare_history(history, _round(ttft=11.0))
-    assert "generate_ttft_p99_ms" in v["regressions"]
-    row = next(r for r in v["metrics"]
-               if r["metric"] == "generate_ttft_p99_ms")
-    assert row["best_round"] == "BENCH_r02.json"
-    # Matching the best passes.
-    v = gate.compare_history(history, _round(ttft=10.0))
-    assert v["regressions"] == []

@@ -117,7 +117,7 @@ def test_engine_serves_quantized(tmp_path):
 
 
 def test_int8_auto_disable_routes_serving_to_f32(tmp_path, monkeypatch):
-    # The auto-fallback closing the BENCH int8_vs_f32 regression: when
+    # The auto-fallback for an int8 path that loses to f32: when
     # the warmup payoff measurement finds int8 SLOWER than f32, serving
     # launches reroute to the f32 path (outputs become bit-identical to
     # an unquantized engine's) instead of shipping the measured loss.

@@ -3,11 +3,9 @@ seeded workload generators (bit-deterministic), the incident-bundle ->
 WorkloadTrace -> replay round trip (exact request mix, session pinning,
 per-decile arrival fidelity), FaultPlan's seeded-probability mode, the
 stream-resume metadata bound at its exact boundary (router ledger +
-replica backstop), a quick-scaled scenario verdict smoke, and the
-bench_gate scenario_pass_ratio skip/fail contract."""
+replica backstop), and a quick-scaled scenario verdict smoke."""
 
 import os
-import sys
 
 import grpc
 import numpy as np
@@ -255,7 +253,7 @@ def test_scenario_quick_smoke_deterministic_verdict():
 
 
 def test_scenario_dir_has_full_matrix():
-    # The checked-in matrix the bench embeds: at least 8 cells, at
+    # The checked-in matrix `--scenario-dir` runs: at least 8 cells, at
     # least 3 distinct generators, at least 2 with fault crossings,
     # and at least one bundle-derived (capture) cell.
     paths = R.scenario_paths(os.path.join(REPO, "scenarios"))
@@ -276,27 +274,3 @@ def test_scenario_dir_has_full_matrix():
     assert captured >= 1
 
 
-def test_bench_gate_scenario_pass_ratio_skip_and_fail():
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import bench_gate
-    finally:
-        sys.path.pop(0)
-
-    def round_doc(ratio=None):
-        doc = {"backend": "cpu", "value": 100000.0, "serving": {}}
-        if ratio is not None:
-            doc["serving"]["scenarios"] = {"pass_ratio": ratio}
-        return doc
-
-    # Pre-ISSUE-18 previous round: the row skips, nothing fails.
-    verdict = bench_gate.compare(round_doc(), round_doc(1.0))
-    rows = {m["metric"]: m for m in verdict["metrics"]}
-    assert "skipped" in rows["scenario_pass_ratio"]
-    assert not verdict["regressions"]
-    # A cell newly failing its SLO verdict drops the ratio past the
-    # threshold and fails the enforced gate.
-    verdict = bench_gate.compare(round_doc(1.0), round_doc(0.75))
-    assert "scenario_pass_ratio" in verdict["regressions"]
-    verdict = bench_gate.compare(round_doc(1.0), round_doc(1.0))
-    assert not verdict["regressions"]

@@ -1786,30 +1786,3 @@ def test_forward_timeout_caps_deadline_less_forwards():
 # ------------------------------------------------------------- bench gate
 
 
-def test_bench_gate_router_rps_skip_and_fail():
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_gate",
-        os.path.join(os.path.dirname(__file__), "..", "tools",
-                     "bench_gate.py"),
-    )
-    bench_gate = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench_gate)
-    base = {"backend": "cpu", "value": 100.0}
-    prev_no_router = dict(base, serving={"coalesced": {"rps": 50.0}})
-    cur = dict(base, serving={
-        "coalesced": {"rps": 50.0}, "router": {"rps": 300.0},
-    })
-    verdict = bench_gate.compare(prev_no_router, cur)
-    rows = {r["metric"]: r for r in verdict["metrics"]}
-    assert "skipped" in rows["router_rps"], \
-        "rounds predating the router section must skip, not fail"
-    prev = dict(base, serving={"router": {"rps": 300.0}})
-    cur_reg = dict(base, serving={"router": {"rps": 250.0}})
-    verdict = bench_gate.compare(prev, cur_reg)
-    assert "router_rps" in verdict["regressions"]
-    cur_ok = dict(base, serving={"router": {"rps": 296.0}})
-    verdict = bench_gate.compare(prev, cur_ok)
-    assert "router_rps" not in verdict["regressions"]

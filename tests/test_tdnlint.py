@@ -2,14 +2,11 @@
 the right id and line, stays silent on the clean twin, and the `tdn
 lint` gate holds in both directions — exit 0 on the shipped tree
 (zero non-baselined findings), exit 1 on a planted violation. Also
-covers the suppression and baseline workflows (docs/STATIC_ANALYSIS.md)
-and the bench_gate report-header integration."""
+covers the suppression and baseline workflows (docs/STATIC_ANALYSIS.md)."""
 
 import json
 import os
 import shutil
-import subprocess
-import sys
 
 import pytest
 
@@ -182,17 +179,3 @@ def test_lint_json_line_is_machine_readable(tmp_path, capsys):
     assert doc["findings"][0]["line"] == _marker_lines(planted)[0]
 
 
-def test_bench_gate_report_only_mentions_lint_status():
-    """The regression report and invariant drift surface in one place:
-    --report-only carries a lint: header line (clean on the shipped
-    tree), enforce mode stays a pure perf verdict."""
-    gate = os.path.join(REPO_ROOT, "tools", "bench_gate.py")
-    base = [sys.executable, gate,
-            "--current", os.path.join(REPO_ROOT, "BENCH_r05.json"),
-            "--previous", os.path.join(REPO_ROOT, "BENCH_r04.json")]
-    report = subprocess.run(base + ["--report-only"],
-                            capture_output=True, text=True)
-    assert report.returncode == 0, report.stderr
-    assert "lint: clean" in report.stdout
-    enforce = subprocess.run(base, capture_output=True, text=True)
-    assert "lint:" not in enforce.stdout

@@ -5,7 +5,7 @@ Settles the width-gate question raised in review
 kernel stop paying? Not measured on today's code. The one round-4 run on
 a v5e found no sharp crossover at uniform widths and a decisive jnp win
 only when interior dims sit below the 128-lane MXU tile; a narrow
-classifier head did not matter. Timing as bench.py::_time_resident.
+classifier head did not matter. Timing as tools/kernel_sweep.py.
 """
 import time, json, sys
 import jax, jax.numpy as jnp, numpy as np

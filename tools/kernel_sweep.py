@@ -1,17 +1,17 @@
 """Shape sweep on live hardware: where do the Pallas kernels win?
 
-The first chip measurements (round 4, tools/resident_probe.py) showed
-XLA's own fusion beating the hand-written fused/int8 Pallas chains at
-the flagship's tiny widths (784-128-64-10). This sweep maps the
-crossover: dense chains at
+The first chip measurements (round 4; their records and the probe
+that took them are gone) showed XLA's own fusion beating the
+hand-written fused/int8 Pallas chains at the flagship's tiny widths
+(784-128-64-10). This sweep maps the crossover: dense chains at
 growing widths (f32 XLA vs fused Pallas vs int8 jnp vs int8 Pallas)
 and attention at growing sequence lengths (XLA dot-product attention
 vs the flash kernel, forward and forward+grad) — so kernel selection
 can be gated on measured wins, not assumptions.
 
-Timing: bench.py::_time_resident's method — chained passes inside one
-jit, a distinct seed per call, a value fetch to close it, the
-dispatch+fetch floor subtracted. Needs a TPU; fails without one.
+Timing: chained passes inside one jit, a distinct seed per call, a
+value fetch to close it, the dispatch+fetch floor subtracted (built
+for a remote chip: ROADMAP D4). Needs a TPU; fails without one.
 
 Emits one JSON line per configuration plus a trailing summary line.
 """
@@ -54,7 +54,7 @@ def main() -> int:
     )
     from tpu_dist_nn.models.fcnn import forward, init_fcnn
 
-    # RTT floor (see bench.py::_rtt_floor).
+    # The dispatch+fetch floor: a trivial jitted call, fetched.
     @jax.jit
     def _trivial(seed):
         return seed * jnp.float32(2.0) + jnp.float32(1.0)

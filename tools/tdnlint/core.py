@@ -31,8 +31,8 @@ import re
 
 class LintError(Exception):
     """A scan target could not be read or parsed. Raised (not
-    SystemExit) so library callers — run_lint from cli.py, bench_gate's
-    fail-safe lint header, tests — can degrade instead of dying; only
+    SystemExit) so library callers — run_lint from cli.py and the
+    tests — can degrade instead of dying; only
     tdnlint.main() converts it to an exit code."""
 
 

@@ -116,8 +116,8 @@ _WARM_BUCKETS = REGISTRY.gauge(
 # Measured at warm_buckets time on quantized engines: f32 wall time /
 # int8 wall time for one warmed-bucket launch. > 1 means the int8 path
 # pays off on the active backend; < 1 means quantized serving is
-# SLOWER here (the BENCH int8_vs_f32 0.24-0.48x regression, made
-# visible at serve time instead of only in the round artifacts).
+# SLOWER here (0.24-0.48x on a CPU in rounds 4 and 5; made visible
+# at serve time, where the operator of that backend can see it).
 _INT8_RATIO = REGISTRY.gauge(
     "tdn_int8_speedup_ratio",
     "f32 launch wall time / int8 launch wall time on the largest warm "

@@ -25,8 +25,8 @@ metrics layer the serving/training hot paths publish into:
   - :mod:`tpu_dist_nn.obs.profile` — performance attribution: completed
     spans folded into a per-stage SELF-time breakdown (p50/p99/share
     per stage, per method), served from ``GET /profile`` (``tdn
-    profile`` pretty-prints it; ``tools/bench_gate.py`` folds it into
-    regression reports).
+    profile`` pretty-prints it; ``tdn metrics --aggregate --profile``
+    merges a fleet's).
   - :mod:`tpu_dist_nn.obs.log` — structured JSON logging: event-shaped,
     trace-correlated, rate-limited records for the serving/engine
     operational paths (``tdn --log-json`` renders the whole process's
@@ -52,8 +52,8 @@ metrics layer the serving/training hot paths publish into:
     prefill/decode at their static kernel shapes) fed at the
     launch/fetch boundaries, every launch split exactly into
     ``useful + pad`` FLOPs with a pad taxonomy (bucket rows,
-    idle/frozen slots, masked attention tails), one shared peak
-    calibration with bench.py, ``tdn_mfu_ratio`` /
+    idle/frozen slots, masked attention tails), one peak table
+    for every process, ``tdn_mfu_ratio`` /
     ``tdn_pad_ratio{path}`` / ``tdn_goodput_flops_total{kind}`` /
     ``tdn_prefix_flops_saved_total``, and ``GET /goodput``.
   - :mod:`tpu_dist_nn.obs.incident` — the flight recorder: detectors

@@ -3,7 +3,7 @@ workload actually uses, and where the rest went.
 
 Every observability layer so far measures TIME (latency histograms,
 traces, /profile self-time, SLO burn) — none measures UTILIZATION.
-``bench.py``'s ``mfu`` comes from a synthetic offline matmul sweep, so
+A training run's MFU says nothing of a server under its traffic, so
 the serving path has no absolute-efficiency axis at all, and the
 dominant serving waste Orca names (pad rows in static buckets, idle /
 frozen decode slots at iteration granularity) is invisible. This module
@@ -28,10 +28,10 @@ is the accounting half:
   for direct host-fed calls).
 * **One peak calibration** — :data:`PEAK_FLOPS` (the per-device-kind
   dense bf16 table) and :func:`host_calibration_gflops` (the
-  jax-independent host-BLAS anchor) moved here FROM bench.py, and
-  bench.py now imports them back — offline ``mfu`` and the runtime
-  ``tdn_mfu_ratio`` resolve their peak through the same code, so the
-  two can never use divergent peaks. A CPU process uses the measured
+  jax-independent host-BLAS anchor) live here and nowhere else: the
+  engine, both generation schedulers and the runtime
+  ``tdn_mfu_ratio`` resolve their peak through the same code, so no
+  two can ever use divergent peaks. A CPU process uses the measured
   host anchor; an accelerator the table does not know is an error.
 
 Exports (docs/OBSERVABILITY.md "Goodput & MFU"):
@@ -48,8 +48,8 @@ Exports (docs/OBSERVABILITY.md "Goodput & MFU"):
 
 Cost discipline: recording is a handful of integer adds per DEVICE
 LAUNCH (not per request, not per row) on the thread that already owns
-the launch; the armed-vs-disarmed A/B in bench.py keeps the bill
-honest.
+the launch; ``enabled = False`` turns it off, and tests hold the
+disarmed ledger to zero.
 """
 
 from __future__ import annotations
@@ -61,8 +61,8 @@ from tpu_dist_nn.obs.registry import REGISTRY, Registry
 
 # Peak dense bf16 FLOP/s per JAX device, by device_kind substring.
 # v2/v3 expose one device per core (half a chip); v4+ one per chip.
-# (Moved from bench.py — the ONE table both offline and runtime MFU
-# resolve through.)
+# (The ONE table every runtime MFU resolves through; the benchmark
+# keeps its own, benchmark/harness/device.py PEAKS.)
 PEAK_FLOPS = (
     ("v6", 918e12),  # Trillium / v6e chip
     ("v5p", 459e12),
@@ -87,10 +87,10 @@ def device_peak_flops(device_kind: str | None) -> float | None:
 def host_calibration_gflops(reps: int = 5) -> float:
     """Fixed host-BLAS anchor: f32 1024^2 matmul GFLOP/s, min-of-reps.
 
-    jax-independent, so it measures the BOX, not the framework. Records
-    in bench JSON so cross-round deltas can separate machine drift from
-    code drift (docs/PERF.md "Cross-round drift"), and doubles as the
-    utilization denominator of a process that runs on the host CPU: the
+    jax-independent, so it measures the BOX, not the framework: two
+    readings apart in time separate the machine's drift from the
+    code's. It is what a process on the host CPU gets as its
+    utilization denominator: the
     best this host can do at a dense matmul.
     """
     import numpy as np
@@ -248,8 +248,8 @@ class GoodputTracker:
     adds under one lock; :meth:`tick` runs on the runtime-sampler tick
     and only does float math over the ledger (tick-pure — peak
     calibration happens in :meth:`ensure_peak` at configure time).
-    ``enabled = False`` turns every record into a no-op (the disarmed
-    arm of bench.py's overhead A/B).
+    ``enabled = False`` turns every record into a no-op (tests use
+    it as the control: a disarmed ledger does not move).
     """
 
     def __init__(self, registry: Registry | None = None):

@@ -1,8 +1,8 @@
 """Performance attribution: spans -> per-stage SELF-time breakdown.
 
 The tracer (obs/trace.py) answers "where did THIS request go"; nothing
-answered "where does the time go in AGGREGATE" — the question the bench
-trajectory raises (host-fed throughput decaying while device-resident
+answered "where does the time go in AGGREGATE" — the question a
+falling rate raises (host-fed throughput decaying while device-resident
 holds: which stage is eating it?). This module folds the tracer's
 completed spans into a rolling per-stage profile:
 
@@ -29,8 +29,8 @@ completed spans into a rolling per-stage profile:
 Stdlib-only, read-only over a snapshot: profiling a live server never
 takes the tracer's lock for longer than ``snapshot()`` does, and never
 touches a device. Serves ``GET /profile`` (obs/exposition.py) and
-``tdn profile`` (cli.py); ``tools/bench_gate.py`` folds the breakdown
-into its regression reports.
+``tdn profile`` (cli.py); obs/collect.py merges the breakdowns of a
+fleet's replicas into one table.
 """
 
 from __future__ import annotations

@@ -38,8 +38,8 @@ any window. This module closes the loop:
   objectives; the run is scored by the real
   :class:`~tpu_dist_nn.obs.slo.SLOTracker` over a
   :class:`~tpu_dist_nn.obs.timeseries.TimeSeriesRing`, and the verdict
-  is machine-readable (bench.py embeds it; tools/bench_gate.py gates
-  ``scenario_pass_ratio``).
+  is machine-readable (``tdn replay --scenario-dir`` prints the
+  matrix's ``pass_ratio``).
 
 Stdlib + numpy + grpc only — importable (and runnable) without jax;
 the tier-1 quick smoke drives a scenario end-to-end in seconds.

@@ -17,8 +17,8 @@ Design constraints (same discipline as the registry):
 * **Head sampling** — the root of a trace decides once
   (``sample_rate``); the decision rides the wire so every process in a
   chain keeps or drops the SAME requests. Rate 0 reduces every hot-path
-  call to an id draw and a boolean check (the bench ``--overlap``
-  no-regression bar).
+  call to an id draw and a boolean check (nothing is recorded, no
+  lock is taken).
 * **Bounded memory** — completed spans live in a ring buffer
   (``capacity``); eviction ticks ``dropped_total``. A fixed set of
   *exemplar slots* always keeps the slowest locally-rooted traces seen

@@ -2,7 +2,7 @@
 zero-bubble W tick.
 
 The round-5 wall-clock measurement (docs/PERF.md "Do ticks translate
-to time?", `artifacts/schedule_walltime_r05/`) showed the executor's
+to time?", `examples/schedule_walltime.py`) showed the executor's
 recompute-based split backward pays the chunk FORWARD in both halves
 (BWD_B and BWD_W each rebuild the vjp from the stashed input), so the
 zero-bubble schedules' tick-level advantage does not survive measured

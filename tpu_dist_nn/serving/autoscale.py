@@ -125,7 +125,7 @@ class Autoscaler:
 
     ``spawner`` is a zero-arg callable that adds one replica to the
     pool and blocks until it serves (the CLI wires
-    ``pool.spawn_local(config, ...)``; tests and the bench inject
+    ``pool.spawn_local(config, ...)``; tests and scenarios inject
     in-process fakes). ``slo`` is the router's
     :class:`~tpu_dist_nn.obs.slo.SLOTracker` (None = utilization-only
     policy).

@@ -478,7 +478,7 @@ class ContinuousScheduler:
     ``tdn_prefix_cache_*`` families.
 
     ``prefill_fn`` / ``step_fn`` / ``copy_fn`` are testing seams (the
-    bench CI smokes inject deterministic cost models); production
+    count tests inject kernels that move no device); production
     always builds the real jitted kernels from ``params``/``cfg``.
     """
 
@@ -830,7 +830,7 @@ class ContinuousScheduler:
         return dict(zip(_trace.ITER_FIELDS, self._clock.record()))
 
     # Prefix-cache accounting (None-safe: 0 with the pool off, so the
-    # sampler/bench read one shape regardless of configuration).
+    # sampler reads one shape regardless of configuration).
     @property
     def prefix_blocks(self) -> int:
         return self._P

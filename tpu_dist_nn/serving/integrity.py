@@ -25,7 +25,7 @@ that it is reachable:
   :class:`~tpu_dist_nn.utils.errors.IntegrityError` (wire: DATA_LOSS)
   instead of shipping NaN activations; unaffected rows in the same
   launch are untouched (bit-parity preserved). ``TDN_INTEGRITY_GUARD=0``
-  or ``GUARD.enabled = False`` opts out (benches).
+  or ``GUARD.enabled = False`` opts out (tests' control).
 * **Canary probes** (:class:`CanaryProber`) — a fixed seeded input
   with a golden temperature-0 answer, ridden on the pool's scrape
   loop. The serving stack is bit-identical at temperature 0 across
@@ -176,13 +176,13 @@ class NumericGuard:
     ``bad_rows(out)`` reduces a materialized float batch to a ``(N,)``
     bool mask of rows carrying non-finite values or magnitudes past
     ``abs_limit`` — one vectorized pass over memory the caller just
-    materialized anyway, so arming it costs well under the 5%
-    throughput budget the bench gates. Callers fail exactly the masked
+    materialized anyway; its cost on the decode loop has no chip
+    reading yet (ROADMAP D7). Callers fail exactly the masked
     rows with IntegrityError and ship the rest untouched.
 
     Disabled via ``TDN_INTEGRITY_GUARD=0`` at import, or
-    ``GUARD.enabled = False`` at runtime (the bench A/B's disarmed
-    arm)."""
+    ``GUARD.enabled = False`` at runtime (what tests use as the
+    control)."""
 
     def __init__(self, enabled: bool | None = None,
                  abs_limit: float = 1e8):
@@ -217,7 +217,7 @@ class NumericGuard:
 
 
 # Process-wide guard instance — the serving batcher, the continuous
-# scheduler, and the bench A/B all arm/disarm THIS object.
+# scheduler, and the tests all arm/disarm THIS object.
 GUARD = NumericGuard()
 
 
@@ -461,7 +461,7 @@ class SpotChecker:
 
 
 def overhead_snapshot() -> dict:
-    """Counter totals for bench artifacts (absent families read 0)."""
+    """Counter totals in one dict (absent families read 0)."""
     def total(name: str) -> float:
         m = REGISTRY.get(name)
         if m is None:

@@ -1162,7 +1162,7 @@ class ReplicaPool:
             # claimed: the breaker registry entry (+ its
             # tdn_breaker_state series) and the healthy series. A
             # long-lived process cycling pools over ephemeral-port
-            # replicas (bench, tests) must not accumulate dead series
+            # replicas (scenarios, tests) must not accumulate dead series
             # forever, and a later pool reusing an address must not
             # inherit this one's breaker history.
             _retire_replica_series(rep.target)

@@ -120,8 +120,8 @@ class _Batcher:
     device result is still materializing — host serialization overlaps
     device execution instead of extending the launch critical section.
     ``pipeline_depth=1`` collapses to the old strictly-serial loop
-    (dispatch fetches inline; the A/B arm ``bench.py --overlap``
-    measures against). Arrival during an in-flight batch remains the
+    (dispatch fetches inline; tests use it as the control that never
+    overlaps). Arrival during an in-flight batch remains the
     coalescing window — no artificial delay is ever inserted.
     """
 
@@ -874,8 +874,8 @@ def serve_engine(engine, port: int, *, max_workers: int = 10,
 
     ``pipeline_depth`` sets the batcher's launch-ahead window (2 =
     double-buffered default: batch N+1 stages and launches while batch
-    N materializes; 1 = the strictly serial legacy loop, kept as the
-    A/B control arm for ``bench.py --overlap``).
+    N materializes; 1 = the strictly serial legacy loop, which tests
+    use as the control).
 
     ``max_pending_rows`` is the admission-control watermark (``tdn up
     --max-pending-rows``): a submit that would queue past it is shed
@@ -1201,8 +1201,8 @@ def serve_lm_generate(params, cfg, port: int, *, max_new_tokens: int,
       Single-chip only (``num_stages == 1``).
     * ``"static"`` — the legacy run-to-completion coalescing batcher in
       front of :func:`~tpu_dist_nn.models.generate.generate` (kept as
-      the A/B control arm, exactly like ``pipeline_depth=1`` for the
-      Process path; ``bench.py --gen-ab`` measures against it).
+      the control, exactly like ``pipeline_depth=1`` for the Process
+      path, and as the only scheduler of the pipelined placement).
     * ``"auto"`` (default) — continuous when ``num_stages == 1`` and
       ``coalesce`` is on; static for the pipelined placement (whose
       overlapped round-robin decoder schedules groups itself) and for

@@ -179,8 +179,8 @@ def encode_matrix(x) -> bytes:
 
 def encode_matrix_scalar(x: np.ndarray) -> bytes:
     """The legacy per-row encoder (3·N list parts + join), kept as the
-    equivalence oracle for tests and the ``bench.py --wire`` A/B
-    control arm. Semantics identical to :func:`encode_matrix`."""
+    equivalence oracle: tests hold :func:`encode_matrix` to its bytes.
+    Nothing serves with it. Semantics identical to :func:`encode_matrix`."""
     x = np.ascontiguousarray(np.asarray(x, dtype="<f8"))
     if x.ndim != 2:
         raise ValueError(f"matrix must be 2-D, got shape {x.shape}")

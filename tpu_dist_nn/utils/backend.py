@@ -1,6 +1,6 @@
 """Backend selection and the compile cache's place — one helper each.
 
-Every entry point that touches a device (``tdn``, ``bench.py``, the
+Every entry point that touches a device (``tdn``, ``benchmark/``, the
 tools, the tests, the multi-chip dry run) goes through here, so there is
 exactly one rule for each question:
 
