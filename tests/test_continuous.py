@@ -743,23 +743,26 @@ def test_mixed_budgets_take_fewer_steps_than_run_to_completion():
     """16 rows on 4 slots, every other one wanting 2 tokens of 32: all
     retire, each slot-step yields a token somebody asked for, the step
     kernel is launched fewer times than run-to-completion's fixed trip
-    count needs, and a row's first token is out before its last."""
+    count needs, and a row's first token is out before its last. The
+    loop runs one launch ahead: the step behind a prompt's last chunk
+    is launched before the chunk's token is read, so the first token is
+    out before any step has been READ, and the gate holds the reads."""
     slots, rows, budget, short = 4, 16, 32, 2
     budgets = [short if i % 2 else budget for i in range(rows)]
     go = threading.Event()
 
-    def wait_for_go():
+    def wait_for_go(toks):
         assert go.wait(30.0)
 
-    sched = _fake_sched(on_step=wait_for_go, slots=slots,
-                        max_new_tokens=budget)
+    sched = _fake_sched(slots=slots, max_new_tokens=budget)
+    sched.fetch_hook = wait_for_go
     try:
         streams = [
             sched.submit_stream(_prompts(1, seed=i), max_new_tokens=b)
             for i, b in enumerate(budgets)
         ]
         # The first row's first token is its prefill's: published while
-        # no step has run and nothing has retired.
+        # no step has been read and nothing has retired.
         assert streams[0].next_event(30.0) == ("tokens", [1])
         assert sched.steps_total == 0 and sched.retired_total == 0
         go.set()
@@ -784,6 +787,9 @@ def test_mixed_budgets_take_fewer_steps_than_run_to_completion():
     # whole budget (generate()'s scan has a fixed trip count).
     assert sched.steps_total < -(-rows // slots) * budget
     assert 0.0 < sched.slot_steps_total / (sched.steps_total * slots) <= 1.0
+    # A budget's end is known before the launch that would pass it: no
+    # lane was computed for nobody.
+    assert sched.discarded_lanes_total == 0
 
 
 @functools.lru_cache(maxsize=None)
@@ -833,3 +839,486 @@ def test_prefilled_tokens_with_the_pool_do_not_grow_with_prompt_length():
     short, _ = _prefilled_tokens(16, pool=True)
     long_, _ = _prefilled_tokens(32, pool=True)
     assert long_ <= short
+
+
+# ------------------------------------------ one launch ahead (ISSUE 30)
+#
+# The loop launches step N+1 from the tokens still on the device before
+# it reads step N. Held to the serial order (a launch hook that reads
+# everything still unread before the next step is launched, which is
+# what the loop did before), the same submissions have to give every
+# stream the same tokens.
+
+EOS = 5
+
+
+def _chain(first, budget, eos=None, prompt_len=T):
+    """What the chain kernels below give one stream, worked out by
+    hand in the serial order: token, then the next from it."""
+    tokens, pos = [first], prompt_len
+    while len(tokens) < budget and tokens[-1] != eos:
+        tokens.append((tokens[-1] * 3 + pos) % 13 + 1)
+        pos += 1
+    return tokens
+
+
+def _first(prompt):
+    return int(prompt[0, -1]) % 5 + 1
+
+
+def _chain_sched(launches=None, on_step=None, **kw):
+    """Stub kernels whose tokens depend on what they are handed: a
+    prompt's first token on its last id, every next token on the one
+    before and its position. ``launches`` takes each step's (pos,
+    active) as launched; ``on_step()`` can hold the device."""
+
+    def fake_prefill(params, cache, slot, tokens, start, key):
+        return np.int32(int(tokens[0, -1]) % 5 + 1), cache
+
+    def fake_step(params, cache, pos, active, tok, key):
+        if launches is not None:
+            launches.append((np.array(pos), np.array(active)))
+        if on_step is not None:
+            on_step()
+        return (np.asarray(tok) * 3 + np.asarray(pos)) % 13 + 1, cache
+
+    kw.setdefault("slots", 3)
+    kw.setdefault("prompt_len", T)
+    kw.setdefault("max_new_tokens", 12)
+    return ContinuousScheduler(
+        None, None, prefill_fn=fake_prefill, step_fn=fake_step, **kw)
+
+
+def _hold_to_serial_order(sched):
+    """Every result is read before the next step is launched: the
+    order of the loop before it ran ahead."""
+    sched.launch_hook = lambda tok: sched._land()
+
+
+def _drain(stream, take=None):
+    """A stream's tokens and how it ended; with ``take`` the client
+    reads that many and cancels, as the wire's clients do."""
+    tokens = []
+    while True:
+        ev = stream.next_event(30.0)
+        assert ev is not None, "a stream stalled"
+        kind, data = ev
+        if kind == "end":
+            return tokens, data["reason"]
+        tokens += data
+        if take is not None and len(tokens) >= take:
+            stream.cancel()
+            return tokens[:take], "cancelled"
+
+
+def _ahead_budgets(sched):
+    budgets = [1, 2, 3, 5, 8, 12, 4, 1, 7]
+    prompts = [_prompts(1, seed=40 + i) for i in range(len(budgets))]
+    streams = [sched.submit_stream(p, max_new_tokens=b)
+               for p, b in zip(prompts, budgets)]
+    got = [_drain(s) for s in streams]
+    assert got == [(_chain(_first(p), b), "max_tokens")
+                   for p, b in zip(prompts, budgets)]
+    return got
+
+
+def _ahead_eos(sched):
+    prompts = [_prompts(1, seed=60 + i) for i in range(10)]
+    streams = [sched.submit_stream(p) for p in prompts]
+    got = [_drain(s) for s in streams]
+    want = [_chain(_first(p), 12, eos=EOS) for p in prompts]
+    assert [g[0] for g in got] == want
+    # Streams that end at their first token, mid-stream and by budget.
+    ends = {(len(w), w[-1] == EOS) for w in want}
+    assert any(n == 1 and e for n, e in ends)
+    assert any(1 < n < 12 and e for n, e in ends)
+    assert any(not e for n, e in ends)
+    return got
+
+
+def _ahead_cancel(sched):
+    prompts = [_prompts(1, seed=80 + i) for i in range(6)]
+    streams = [sched.submit_stream(p) for p in prompts]
+    takes = [3, None, 5, None, 1, 4]
+    got = [_drain(s, take=k) for s, k in zip(streams, takes)]
+    assert [g[0] for g in got] == [
+        _chain(_first(p), 12)[:k] for p, k in zip(prompts, takes)]
+    return got
+
+
+def _ahead_preempt(sched, permits):
+    victim_p, crit_p = _prompts(1, seed=90), _prompts(1, seed=91)
+    victim = sched.submit_stream(victim_p, slo_class="best_effort")
+    permits.release(4)  # the device stops inside its fifth step
+    head = []
+    while len(head) < 3:  # mid-decode when the critical row arrives
+        head += victim.next_event(30.0)[1]
+    crit = sched.submit_stream(crit_p, slo_class="critical")
+    permits.release(10_000)
+    got_crit = _drain(crit)
+    tail, reason = _drain(victim)
+    assert sched.preempted_total == 1
+    got = [(head + tail, reason), got_crit]
+    assert got == [(_chain(_first(victim_p), 12), "max_tokens"),
+                   (_chain(_first(crit_p), 12), "max_tokens")]
+    return got
+
+
+def _ahead_guard(sched):
+    """Slot 1's lane is indicted in the first step that carries both
+    slots: that stream fails alone before the step's token ships."""
+    from tpu_dist_nn.serving import integrity
+
+    wrapped = sched._step
+
+    def poisoned(params, cache, pos, active, tok, key, *on_device):
+        toks, _ok, cache = wrapped(params, cache, pos, active, tok, key,
+                                   *on_device)
+        ok = np.ones(len(active), bool)
+        if active[0] and active[1]:
+            ok[1] = False
+        return toks, ok, cache
+
+    sched._step = poisoned
+    prev, integrity.GUARD.enabled = integrity.GUARD.enabled, True
+    try:
+        prompts = [_prompts(1, seed=95 + i) for i in range(2)]
+        streams = [sched.submit_stream(p) for p in prompts]
+        got = [_drain(s) for s in streams]
+    finally:
+        integrity.GUARD.enabled = prev
+    assert got == [(_chain(_first(prompts[0]), 12), "max_tokens"),
+                   ([_first(prompts[1])], "error")]
+    return got
+
+
+def _ahead_pool_and_chunks(sched):
+    rng = np.random.default_rng(7)
+    header = rng.integers(0, 64, T - 2)
+    prompts = [np.concatenate([header, rng.integers(0, 64, 2)])[None, :]
+               for _ in range(6)]
+    got = [_drain(sched.submit_stream(prompts[0]))]  # the header goes in
+    streams = [sched.submit_stream(p) for p in prompts[1:]]
+    got += [_drain(s) for s in streams]
+    assert sched.prefix_hits_total >= 4
+    assert sched.prefill_chunks_total > len(prompts)
+    assert got == [(_chain(_first(p), 12), "max_tokens") for p in prompts]
+    return got
+
+
+def _ahead_gpt2(sched):
+    """The real kernels at a toy size, greedy: chunked prefill, the
+    prefix pool, mixed budgets, an EOS id the model does produce."""
+    prompts = _shared_prefix_prompts(7, header_len=6)
+    budgets = [N, 3, 1, N, 6, 2, N]
+    got = [_drain(sched.submit_stream(prompts[:1], max_new_tokens=N))]
+    streams = [sched.submit_stream(prompts[i:i + 1], max_new_tokens=b)
+               for i, b in list(enumerate(budgets))[1:]]
+    got += [_drain(s) for s in streams]
+    assert sched.prefix_hits_total >= 1
+    return got
+
+
+@functools.lru_cache(maxsize=None)
+def _gpt2_eos():
+    """A token the toy model produces mid-stream on these prompts."""
+    ref = np.asarray(generate(
+        PARAMS, CFG, jnp.asarray(_shared_prefix_prompts(7, header_len=6)), N))
+    return int(ref[0, 4])
+
+
+AHEAD_CASES = {
+    "budgets_end_on_different_steps": (_ahead_budgets, {}),
+    "eos_mid_stream": (_ahead_eos, {"eos_id": EOS}),
+    "client_cancel": (_ahead_cancel, {}),
+    "preemption_with_replay": (_ahead_preempt, {"slots": 1}),
+    "guard_trips_one_slot": (_ahead_guard, {"slots": 2}),
+    "prefix_pool_hit_and_chunked_prefill": (
+        _ahead_pool_and_chunks,
+        {"prefix_cache_blocks": 2, "prefill_chunk": 3}),
+    "gpt2_family": (_ahead_gpt2, None),
+}
+
+
+@pytest.mark.parametrize("case", AHEAD_CASES)
+def test_streams_get_the_serial_orders_tokens(case):
+    drive, kw = AHEAD_CASES[case]
+    runs = {}
+    for order in ("serial", "ahead"):
+        if kw is None:
+            sched = _sched(slots=3, prefix_cache_blocks=2, prefill_chunk=3,
+                           eos_id=_gpt2_eos())
+        elif drive is _ahead_preempt:
+            permits = threading.Semaphore(0)
+            sched = _chain_sched(
+                on_step=lambda p=permits: p.acquire(timeout=30.0), **kw)
+        else:
+            sched = _chain_sched(**kw)
+        if order == "serial":
+            _hold_to_serial_order(sched)
+        try:
+            if drive is _ahead_preempt:
+                runs[order] = drive(sched, permits)
+            else:
+                runs[order] = drive(sched)
+        finally:
+            sched.close()
+        if order == "serial":
+            # Held to the serial order nothing is launched ahead, and
+            # an EOS is read before the launch that would carry it on.
+            assert sched.overlapped_total == 0
+            # (A cancel comes from another thread whenever it comes:
+            # it can fall between any loop's reap and its launch.)
+            assert (sched.discarded_lanes_total == 0
+                    or case == "client_cancel")
+        else:
+            assert sched.overlapped_total > 0
+    assert runs["ahead"] == runs["serial"]
+    if kw is None:
+        reasons = [r for _, r in runs["ahead"]]
+        assert "eos" in reasons and "max_tokens" in reasons
+
+
+def test_of_n_step_launches_all_but_the_first_are_made_ahead():
+    sched = _chain_sched(slots=2)
+    try:
+        sched.submit(_prompts(1, seed=30))  # budget 12: 11 steps
+    finally:
+        sched.close()
+    assert sched.steps_total == 11
+    assert sched.overlapped_total == 10
+    assert sched.discarded_lanes_total == 0
+    records = [r for r in _iteration_records(sched)]
+    assert records[-1]["steps_ahead"] == 10
+    ahead = [b["steps_ahead"] - a["steps_ahead"]
+             for a, b in zip(records, records[1:])]
+    # One more an iteration, but for the last, which only reads.
+    assert ahead == [1] * 10 + [0]
+
+
+def _iteration_records(sched):
+    from tpu_dist_nn.obs.trace import ITER_FIELDS, ITERATIONS
+
+    me = sched.loop_totals()["sched"]
+    return [dict(zip(ITER_FIELDS, r)) for r in ITERATIONS.snapshot()
+            if r[ITER_FIELDS.index("sched")] == me]
+
+
+def test_no_launch_has_a_slot_active_past_its_budget():
+    """A slot whose budget the launches so far fill is not in the next
+    one: no step writes a row past the extent, read or unread."""
+    launches = []
+    budgets = [1, 2, 3, 5, 8, 12, 4, 1, 7, 12, 2]
+    sched = _chain_sched(launches=launches, slots=3)
+    try:
+        streams = [
+            sched.submit_stream(_prompts(1, seed=i), max_new_tokens=b)
+            for i, b in enumerate(budgets)
+        ]
+        assert all(_drain(s)[1] == "max_tokens" for s in streams)
+    finally:
+        sched.close()
+    assert sched.slot_steps_total == sum(b - 1 for b in budgets)
+    assert sum(int(a.sum()) for _, a in launches) == sched.slot_steps_total
+    # The last decode of the longest budget writes position T + 12 - 2.
+    assert max(int(p[a].max()) for p, a in launches if a.any()) == T + 10
+    assert sched.discarded_lanes_total == 0
+
+
+def test_one_discarded_lane_an_eos_or_cancel_and_none_a_budget_end():
+    launches = []
+    sched = _chain_sched(launches=launches, slots=2, eos_id=EOS)
+    prompts = [_prompts(1, seed=60 + i) for i in range(10)]
+    try:
+        got = [_drain(sched.submit_stream(p)) for p in prompts]
+    finally:
+        sched.close()
+    # One at a time: an EOS is read while the step launched behind it
+    # still carries the lane, unless it was the budget's last token.
+    eos = sum(r == "eos" and len(t) < 12 for t, r in got)
+    assert eos >= 3 and any(r == "max_tokens" for _, r in got)
+    assert sched.discarded_lanes_total == eos
+    # Computed, not shipped, and no slot-step: every lane launched is
+    # a slot-step or a discarded lane.
+    assert (sum(int(a.sum()) for _, a in launches)
+            == sched.slot_steps_total + sched.discarded_lanes_total)
+    # A client that cancels is found one launch late too: the device
+    # is held inside the step behind the two tokens the client wants.
+    permits = threading.Semaphore(0)
+    sched = _chain_sched(on_step=lambda: permits.acquire(timeout=30.0),
+                         slots=2)
+    try:
+        stream = sched.submit_stream(_prompts(1, seed=81))
+        permits.release(2)
+        tokens, _ = _drain(stream, take=2)
+        assert len(tokens) == 2
+        permits.release(10_000)
+        deadline = time.monotonic() + 10
+        while sched.slots_active and time.monotonic() < deadline:
+            time.sleep(0.002)
+        assert sched.slots_active == 0
+    finally:
+        permits.release(10_000)
+        sched.close()
+    assert sched.discarded_lanes_total == 1
+    assert sched.steps_total == 3 and sched.slot_steps_total == 2
+
+
+def test_fetch_fault_with_a_launch_outstanding_is_a_device_fault():
+    """The step launched behind the one whose fetch failed took the
+    same donated buffer: it is dropped unread, every resident fails
+    over, the pool goes cold, and the scheduler keeps serving."""
+    from tpu_dist_nn.testing import faults
+    from tpu_dist_nn.utils.errors import InternalError
+
+    sched = _chain_sched(slots=2, prefix_cache_blocks=2, prefill_chunk=3)
+    p = _prompts(2, seed=33)
+    try:
+        assert sched.submit(p[:1]).shape == (1, T + 12)
+        assert sched.prefix_blocks_used == 2
+        steps0 = sched.steps_total
+        unread = []
+
+        def fetch(toks):
+            unread.append(len(sched._unread))
+            plan.fire(toks)
+
+        plan = faults.FaultPlan(at={3: faults.internal()})
+        sched.fetch_hook = fetch
+        with pytest.raises(InternalError):
+            sched.submit(p)
+        # A step was out behind the one that failed, and went unread.
+        assert unread[2] >= 1
+        assert sched.steps_total == steps0 + 2
+        assert sched.inflight_rows == 0 and not sched._unread
+        assert sched.prefix_blocks_used == 0  # the device fault's mark
+        out = sched.submit(p[1:])
+        np.testing.assert_array_equal(
+            out[0, T:], _chain(_first(p[1:]), 12))
+    finally:
+        sched.close()
+
+
+def test_the_loop_waits_for_the_step_it_launched_and_reads_it_a_launch_late():
+    """The order of the loop on a device that runs behind the host:
+    step n+1 is launched when step n has FINISHED (the one place the
+    loop waits) and before step n is READ, and a prompt's first token
+    is read behind the step launched after its chunk."""
+    log = []
+
+    class OnDevice:
+        """A result still on the device: waiting for it and reading it
+        are two things, as for a jax.Array."""
+
+        def __init__(self, name, value):
+            self.name, self.value = name, value
+
+        def block_until_ready(self):
+            log.append(("finished", self.name))
+            return self
+
+        def __array__(self, dtype=None, copy=None):
+            log.append(("read", self.name))
+            return np.asarray(self.value, dtype)
+
+        def __int__(self):
+            log.append(("read", self.name))
+            return int(self.value)
+
+    steps = iter(range(1000))
+
+    def fake_prefill(params, cache, slot, tokens, start, key):
+        log.append(("launch", "chunk"))
+        return OnDevice("chunk", np.int32(1)), cache
+
+    def fake_step(params, cache, pos, active, tok, key):
+        name = next(steps)
+        log.append(("launch", name))
+        return OnDevice(name, np.asarray(tok) + 1), cache
+
+    sched = ContinuousScheduler(
+        None, None, prefill_fn=fake_prefill, step_fn=fake_step, slots=2,
+        prompt_len=T, max_new_tokens=5)
+    try:
+        out = sched.submit(_prompts(1, seed=31))
+    finally:
+        sched.close()
+    assert list(out[0, T:]) == [1, 2, 3, 4, 5]
+    # (An injected step is handed its tokens whole, so the stub's
+    # wrapper takes the value for the merge the real program does on
+    # the device: the reads the loop itself makes are the LAST of each.)
+    log = [e for i, e in enumerate(log)
+           if e[0] != "read" or e not in log[i + 1:]]
+    order = [e for e in log if e[0] != "read" or e[1] != "chunk"]
+    launches = [i for i, e in enumerate(order) if e[0] == "launch"
+                and e[1] != "chunk"]
+    for n, at in enumerate(launches[1:], start=1):
+        before = order[:at]
+        assert ("finished", n - 1) in before  # waited for, then launched
+        # ... from tokens the host has not taken for itself: the read
+        # that accounts and publishes step n-1 comes behind the launch.
+        assert order.index(("finished", n - 1)) < at
+        later = order[at:]
+        assert ("read", n - 1) in later
+    assert sched.overlapped_total == len(launches) - 1 == 3
+
+
+@pytest.mark.parametrize("chunk_s, first_goes_first", [(0.0, True),
+                                                       (0.002, True)])
+def test_a_first_token_goes_ahead_of_the_round_when_its_chunk_is_through(
+        chunk_s, first_goes_first):
+    """A round's handlers are woken together, behind the first token of
+    a prompt whose chunk is through by then or soon (as long again as
+    it has had since its launch). (That a LONG chunk lets the round go
+    first cannot be shown through the injected seams, whose step reads
+    the chunk's token when it is launched; on the chip it is the
+    long-document cell's `itl_p95_ms`: PERF.md section 6, PR 30.)"""
+    woken = []
+
+    class Chunk:
+        """The chunk's token, on a device that needs `chunk_s` for it."""
+
+        def __init__(self):
+            self.done_at = time.monotonic() + chunk_s
+
+        def is_ready(self):
+            return time.monotonic() >= self.done_at
+
+        def __int__(self):
+            time.sleep(max(0.0, self.done_at - time.monotonic()))
+            return 1
+
+    def fake_prefill(params, cache, slot, tokens, start, key):
+        woken.clear()  # what follows is this chunk's iteration
+        return Chunk(), cache
+
+    def fake_step(params, cache, pos, active, tok, key):
+        time.sleep(0.005)
+        return np.asarray(tok) + 1, cache
+
+    sched = ContinuousScheduler(
+        None, None, prefill_fn=fake_prefill, step_fn=fake_step, slots=2,
+        prompt_len=T, max_new_tokens=400)
+    try:
+        old = sched.submit_stream(_prompts(1, seed=1))
+        assert old.next_event(10.0)[0] == "tokens"  # decoding now
+        wake = type(old).wake
+        old.wake = lambda: (woken.append("round"), wake(old))[1]
+        new = sched.submit_stream(_prompts(1, seed=2))
+        publish = type(new).publish
+
+        def spy(tokens, **kw):
+            if "first" not in woken:
+                woken.append("first")
+            return publish(new, tokens, **kw)
+
+        new.publish = spy
+        assert new.next_event(10.0)[0] == "tokens"
+        new.cancel()
+        old.cancel()
+    finally:
+        sched.close()
+    at = woken.index("first")
+    # The round published beside the chunk's launch was woken before
+    # the first token only where the chunk was long.
+    assert ("round" in woken[:at]) is not first_goes_first

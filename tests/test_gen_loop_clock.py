@@ -313,8 +313,9 @@ def test_decode_span_says_what_the_request_rode_and_no_step_spans():
     assert attrs["step_fetch_s"] >= 0.001 * (N - 1)
     assert 0 < attrs["host_s"] < decode.dur
     # It joins the iteration records by sequence number: the iteration
-    # that prefilled it stepped it too.
-    assert attrs["iter_last"] - attrs["iter_first"] + 1 == attrs["steps"]
+    # that prefilled it launched its first step too, and every step is
+    # read in the iteration after the one that launched it.
+    assert attrs["iter_last"] - attrs["iter_first"] == attrs["steps"]
     seqs = {r["seq"] for r in _mine(sched)}
     assert set(range(attrs["iter_first"], attrs["iter_last"] + 1)) <= seqs
 
@@ -420,7 +421,8 @@ def lowered_programs():
         key = sched._next_key()
         step = sched._step.lower(
             sched._params, sched._cache, sched._pos, sched._active,
-            sched._tok, key).as_text(debug_info=True)
+            sched._tok, key, sched._prev, sched._first,
+        ).as_text(debug_info=True)
         prefill = sched._prefill.lower(
             sched._params, sched._cache, np.int32(0),
             np.zeros((1, T), np.int32), np.int32(0), key,
@@ -439,5 +441,7 @@ def test_step_programs_name_their_scopes(lowered_programs, program, scope):
                      lowered_programs[program])
 
 
-def test_guard_scope_is_in_the_step_program(lowered_programs):
-    assert re.search(r'[/"]guard[/"]', lowered_programs["step"])
+@pytest.mark.parametrize("scope", ["guard", "next_token"])
+def test_guard_and_token_merge_scopes_are_in_the_step_program(
+        lowered_programs, scope):
+    assert re.search(rf'[/"]{scope}[/"]', lowered_programs["step"])

@@ -541,8 +541,9 @@ def test_decode_step_guard_fails_bad_slot_alone():
         out = sched.submit(np.ones((1, T), np.int32), max_new_tokens=2)
         assert out.shape == (1, T + N)
 
-        def poisoned(params, cache, pos, active, tok, key):
-            toks, _ok, cache = wrapped(params, cache, pos, active, tok, key)
+        def poisoned(params, cache, pos, active, tok, key, *on_device):
+            toks, _ok, cache = wrapped(params, cache, pos, active, tok, key,
+                                       *on_device)
             ok = np.ones(2, bool)
             if active[0] and active[1]:  # both resident: indict slot 1
                 ok[1] = False

@@ -415,6 +415,65 @@ def test_preempted_row_resumes_its_stream(weights, rows):
         sched.close()
 
 
+def _serial_and_ahead(params, cfg, prompts, budgets, alone=0, **kw):
+    """The same submissions with the loop held to the serial order (a
+    launch hook that reads everything unread before the next step is
+    launched) and as it runs, one launch ahead: (tokens, scheduler)
+    of each. The first ``alone`` streams are drained before the rest
+    are submitted."""
+    runs = []
+    for hold in (True, False):
+        sched = ContinuousScheduler(params, cfg, slots=2, prompt_len=100,
+                                    max_new_tokens=24, **kw)
+        if hold:
+            sched.launch_hook = lambda tok, s=sched: s._land()
+        try:
+            work = list(zip(prompts, budgets))
+            got = [_drain(sched.submit_stream(p[None], max_new_tokens=b))
+                   for p, b in work[:alone]]
+            streams = [sched.submit_stream(p[None], max_new_tokens=b)
+                       for p, b in work[alone:]]
+            runs.append((got + [_drain(s) for s in streams], sched))
+        finally:
+            sched.close()
+    return runs
+
+
+@pytest.mark.parametrize("case", ["budgets_and_chunks", "eos_mid_stream",
+                                  "prefix_pool_hit"])
+def test_streams_get_the_serial_orders_tokens(weights, rows, served, case):
+    """This family through the loop that launches step N+1 before it
+    reads step N (tests/test_continuous.py has GPT-2's and the stub
+    kernels' cases): lightning state, rings and compressed keys of a
+    lane computed for nobody are never a later occupant's."""
+    prompts, budgets, out, _ = served
+    params, cfg, kw = CFG16.cast_params(weights), CFG16, {"prefill_chunk": 32}
+    if case == "eos_mid_stream":
+        # A token every stream of `served` holds past its first: each
+        # ends there, the slot's next occupant starts behind a lane
+        # that was computed and thrown away.
+        kw["eos_id"] = int(out[0][5])
+        budgets = [24, 24, 24]
+    elif case == "prefix_pool_hit":
+        params, cfg = weights, CFG32
+        prompts = np.stack([rows[0, :100], np.concatenate(
+            [rows[0, :64], rows[1, 64:100]]), rows[2, :100]])
+        budgets = [6, 6, 3]
+        kw.update(prefix_cache_blocks=4, alone=1)
+    (serial, held), (ahead, sched) = _serial_and_ahead(
+        params, cfg, prompts, budgets, **kw)
+    assert ahead == serial
+    assert held.overlapped_total == 0 and held.discarded_lanes_total == 0
+    assert sched.overlapped_total > 0
+    if case == "budgets_and_chunks":
+        assert ahead == out and sched.discarded_lanes_total == 0
+    elif case == "eos_mid_stream":
+        assert ahead[0] == out[0][:out[0].index(kw["eos_id"]) + 1]
+        assert sched.discarded_lanes_total >= 1
+    else:
+        assert sched.prefix_hits_total == held.prefix_hits_total >= 1
+
+
 # --------------------------------------------------- config, loader, counts
 
 def test_loader_reads_the_benchmarks_configuration():

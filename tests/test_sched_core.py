@@ -643,6 +643,13 @@ def test_overload_drill_critical_never_shed_best_effort_absorbs():
     assert sched.preempted_total == 2
     assert _counter("tdn_gen_preemptions_total",
                     slo_class="best_effort") >= 2
+    # The loop runs one launch ahead, and a preemption reads the step
+    # still out before it keeps the victim's tokens for replay: every
+    # row read 1..N above, and no lane was computed for nobody (budgets
+    # end before the launch that would pass them, an eviction is no
+    # EOS).
+    assert sched.overlapped_total > 0
+    assert sched.discarded_lanes_total == 0
 
 
 # ----------------------------------------------------- router class hop

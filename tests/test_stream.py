@@ -122,6 +122,28 @@ def test_token_stream_overflow_and_cancel_flip_the_channel():
     assert kind == "end" and data["code"] == "CANCELLED"
 
 
+def test_publish_without_notify_leaves_the_consumer_asleep_until_woken():
+    """The scheduler publishes a round stream by stream and wakes the
+    handlers together at its end (`ContinuousScheduler._land`): a
+    publish with `notify=False` enqueues and wakes nobody, `wake` does;
+    a consumer that was not waiting finds the tokens without a wake."""
+    import threading
+
+    s = TokenStream()
+    got = []
+    consumer = threading.Thread(target=lambda: got.append(s.next_event(10.0)))
+    consumer.start()
+    time.sleep(0.05)  # blocked in next_event
+    assert s.publish([1], notify=False) and s.publish([1, 2], notify=False)
+    time.sleep(0.05)
+    assert not got
+    s.wake()
+    consumer.join(5)
+    assert got == [("tokens", [1, 2])]
+    assert s.publish([1, 2, 3], notify=False)
+    assert s.next_event(1.0) == ("tokens", [3])
+
+
 # ------------------------------------------------------ wire parity
 
 

@@ -138,7 +138,10 @@ def _slot_step(S, jnp):
                 for op in found
                 if op["opcode"] != "custom-call tpu_custom_call"]
 
-    args = (params, cache, ints, S((slots,), jnp.bool_), ints, key)
+    # As the loop launches it: the tokens of the step and of the chunk
+    # launched before, still on the device, come in beside `tok`.
+    args = (params, cache, ints, S((slots,), jnp.bool_), ints, key, ints,
+            S((), jnp.int32))
     return step, args, check
 
 
@@ -192,7 +195,8 @@ def _sala_step(S, jnp):
                 for op in found
                 if op["opcode"] != "custom-call tpu_custom_call"]
 
-    args = (params, cache, ints, S((slots,), jnp.bool_), ints, key)
+    args = (params, cache, ints, S((slots,), jnp.bool_), ints, key, ints,
+            S((), jnp.int32))
     return step, args, check
 
 

@@ -177,8 +177,11 @@ def main(argv=None) -> int:
         key = shaped(jax.eval_shape(lambda: jax.random.key(0)))
         prefill, copy, step = slot_kernels(tcfg, 0.0, None, None)
         ints, scalar = spec((S,), jnp.int32), spec((), jnp.int32)
+        # The step as the loop launches it: `prev` and `first`, the
+        # tokens still on the device, beside `tok`.
         programs = [("step", step, (params, cache, ints,
-                                    spec((S,), jnp.bool_), ints, key))]
+                                    spec((S,), jnp.bool_), ints, key, ints,
+                                    scalar))]
         if args.prefill:
             programs += [
                 ("prefill_chunk", prefill, (

@@ -23,7 +23,8 @@ is the accounting half:
   the remainder of the same integer model, never re-derived), so
   conservation is testable to the FLOP. Pad carries a reason
   (``pad_rows``, ``idle_slot``, ``mid_prefill_slot``, ``attn_tail``,
-  ``chunk_tail``, ``eos_frozen``) and a path (``batcher`` for the
+  ``chunk_tail``, ``eos_frozen``, ``preempt_replay``,
+  ``discarded_lane``) and a path (``batcher`` for the
   Process coalescer, ``gen`` for the generation schedulers, ``engine``
   for direct host-fed calls).
 * **One peak calibration** — :data:`PEAK_FLOPS` (the per-device-kind
@@ -380,6 +381,7 @@ class GoodputTracker:
     def record_decode_step(self, model: LMFlopModel, active_pos,
                            idle_slots: int, mid_prefill_slots: int, *,
                            replay_slots: int = 0,
+                           discarded_slots: int = 0,
                            path: str = "gen") -> None:
         """One ``decode_step_slots`` launch: ``active_pos`` is the
         launch-time position of every ACTIVE slot; inactive lanes split
@@ -387,7 +389,10 @@ class GoodputTracker:
         (``mid_prefill_slot``); active lanes' dead key extent is
         ``attn_tail``. ``replay_slots`` are active lanes re-running
         tokens a preemption threw away (``preempt_replay`` — work
-        re-done, never useful twice)."""
+        re-done, never useful twice). ``discarded_slots`` are lanes the
+        scheduler launched for an occupant that had gone by the time
+        the launch was read (``discarded_lane``: an EOS or a cancel is
+        found one launch late)."""
         if not self.enabled:
             return
         sf = model.step_flops()
@@ -399,6 +404,8 @@ class GoodputTracker:
             pads["mid_prefill_slot"] = int(mid_prefill_slots) * sf
         if replay_slots > 0:
             pads["preempt_replay"] = int(replay_slots) * sf
+        if discarded_slots > 0:
+            pads["discarded_lane"] = int(discarded_slots) * sf
         tail = len(list(active_pos)) * sf - useful
         if tail > 0:
             pads["attn_tail"] = tail

@@ -196,6 +196,20 @@ class RuntimeSampler:
                 "Pallas attention kernel (kernels/sparse_attend.py); the "
                 "rest ran its XLA loop, or the model has none",
             ),
+            # The loop runs one launch ahead (serving/continuous.py).
+            "overlapped_total": reg.counter(
+                "tdn_gen_steps_ahead_total",
+                "decode step launches made while the step launched "
+                "before them was still unread: the device had its next "
+                "launch queued while the host published the last one",
+            ),
+            "discarded_lanes_total": reg.counter(
+                "tdn_gen_discarded_lanes_total",
+                "lanes a decode step computed for a slot whose occupant "
+                "had gone by the time the step was read (EOS, a cancel "
+                "or the numeric guard, found one launch late): pad, "
+                "never published",
+            ),
         }
         self._g_gen_cache = reg.gauge(
             "tdn_gen_cache_bytes",

@@ -592,6 +592,10 @@ ITER_FIELDS = (
     # Positions prefilled, and the first position of every chunk summed:
     # an iteration's growth of the two is its chunk's size and start.
     "prefill_tokens", "prefill_starts",
+    # Step launches made while the step launched before them was still
+    # unread: the loop runs one launch ahead, so over a busy window
+    # this grows by one an iteration.
+    "steps_ahead",
 )
 
 
@@ -604,7 +608,7 @@ class IterationRing:
     it is still there to be read after the scheduler is closed.
     """
 
-    def __init__(self, capacity: int = 4096):
+    def __init__(self, capacity: int = 8192):
         self._buf: collections.deque[tuple] = collections.deque(
             maxlen=int(capacity)
         )

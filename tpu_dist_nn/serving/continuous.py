@@ -55,6 +55,38 @@ at DECODE-STEP granularity instead:
   the same scheduler iteration while the remaining slots keep
   decoding. Finished rows stream back to their waiters immediately.
 
+* **One launch ahead**: the loop launches step N+1 before it reads
+  step N. Nearly all of what the launch needs is known without the
+  read: a decoding slot's next input token is step N's output where it
+  lies, on the device (the step program merges it with what only the
+  host knows: a replay's forced token); ``pos`` advances by one; a slot
+  that ends BY BUDGET at step N is known beforehand and is simply not
+  in N+1, so no launch writes a row past a slot's extent. A slot that
+  ends by EOS, by the numeric guard or by a client's cancel is found
+  one launch late: its lane of step N+1 is computed and thrown away
+  (the row it wrote lies past the frontier of whoever takes the slot
+  next, and the device runs launches in order, so a prefill into the
+  freed slot lands behind it). An iteration is: reap / admit / bind →
+  launch the prefill chunk → launch step N+1 → read step N, account,
+  publish, retire → read the chunk's token where it ended a prompt →
+  wait until step N+1 has finished, the one place the loop waits for
+  the device (:meth:`ContinuousScheduler._await_device`), and go
+  around. Account, publish, retire and the handler threads' sends run
+  while the device works; reap, admit, bind and the two dispatches run
+  with nothing queued, so a request that arrived during the wait is
+  bound and its chunk launched at once. (Launched as early as its
+  inputs are known, with the wait behind the launch, the loop outran
+  the stream plane it shares an interpreter with: PERF.md section 6,
+  PR 30.) A round's publishes only enqueue and its handlers are woken
+  together at its end, a request's first token ahead of them. At most
+  one step is unread between iterations, and whatever needs the host's
+  view of the streams to be final reads it first
+  (:meth:`ContinuousScheduler._land`): a preemption, a launch hook's
+  fault, close. Every stream gets the
+  tokens the serial order gave it (sampling keys are drawn in launch
+  order: chunk, step, chunk, step; a launch whose lanes are all thrown
+  away draws one too).
+
 What a layer is stays with the model. The three programs are built from
 the four functions a model's config hands over with ``cfg.slot_model()``
 (:mod:`tpu_dist_nn.models.slot_model`; the names above are GPT-2's, in
@@ -72,9 +104,14 @@ letting resident rows — INCLUDING half-prefilled slots — finish before
 failing still-pending waiters over as UNAVAILABLE (the ``_Batcher``
 drain contract, so ``GracefulDrain`` works unchanged), and first-class
 fault hook points — ``launch_hook`` fires before every step-kernel
-dispatch, ``fetch_hook`` before its token fetch, and ``prefill_hook``
-before every prefill-chunk dispatch (a mid-prefill fault fails that
-request over, frees its slot, and releases its prefix-block ref).
+dispatch (a fault there leaves the cache intact: what the device has
+already computed is read and shipped, then every resident fails over),
+``fetch_hook`` before a step's token fetch (a fault there is a device
+fault: the step launched behind it took the same donated buffer and is
+dropped unread, the cache is rebuilt, the prefix pool goes cold), and
+``prefill_hook`` before every prefill-chunk dispatch (a mid-prefill
+fault fails that request over, frees its slot, and releases its
+prefix-block ref).
 Assign a ``testing/faults.py`` plan's ``fire`` directly (the
 ``inject_engine_faults`` helper covers only engine hooks).
 
@@ -172,6 +209,10 @@ _SCHED_IDS = itertools.count(1)
 _HOST = tuple(i for i, p in enumerate(_trace.LOOP_PHASES)
               if p in _trace.LOOP_HOST_PHASES)
 _SPAN_NAMES = tuple("tdn.gen." + p for p in _trace.LOOP_PHASES)
+# What the host writes into a step's token input where the token is
+# still on the device (an id is never negative): the lane's own output
+# of the step launched before, or the token of the chunk launched before.
+_FROM_STEP, _FROM_CHUNK = -1, -2
 
 
 def slot_kernels(cfg, temperature, top_k, top_p):
@@ -215,12 +256,22 @@ def slot_kernels(cfg, temperature, top_k, top_p):
         return sample(logits, key)[0], cache
 
     @functools.partial(jax.jit, donate_argnums=(1,))
-    def step(params, cache, pos, active, tok, key):
+    def step(params, cache, pos, active, tok, key, prev=None, first=None):
         # Decode advances the REQUEST region only: `pos`, `active` and
         # `tok` have S entries, and the step reads and writes slots
         # [0, S) of the one buffer where they lie. The pool blocks past
         # slot S hold cached prefixes, not decoding sequences, and are
         # not touched.
+        if prev is not None:
+            # The loop launches this step before it has read the one
+            # before it: a lane's input token is then still on the
+            # device, as `prev (S,)`, the step's output before, or as
+            # `first ()`, the token of the chunk that ended a prompt.
+            # The host marks those lanes in `tok` and writes what only
+            # it knows (a replay's forced token) as the id itself.
+            with jax.named_scope("next_token"):
+                tok = jnp.where(tok == _FROM_STEP, prev, tok)
+                tok = jnp.where(tok == _FROM_CHUNK, first, tok)
         logits, cache = model.decode_step_slots(
             params, cache, pos, tok, cfg, active=active
         )
@@ -273,6 +324,8 @@ class _LoopClock:
         # Positions prefilled, and the first positions of their chunks
         # summed: neighbours' difference is an iteration's chunk.
         self.prefill_tokens = self.prefill_starts = 0
+        # Step launches made while the step before them was unread.
+        self.steps_ahead = 0
         # Of the iteration in progress.
         self.prefilled = False
         self.active_slots = 0
@@ -315,12 +368,13 @@ class _LoopClock:
             self.queue_wait_ns, self.binds,
             self.prefill_wait_ns, self.first_tokens,
             self.stream_lag_ns, self.stream_frames,
-            self.prefill_tokens, self.prefill_starts,
+            self.prefill_tokens, self.prefill_starts, self.steps_ahead,
         )
 
     def end_iteration(self) -> None:
         """Close the iteration at the top of the next (``reap`` is its
-        first phase) and put it on the record if it fed the device."""
+        first phase) and put it on the record if it fed the device or
+        read a step back from it."""
         self.mark(_REAP)
         if self.launched:
             self.seq += 1
@@ -545,11 +599,18 @@ class ContinuousScheduler:
                 raise ValueError(
                     "prefill_fn and step_fn must be injected together"
                 )
-            # The public step_fn seam keeps its (toks, cache) contract;
-            # normalize to the internal 3-tuple with ok=None — injected
+            # The public step_fn seam keeps its contract: six
+            # arguments, `tok` whole (what the real program merges on
+            # the device is merged here, where an injected kernel's
+            # result is already the host's), and (toks, cache) back;
+            # normalized to the internal 3-tuple with ok=None — injected
             # kernels carry no logits for the in-launch numeric guard.
-            def _step_no_guard(*a, _fn=step_fn):
-                toks, cache = _fn(*a)
+            def _step_no_guard(params, cache, pos, active, tok, key, prev,
+                               first, _fn=step_fn):
+                tok = np.where(tok == _FROM_STEP, np.asarray(prev), tok)
+                tok = np.where(tok == _FROM_CHUNK, np.asarray(first), tok)
+                toks, cache = _fn(params, cache, pos, active,
+                                  tok.astype(np.int32), key)
                 return toks, None, cache
 
             self._prefill, self._step = prefill_fn, _step_no_guard
@@ -594,12 +655,35 @@ class ContinuousScheduler:
         self.sparse_positions_total = 0
         self.dense_positions_total = 0
         # Host-side slot state: the loop thread is the only writer.
-        # _active marks DECODING slots; a bound slot whose prefill is
-        # still chunking has an occupant but is not yet active.
+        # _pos, _active and _tok are what the NEXT step launch is
+        # given, advanced when a step is launched and not when it is
+        # read: _active marks the slots that launch decodes (a bound
+        # slot whose prefill is still chunking has an occupant but is
+        # not active, nor is one whose budget the launches so far
+        # fill), _tok holds _FROM_STEP / _FROM_CHUNK where the token is
+        # still on the device. A launch is handed copies: the arrays
+        # change while it may still be reading them.
         self._pos = np.zeros(self._S, np.int32)
         self._active = np.zeros(self._S, bool)
         self._tok = np.zeros(self._S, np.int32)
         self._occupant: list[dict | None] = [None] * self._S
+        # Launches whose result the host has not read, in the order the
+        # device runs them, one record each (_land_step's has "lanes",
+        # _land_first's none): at most the step launched an iteration
+        # ago, the token of this iteration's chunk where it ended a
+        # prompt, and the step just launched; between iterations one
+        # step at most.
+        self._unread: collections.deque[dict] = collections.deque()
+        # The last step's tokens and the token of the last chunk that
+        # ended a prompt, where they were left (the device): the next
+        # step's `prev` and `first`.
+        self._prev = np.zeros(self._S, np.int32)
+        self._first = np.int32(0)
+        # Streams published to and not yet woken (_land wakes them).
+        self._woken: list[TokenStream] = []
+        # The iteration's last launch, as (phase its wait is booked to,
+        # result): what _await_device waits for.
+        self._launched: tuple | None = None
         self._prefill_rr = 0  # round-robin fairness over chunking slots
         # Fault-injection hook points (testing/faults.py): called at
         # the top of every step-kernel dispatch / token fetch /
@@ -630,9 +714,12 @@ class ContinuousScheduler:
         self.batches_total = 0     # step-kernel launches (steps_total
         #                            is a read alias — one source of truth)
         self.preempted_total = 0   # rows evicted for a critical bind
-        self.overlapped_total = 0  # N/A here; kept for sampler parity
         # Generation-specific stats.
         self.slot_steps_total = 0  # active slots summed over steps
+        # Lanes a step computed for a slot whose occupant had gone by
+        # the time it was read (EOS, a cancel, the guard: found one
+        # launch late); pad, not slot-steps, and never published.
+        self.discarded_lanes_total = 0
         self.retired_total = 0     # rows retired (eos + max_tokens)
         self.prefill_chunks_total = 0  # chunk-kernel launches
         # ... of which the model says its program holds its attention
@@ -749,6 +836,7 @@ class ContinuousScheduler:
             self._params, cache,
             np.zeros(self._S, np.int32), np.zeros(self._S, bool),
             np.zeros(self._S, np.int32), key,
+            np.zeros(self._S, np.int32), np.int32(0),
         )
         np.asarray(toks)  # force the compile + execution to finish
         self._cache = cache
@@ -815,10 +903,17 @@ class ContinuousScheduler:
 
     @property
     def steps_total(self) -> int:
-        """Step-kernel launches, under the name the occupancy ratio
-        reads naturally (alias of ``batches_total`` — a device launch
-        IS a decode step here)."""
+        """Step-kernel launches read back, under the name the occupancy
+        ratio reads naturally (alias of ``batches_total`` — a device
+        launch IS a decode step here)."""
         return self.batches_total
+
+    @property
+    def overlapped_total(self) -> int:
+        """Step launches made while the step before them was unread
+        (``tdn_gen_steps_ahead_total``; over ``batches_total`` the
+        sampler's ``tdn_batcher_overlap_ratio``)."""
+        return self._clock.steps_ahead
 
     def loop_totals(self) -> dict:
         """The loop's cumulative accounting as of its last phase
@@ -995,19 +1090,27 @@ class ContinuousScheduler:
 
     # ------------------------------------------------------------ loop
 
-    def _publish(self, occ: dict) -> None:
+    def _publish(self, occ: dict, first: bool = False) -> None:
         """Flush the occupant's known-token list into its stream, if it
         has one (called after every ``occ["tokens"]`` append). A dead
         stream (client gone / buffer overflow) marks the item abandoned
         — the loop's reap pass frees the slot next iteration. A live
-        publish slides the stream's next-token-gap deadline."""
+        publish slides the stream's next-token-gap deadline.
+
+        A step's tokens are enqueued now and their handlers woken
+        together with the rest of the round (:meth:`_land`): a handler
+        woken in the middle of the loop's publishes can only take the
+        interpreter away from them. A request's ``first`` token wakes
+        its handler at once, ahead of that crowd."""
         item = occ["item"]
         stream = item.get("stream")
         if stream is None:
             return
-        if not stream.publish(occ["tokens"]):
+        if not stream.publish(occ["tokens"], notify=first):
             item["abandoned"] = True
             return
+        if not first:
+            self._woken.append(stream)
         slide_stream_deadline(item, item.get("gap_budget"))
 
     def _reap_cancelled(self) -> None:
@@ -1080,14 +1183,19 @@ class ContinuousScheduler:
 
     def _device_fault(self, e: Exception) -> None:
         """A REAL kernel call raised (not an injected hook fault, which
-        fires before the dispatch): the cache buffer was DONATED to
-        that call and may already be consumed, so per-slot recovery is
-        impossible — fail every resident over, rebuild a fresh zeroed
-        cache (every slot is free after the fan-out, so zeroes are the
-        correct contents), and drop the prefix pool, whose blocks lived
-        in the dead cache. The scheduler then keeps serving later
-        arrivals — the same contract as before, paid for with a cold
-        prefix pool."""
+        fires before the dispatch), or a fetch did: the cache buffer was
+        DONATED to that call and may already be consumed, so per-slot
+        recovery is impossible — fail every resident over, rebuild a
+        fresh zeroed cache (every slot is free after the fan-out, so
+        zeroes are the correct contents), and drop the prefix pool,
+        whose blocks lived in the dead cache. Whatever was launched
+        after the call that failed took the same buffer: what is still
+        unread is dropped unread. The scheduler then keeps serving
+        later arrivals — the same contract as before, paid for with a
+        cold prefix pool."""
+        self._unread.clear()
+        self._launched = None
+        self._prev, self._first = np.zeros(self._S, np.int32), np.int32(0)
         self._fail_occupants(e)
         if self._make_cache is not None:
             try:
@@ -1270,12 +1378,16 @@ class ContinuousScheduler:
                 self._device_fault(e)
                 return
 
-    def _prefill_chunk_once(self, slot: int) -> None:
-        """Run ONE chunk of ``slot``'s pending prefill — the at-most-
+    def _launch_chunk(self, slot: int) -> None:
+        """Launch ONE chunk of ``slot``'s pending prefill — the at-most-
         one-chunk-per-iteration budget that keeps a long prompt from
-        freezing the resident decode streams. The final chunk yields
-        the prompt's last-position sample: the request's first token
-        (TTFT), after which the slot joins the decode frontier."""
+        freezing the resident decode streams — and wait for nothing:
+        what the host books of a chunk it knows without the chunk's
+        result. The final chunk yields the prompt's last-position
+        sample, the request's first token (TTFT): the slot joins the
+        step launched next, which takes that token from the device
+        (``_FROM_CHUNK``), and the host reads it behind that launch
+        (:meth:`_land_first`)."""
         clock = self._clock
         clock.mark(_PREFILL_DISPATCH)
         clock.prefilled = clock.launched = True
@@ -1306,17 +1418,7 @@ class ContinuousScheduler:
             self._device_fault(e)
             return
         self._cache = cache
-        clock.mark(_PREFILL_FETCH)
-        try:
-            tok = int(tok)  # the token fetch (host sync)
-        except Exception as e:  # noqa: BLE001 — donated cache: global
-            # On async backends a failed LAUNCH surfaces here, at the
-            # first host sync of its results — the rebound cache is the
-            # poisoned donated output, so this is a device fault, not a
-            # per-item one (on the sync CPU backend a post-return fetch
-            # failure is unreachable, so nothing is lost by escalating).
-            self._device_fault(e)
-            return
+        self._launched = (_PREFILL_FETCH, tok)
         clock.mark(_PREFILL_POST)
         occ["fill"] = start + size
         self.prefill_chunks_total += 1
@@ -1334,26 +1436,34 @@ class ContinuousScheduler:
             )
         now = time.monotonic()
         if item["ctx"] is not None:
+            # The launch alone: the loop waits for a chunk to end only
+            # where it was a prompt's last (the `prefill` span) or
+            # nothing decodes beside it.
             _trace.TRACER.record_span(
                 "prefill.chunk", item["ctx"], t0, now - t0,
                 attrs={"slot": slot, "start": start, "tokens": size},
             )
         if self._pool is not None:
+            # The copy out of the slot runs behind the chunk: the
+            # device takes launches in order.
             self._maybe_insert_tiers(slot, occ, start)
             if self._occupant[slot] is not occ:
                 return  # an insert-copy fault failed the slot over
         if occ["fill"] < self._T:
             return
+        self._pos[slot] = self._T
+        # Tokens of this stream known or under way: the first is.
+        occ["issued"] = 1
         if occ["resume"] is not None:
             # Preemption resume: the first generated token is KNOWN —
-            # the prefill's last-position sample is discarded, the
-            # remaining prefix replays through the shared step kernel
-            # with forced tokens (bit-identical K/V to the original
-            # run; TTFT was observed on the first pass and is not
-            # re-counted).
+            # the prefill's last-position sample is discarded and never
+            # fetched, the remaining prefix replays through the shared
+            # step kernel with forced tokens (bit-identical K/V to the
+            # original run; TTFT was observed on the first pass and is
+            # not re-counted).
             known = occ["resume"]
             occ["resume"] = None
-            occ["replay"] = known[1:]
+            occ["replay"] = collections.deque(known[1:])
             first = int(known[0])
             self._note_first_token(occ, now)
             if item["ctx"] is not None:
@@ -1369,9 +1479,38 @@ class ContinuousScheduler:
             occ["tokens"].append(first)
             self._publish(occ)
             self._active[slot] = True
-            self._pos[slot] = self._T
             self._tok[slot] = first
             return
+        if occ["budget"] > 1:
+            self._active[slot] = True
+            self._tok[slot] = _FROM_CHUNK
+            self._first = tok
+        self._unread.append(
+            {"slot": slot, "occ": occ, "tok": tok, "t_launch": t0})
+
+    def _land_first(self, rec: dict) -> None:
+        """Read the token of a prompt's last chunk — the request's
+        first — and publish it. The step launched behind the chunk
+        already carries the slot; an EOS here is found one launch late
+        like any other."""
+        clock = self._clock
+        clock.mark(_PREFILL_FETCH)
+        slot, occ = rec["slot"], rec["occ"]
+        try:
+            tok = int(rec["tok"])  # the token fetch (host sync)
+        except Exception as e:  # noqa: BLE001 — donated cache: global
+            # On async backends a failed LAUNCH surfaces here, at the
+            # first host sync of its results — the rebound cache is the
+            # poisoned donated output, so this is a device fault, not a
+            # per-item one (on the sync CPU backend a post-return fetch
+            # failure is unreachable, so nothing is lost by escalating).
+            self._device_fault(e)
+            return
+        clock.mark(_PREFILL_POST)
+        if self._occupant[slot] is not occ:
+            return  # failed over since the launch
+        item = occ["item"]
+        now = time.monotonic()
         # Prefill complete: `tok` is the sample from the prompt's last
         # position — the first generated token.
         ttft = now - item["t_submit"]
@@ -1389,69 +1528,116 @@ class ContinuousScheduler:
                 },
             )
         occ["tokens"].append(tok)
-        self._publish(occ)
-        self._active[slot] = True
-        self._pos[slot] = self._T
-        self._tok[slot] = tok
+        self._publish(occ, first=True)
         if self._eos is not None and tok == self._eos:
             self._retire(slot, "eos")
         elif len(occ["tokens"]) >= occ["budget"]:
             self._retire(slot, "max_tokens")
 
-    def _step_once(self) -> None:
-        """One compiled step over every decoding slot; retire/refill
-        happens on the host between steps (the iteration-level
-        boundary)."""
+    def _step_failed(self, e: Exception, kernel: bool) -> None:
+        # Rate-limited: a wedged backend fails every subsequent
+        # step too — the first few stack traces are the signal,
+        # thousands more per minute are noise.
+        slog.exception(
+            "gen.step_failed", error=f"{type(e).__name__}: {e}",
+            active_slots=int(self._active.sum()),
+            steps_total=self.batches_total,
+        )
+        if kernel:
+            # A raise from the kernel call itself, or at the fetch of
+            # its result, may have consumed the donated cache.
+            self._device_fault(e)
+        else:
+            # A launch hook fires before the dispatch and leaves the
+            # cache intact: what the device has already computed is
+            # read and shipped first, then every resident fails over.
+            self._land()
+            self._fail_occupants(e)
+
+    def _launch_step(self) -> bool:
+        """Launch one compiled step over every decoding slot, from
+        what is known without reading the step before it: a lane's
+        input token is that step's output where it lies (the device),
+        its position one further, and a slot whose budget the launches
+        so far fill is simply not in this one, so no launch writes a
+        row past a slot's extent. What only a read can tell (EOS, the
+        guard, a cancel) is found one launch late: that lane is
+        computed and thrown away (:meth:`_land_step`). False where the
+        launch failed: nothing of it is left to read."""
         clock = self._clock
         clock.mark(_STEP_DISPATCH)
         clock.launched = True
-
-        def fail(e: Exception, kernel: bool) -> None:
-            # Rate-limited: a wedged backend fails every subsequent
-            # step too — the first few stack traces are the signal,
-            # thousands more per minute are noise.
-            slog.exception(
-                "gen.step_failed", error=f"{type(e).__name__}: {e}",
-                active_slots=int(self._active.sum()),
-                steps_total=self.batches_total,
-            )
-            # A raise from the kernel call itself may have consumed
-            # the donated cache; hook/fetch faults leave it intact.
-            self._device_fault(e) if kernel else self._fail_occupants(e)
-
         if self.launch_hook is not None:
             try:
                 self.launch_hook(self._tok)
             except Exception as e:  # noqa: BLE001 — fan out to occupants
-                fail(e, kernel=False)
-                return
+                self._step_failed(e, kernel=False)
+                return False
         try:
             toks, ok, cache = self._step(
-                self._params, self._cache, self._pos, self._active,
-                self._tok, self._next_key(),
+                self._params, self._cache, self._pos.copy(),
+                self._active.copy(), self._tok.copy(), self._next_key(),
+                self._prev, self._first,
             )
         except Exception as e:  # noqa: BLE001 — fan out to occupants
-            fail(e, kernel=True)
-            return
+            self._step_failed(e, kernel=True)
+            return False
         self._cache = cache
+        self._prev = toks
+        self._launched = (_STEP_FETCH, toks)
+        if self._unread and "lanes" in self._unread[0]:
+            clock.steps_ahead += 1
+        lanes = []
+        idle = mid = 0
+        for s in range(self._S):
+            occ = self._occupant[s]
+            if not self._active[s]:
+                if occ is not None and occ["fill"] < self._T:
+                    mid += 1
+                else:
+                    idle += 1  # empty, or its last token is under way
+                continue
+            # A replayed lane's sample is discarded: its next token is
+            # already known, and is the next launch's input.
+            forced = occ["replay"].popleft() if occ.get("replay") else None
+            lanes.append((s, occ, int(self._pos[s]), forced))
+            self._pos[s] += 1
+            self._tok[s] = _FROM_STEP if forced is None else forced
+            occ["issued"] += 1
+            if occ["issued"] >= occ["budget"]:
+                self._active[s] = False  # its last token is under way
+        self._unread.append({
+            "toks": toks, "ok": ok, "lanes": lanes, "idle": idle,
+            "mid": mid,
+        })
+        return True
+
+    def _land_step(self, rec: dict) -> None:
+        """Read a step's tokens (it has finished: the loop waited for
+        it before it launched the next), account, publish, retire."""
+        clock = self._clock
         clock.mark(_STEP_FETCH)
-        if self.fetch_hook is not None:
-            try:
-                self.fetch_hook(toks)
-            except Exception as e:  # noqa: BLE001 — fan out to occupants
-                fail(e, kernel=False)
-                return
+        clock.launched = True
         try:
-            toks = np.asarray(toks)
-            ok = np.asarray(ok) if ok is not None else None
+            if self.fetch_hook is not None:
+                self.fetch_hook(rec["toks"])
+            toks = np.asarray(rec["toks"]).tolist()
+            ok = np.asarray(rec["ok"]) if rec["ok"] is not None else None
         except Exception as e:  # noqa: BLE001 — fan out to occupants
             # Async backends surface a failed launch at this first host
             # sync: the rebound cache is the poisoned donated output,
-            # so recover as a device fault (kernel=True), unlike the
-            # pre-sync hook fault above which leaves the cache intact.
-            fail(e, kernel=True)
+            # and the step launched behind this one took it, so recover
+            # as a device fault, unlike the launch hook's fault, which
+            # fires before the dispatch and leaves the cache intact.
+            self._step_failed(e, kernel=True)
             return
         clock.mark(_STEP_ACCOUNT)
+        # A lane whose occupant has gone since the launch (EOS at the
+        # read before, a cancel, a fault) was computed for nobody: the
+        # row it wrote lies past the frontier of whoever holds the slot
+        # now, and its token is not shipped.
+        lanes = [ln for ln in rec["lanes"] if self._occupant[ln[0]] is ln[1]]
+        discarded = len(rec["lanes"]) - len(lanes)
         # Act on the in-kernel numeric guard (host decision — the
         # runtime opt-out never reshapes the compiled kernel): a slot
         # whose logits went non-finite fails over ALONE with INTEGRITY
@@ -1459,10 +1645,7 @@ class ContinuousScheduler:
         # untouched (bit-parity preserved).
         bad_slots: list[int] = []
         if ok is not None and _integrity.GUARD.enabled:
-            bad_slots = [
-                s for s in range(self._S)
-                if self._active[s] and not ok[s]
-            ]
+            bad_slots = [ln[0] for ln in lanes if not ok[ln[0]]]
         if bad_slots:
             _integrity.GUARD_ROWS_FAILED.inc(len(bad_slots))
             _integrity.GUARD_LAUNCHES.inc()
@@ -1478,67 +1661,114 @@ class ContinuousScheduler:
                     f"logits for slot {s} — failing this row instead "
                     f"of shipping a garbage token"
                 ))
+            lanes = [ln for ln in lanes if ln[0] not in bad_slots]
         self.batches_total += 1
-        active = clock.active_slots = int(self._active.sum())
-        self._count_positions(self._pos[self._active])
+        self.discarded_lanes_total += discarded
+        active = clock.active_slots = len(lanes)
+        self._count_positions(np.array([ln[2] for ln in lanes], np.int32))
         self.slot_steps_total += active
         self._m_rows.observe(active)
         if self._gp_model is not None:
             # Goodput split of this launch at slot granularity (Orca's
             # waste taxonomy): active lanes are useful up to their live
-            # attention frontier (launch-time pos — read BEFORE the
-            # retire loop advances it), occupied-but-chunking lanes are
-            # mid_prefill pad, empty lanes idle pad.
-            active_pos = []
-            idle = mid = replay = 0
-            for s in range(self._S):
-                if self._active[s]:
-                    if self._occupant[s].get("replay"):
-                        # Re-doing work the preemption threw away:
-                        # booked as pad (reason preempt_replay), never
-                        # as useful.
-                        replay += 1
-                    else:
-                        active_pos.append(int(self._pos[s]))
-                elif self._occupant[s] is None:
-                    idle += 1
-                else:
-                    mid += 1
+            # attention frontier (launch-time pos), lanes re-doing what
+            # a preemption threw away are pad (preempt_replay), lanes
+            # computed for an occupant that had gone are pad
+            # (discarded_lane), occupied-but-chunking lanes are
+            # mid_prefill pad, the rest idle pad.
             GOODPUT.record_decode_step(
-                self._gp_model, active_pos, idle, mid,
-                replay_slots=replay,
+                self._gp_model,
+                [ln[2] for ln in lanes if ln[3] is None],
+                rec["idle"] + len(bad_slots), rec["mid"],
+                replay_slots=sum(ln[3] is not None for ln in lanes),
+                discarded_slots=discarded,
             )
         clock.mark(_STEP_PUBLISH)
-        for s in range(self._S):
-            if not self._active[s]:
-                continue
-            occ = self._occupant[s]
-            if occ.get("replay"):
+        for s, occ, _pos, forced in lanes:
+            if forced is not None:
                 # Preemption replay: the step WROTE this position's
                 # K/V from the forced token (the same computation the
                 # original run performed); its sample is discarded —
                 # the next token is already known. No retire checks:
                 # the replayed stream was mid-decode when preempted.
-                forced = int(occ["replay"].pop(0))
-                occ["tokens"].append(forced)
+                occ["tokens"].append(int(forced))
                 self._publish(occ)
-                self._pos[s] += 1
-                self._tok[s] = forced
                 continue
-            tok = int(toks[s])
+            tok = toks[s]
             occ["tokens"].append(tok)
             self._publish(occ)
-            self._pos[s] += 1
-            self._tok[s] = tok
             if self._eos is not None and tok == self._eos:
                 self._retire(s, "eos")
             elif len(occ["tokens"]) >= occ["budget"]:
                 self._retire(s, "max_tokens")
 
+    def _await_device(self) -> None:
+        """Wait, off the interpreter, until the last launch of this
+        iteration (its step; its chunk where nothing decodes yet) has
+        finished, without reading it. The loop has to wait for the
+        device somewhere; here, the wait ends right before the next
+        reap / admit / bind, so a request that arrived meanwhile is
+        bound and its chunk launched at once and not behind a step
+        queued earlier, and the handler threads the publishes woke have
+        the interpreter to themselves. The next step is then launched
+        from this one's tokens where they lie and this one is read
+        behind that launch: account, publish and retire stay in the
+        step's shadow, the dispatch does not (nothing is queued while
+        the loop admits and launches). Where the host is the slower
+        (the launch finished long ago) this waits for nothing."""
+        launched, self._launched = self._launched, None
+        if launched is None:
+            return
+        phase, result = launched
+        wait = getattr(result, "block_until_ready", None)
+        if wait is None:
+            return  # an injected kernel's result is the host's already
+        self._clock.mark(phase)
+        try:
+            wait()
+        except Exception as e:  # noqa: BLE001 — fan out to occupants
+            # A failed launch surfaces at the first wait for it.
+            self._step_failed(e, kernel=True)
+
+    def _land(self, keep: int = 0) -> None:
+        """Read, oldest first, every launch still unread but the
+        ``keep`` newest. Whatever needs the host's view of the streams
+        to be final (a preemption keeps the tokens it replays; a launch
+        hook's fault fails over what was shipped) calls it with none
+        kept. A device fault on the way drops the rest unread.
+
+        A step's publishes only enqueue (:meth:`_publish`): its
+        handlers are woken here, together. Behind a chunk that ended a
+        prompt they wait for the first token, which then goes out ahead
+        of them, but only as long again as the chunk has already had
+        since its launch: one that is not through by then (a long
+        chunk) lets the round go first."""
+        while len(self._unread) > keep:
+            rec = self._unread.popleft()
+            if "lanes" in rec:
+                self._land_step(rec)
+                continue
+            ready = getattr(rec["tok"], "is_ready", None)
+            if ready is not None and self._woken:
+                due = 2 * time.monotonic() - rec["t_launch"]
+                while not ready() and time.monotonic() < due:
+                    time.sleep(0)  # off the interpreter
+                if not ready():
+                    self._wake_round()
+            self._land_first(rec)
+        self._wake_round()
+
+    def _wake_round(self) -> None:
+        woken, self._woken = self._woken, []
+        for stream in woken:
+            stream.wake()
+
     def _resident(self) -> bool:
-        """Any slot occupied — decoding or mid-prefill (both must drain
-        before close() may stop the loop)."""
-        return any(o is not None for o in self._occupant)
+        """Any slot occupied — decoding or mid-prefill — or a launch
+        still unread (all must drain before close() may stop the loop,
+        or the loop may sleep)."""
+        return bool(self._unread) or any(
+            o is not None for o in self._occupant)
 
     def _next_bindable(self, max_rank: int | None = None):  # caller-holds: _cond
         """The next row to bind, in class-priority order across BOTH
@@ -1677,7 +1907,12 @@ class ContinuousScheduler:
                 got = self._next_bindable(max_rank=0)
             if got is None:
                 return
-            self._preempt_slot(victim)
+            # The victim's stream has to be whole before it is kept for
+            # replay: read the step still out. That may retire a row
+            # (the victim too), and then nobody has to go.
+            self._land()
+            if all(o is not None for o in self._occupant):
+                self._preempt_slot(victim)
             self._bind(got)
 
     def _loop(self) -> None:
@@ -1716,11 +1951,18 @@ class ContinuousScheduler:
                 self._bind(bindable)
             if self._preemption and not core.closed:
                 self._preempt_for_critical()
+            # One launch ahead: the chunk and the next step go to the
+            # device on what is known without a read, and only then is
+            # the step launched an iteration ago read, and behind it
+            # the chunk's token. Account, publish and the handlers'
+            # sends run while the device works; then the loop waits
+            # for it.
             slot = self._next_prefill_slot()
             if slot is not None:
-                self._prefill_chunk_once(slot)
-            if self._active.any():
-                self._step_once()
+                self._launch_chunk(slot)
+            ahead = self._active.any() and self._launch_step()
+            self._land(keep=1 if ahead else 0)
+            self._await_device()
 
     # ------------------------------------------------------------ close
 

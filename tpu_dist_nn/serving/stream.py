@@ -110,13 +110,16 @@ class TokenStream:
         with self._cond:
             self._sent = max(self._sent, int(n))
 
-    def publish(self, tokens) -> bool:
+    def publish(self, tokens, *, notify: bool = True) -> bool:
         """Enqueue the unseen suffix of the full known-token list.
 
         Called from the scheduler loop with ``occ["tokens"]`` after
         every append; never blocks. Returns False once the stream is
         cancelled (client gone or buffer overflowed) — the scheduler's
-        cue to abandon the row and free its slot.
+        cue to abandon the row and free its slot. ``notify=False``
+        leaves the consumer asleep: the producer has more streams to
+        publish to and wakes them together (:meth:`wake`) when it is
+        about to let go of the interpreter they all share.
         """
         with self._cond:
             if self._cancelled or self._terminal is not None:
@@ -145,8 +148,14 @@ class TokenStream:
                 _STREAM_CANCELLED.inc()
                 self._cond.notify_all()
                 return False
-            self._cond.notify_all()
+            if notify:
+                self._cond.notify_all()
             return True
+
+    def wake(self) -> None:
+        """The other half of ``publish(notify=False)``."""
+        with self._cond:
+            self._cond.notify_all()
 
     def finish(self, reason: str, code: str = "",
                message: str = "") -> None:
