@@ -163,6 +163,11 @@ QUICK_TESTS = {
     "test_sala": ["test_forward_matches_reference",
                   "test_prefill_then_decode_matches_full_forward[37]",
                   "test_rebinding_a_slot_leaves_no_state_behind"],
+    # PR 31: the third block family (rings, a scan, a chunk without
+    # logits) against its plain reference.
+    "test_sambay": ["test_forward_matches_reference",
+                    "test_prefill_then_decode_matches_full_forward[13]",
+                    "test_scheduler_streams_complete_with_lengths_as_asked"],
     "test_kv_write": [
         "test_write_rows_lands_rows_and_nothing_else[partial_lane_block]"],
     # PR 28: the chunk's block-masked attention kernel against the loop.

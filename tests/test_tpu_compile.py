@@ -227,6 +227,56 @@ def _sala_chunk(S, jnp):
     return chunk, args, check
 
 
+def _sambay_step(S, jnp):
+    """The scheduler's step program for the SambaY family at the
+    published attention widths (40 query heads on 20 K/V heads of 64,
+    rings of 512) and the benchmark cell's 96 slots of extent 3071,
+    eight layers deep on a narrow MLP, with a prefix pool: two Mosaic
+    calls write the ring rows at `pos mod 512` and the shared K/V rows
+    at `pos`, and nothing else in the compiled program yields a layer of
+    a ring (nor, eight times that, the shared K or V): the rows are
+    read where they lie."""
+    import jax
+
+    from tools.aot_step_ops import big_ops
+    from tpu_dist_nn.models import sambay
+    from tpu_dist_nn.serving.continuous import slot_kernels
+
+    cfg = sambay.SambaYConfig(
+        vocab_size=1024, hidden_size=2560, intermediate_size=512,
+        n_heads=40, n_kv_heads=20, n_layers=8, sliding_window=512,
+        max_seq_len=262144,
+    )
+    slots, pool, extent = 96, 4, 3071
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: S(a.shape, a.dtype), tree)
+
+    params = shaped(jax.eval_shape(
+        lambda: sambay.init_sambay(jax.random.key(0), cfg)))
+    cache = shaped(jax.eval_shape(
+        lambda: sambay.init_slot_cache(cfg, slots + pool, extent)))
+    key = shaped(jax.eval_shape(lambda: jax.random.key(0)))
+    _, _, step = slot_kernels(cfg, 0.0, None, None)
+    ints = S((slots,), jnp.int32)
+
+    def check(text):
+        layer = slots * cfg.n_kv_heads * cfg.head_dim * cfg.sliding_window
+        found = big_ops(text, layer)["ops"]
+        calls = [op for op in found
+                 if op["opcode"] == "custom-call tpu_custom_call"]
+        problems = [] if len(calls) == 2 else [
+            f"{len(calls)} row writes at cache size, not the rings' and "
+            "the shared K/V's"]
+        return problems + [
+            f"{op['name']} ({op['opcode']}) {op['shapes']}"
+            for op in found if op not in calls]
+
+    args = (params, cache, ints, S((slots,), jnp.bool_), ints, key, ints,
+            S((), jnp.int32))
+    return step, args, check
+
+
 def _sparse_attend_small_tiles(S, jnp):
     """The kernel alone at the smallest tiles its dispatch keeps (query
     128, key 384: an extent of 1152 that 768 does not divide); the cell's
@@ -264,6 +314,7 @@ CASES = {
     "sala_step_g2_d128_m33023_in_place": _sala_step,
     "sala_chunk_c2048_g2_d128_m33023_attend_kernel": _sala_chunk,
     "sparse_attend_q128_k384_smallest_tiles": _sparse_attend_small_tiles,
+    "sambay_step_g20_d64_w512_m3071_in_place": _sambay_step,
 }
 
 

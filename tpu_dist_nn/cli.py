@@ -1444,11 +1444,7 @@ def _lm_serve_model_config(args) -> int:
     through ``serve_lm_generate`` and the continuous scheduler."""
     import jax
 
-    from tpu_dist_nn.models.sala import (
-        init_sala,
-        load_model_config,
-        num_params,
-    )
+    from tpu_dist_nn.models.sala import init_model_config, load_model_config
     from tpu_dist_nn.serving.resilience import GracefulDrain
 
     if getattr(args, "serve_generate", None) is None:
@@ -1458,10 +1454,10 @@ def _lm_serve_model_config(args) -> int:
     _validate_slo_flags(args, needs="serve-generate")
     _validate_incident_flags(args, needs="serve-generate")
     cfg = load_model_config(args.model_config)
-    params = init_sala(jax.random.key(args.seed), cfg)
+    params, count, kinds = init_model_config(jax.random.key(args.seed), cfg)
     report = {"model_config": args.model_config,
-              "params": num_params(cfg), "param_dtype": cfg.param_dtype,
-              "layers": list(cfg.mixer_types)}
+              "params": count, "param_dtype": cfg.param_dtype,
+              "layers": list(kinds)}
     drain = GracefulDrain(grace_seconds=args.drain_grace_seconds)
     metrics_server = _start_metrics_server(
         args, health_fn=drain.wrap_health(None)
@@ -4008,7 +4004,8 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--model-config", default=None, metavar="FILE.json",
                    help="serve a published architecture instead of "
                         "training the Tiny-Transformer: a config.json in "
-                        "the source's own keys (model_type: minicpm_sala), "
+                        "the source's own keys (model_type: minicpm_sala "
+                        "or phi4flash), "
                         "seeded random weights in its param_dtype, served "
                         "by --serve-generate on the continuous scheduler "
                         "(docs/MODEL_CONFIG.md)")

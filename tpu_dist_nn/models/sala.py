@@ -231,14 +231,14 @@ class SalaConfig:
 
 def load_model_config(path: str):
     """The config of ``tdn lm --model-config <file.json>``: dispatch on
-    the file's ``model_type``."""
+    the file's ``model_type``.  The other families are dispatched at
+    the end of this file, so that no line of a function this family's
+    programs trace moves (their compile-cache keys hold them)."""
     with open(path) as f:
         d = json.load(f)
     if d.get("model_type") == "minicpm_sala":
         return SalaConfig.from_dict(d)
-    raise ValueError(
-        f"{path}: model_type {d.get('model_type')!r} has no loader "
-        "(known: 'minicpm_sala')")
+    return _load_other_family(path, d)
 
 
 # ------------------------------------------------------------ parameters
@@ -934,3 +934,26 @@ class SalaFlopModel:
             total += self.chunk_flops(c)
             pos += c
         return total
+
+
+# ------------------------------------------- the --model-config entry
+# Below every traced function of this file: a program's compile-cache
+# key holds the source lines of what it traces (PERF.md section 6, PR
+# 29), and this family's programs are to stay what they were.
+
+def _load_other_family(path: str, d: dict):
+    if d.get("model_type") == "phi4flash":
+        from tpu_dist_nn.models.sambay import SambaYConfig
+
+        return SambaYConfig.from_dict(d)
+    raise ValueError(
+        f"{path}: model_type {d.get('model_type')!r} has no loader "
+        "(known: 'minicpm_sala', 'phi4flash')")
+
+
+def init_model_config(key: jax.Array, cfg):
+    """What ``tdn lm --model-config`` needs of a loaded config, whatever
+    its family: ``(seeded parameters, their count, its layers' kinds)``."""
+    if isinstance(cfg, SalaConfig):
+        return init_sala(key, cfg), num_params(cfg), cfg.mixer_types
+    return cfg.init_params(key), cfg.num_params(), cfg.layer_kinds

@@ -3,11 +3,14 @@
 The scheduler (:mod:`tpu_dist_nn.serving.continuous`) builds its three
 programs — chunk prefill, slot copy, decode step — from four functions
 and never learns what a layer is.  A model's config hands them over
-with ``cfg.slot_model()``; the two implementers are
+with ``cfg.slot_model()``; the three implementers are
 :class:`~tpu_dist_nn.models.transformer.TransformerConfig` (GPT-2's
-block, one ``{k, v}`` cache: :mod:`.generate`) and
+block, one ``{k, v}`` cache: :mod:`.generate`),
 :class:`~tpu_dist_nn.models.sala.SalaConfig` (two kinds of layer, K/V
-rows plus compressed keys plus recurrent state: :mod:`.sala`).
+rows plus compressed keys plus recurrent state: :mod:`.sala`) and
+:class:`~tpu_dist_nn.models.sambay.SambaYConfig` (five kinds of layer,
+one K/V cache plus window rings plus convolution inputs and scan state,
+and a fourth program: a chunk that ends without logits: :mod:`.sambay`).
 docs/MODEL_CONFIG.md says what the prefix pool and preemption require
 of an implementer.
 """
@@ -52,3 +55,10 @@ class SlotModel:
     # model's attention kernel (kernels/sparse_attend.py) and not its
     # XLA loop.  It reads the shapes, as the model's dispatch does.
     attend_kernel: Callable = _no_kernel
+    # (params, cfg, cache, slot, tokens (1, C), start) -> cache: the
+    # chunk without logits, for a model whose later layers see only the
+    # position a token is read from.  Where a model gives it, the
+    # scheduler launches it (a fourth program, `jit_prefill_body`) for
+    # every chunk whose token nobody reads; it leaves the cache what
+    # `prefill_chunk_into_cache` leaves.  None: every chunk is that one.
+    prefill_body_into_cache: Callable | None = None

@@ -413,12 +413,16 @@ class GoodputTracker:
 
     def record_prefill_chunk(self, model: LMFlopModel, start: int,
                              size: int, final: bool, *,
+                             body: bool = False,
                              path: str = "gen") -> None:
         """One prefill-chunk launch: the masked key tail and the
-        non-sampled unembed positions are ``chunk_tail`` pad."""
+        non-sampled unembed positions are ``chunk_tail`` pad. ``body``:
+        the launch was the model's program that ends without logits
+        (``SlotModel.prefill_body_into_cache``), whose static cost the
+        model gives as ``body_flops``."""
         if not self.enabled:
             return
-        total = model.chunk_flops(size)
+        total = model.body_flops(size) if body else model.chunk_flops(size)
         useful = model.chunk_useful_flops(start, size, final)
         tail = total - useful
         self._add("prefill", path, useful,

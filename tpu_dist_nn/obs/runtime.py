@@ -190,6 +190,12 @@ class RuntimeSampler:
                 "tdn_gen_prefill_chunks_total",
                 "prefill chunk launches",
             ),
+            "prefill_body_chunks_total": reg.counter(
+                "tdn_gen_prefill_body_chunks_total",
+                "prefill chunk launches made with the model's program "
+                "that ends without logits (a chunk that ends no prompt, "
+                "or a resume's re-prefill); the rest sampled a token",
+            ),
             "attend_kernel_chunks_total": reg.counter(
                 "tdn_gen_attend_kernel_chunks_total",
                 "prefill chunk launches whose shapes tile for the model's "
@@ -214,7 +220,7 @@ class RuntimeSampler:
         self._g_gen_cache = reg.gauge(
             "tdn_gen_cache_bytes",
             "bytes of the slot cache by kind of state: kv rows, "
-            "compressed keys, recurrent state",
+            "compressed keys, window rings, recurrent state",
             labels=("kind",),
         )
         # Router replica pools (serving/pool.py): the fleet-state

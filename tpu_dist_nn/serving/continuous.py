@@ -288,6 +288,32 @@ def slot_kernels(cfg, temperature, top_k, top_p):
             jax.jit(model.copy_cache_slot, donate_argnums=(0,)), step)
 
 
+def slot_body_kernel(cfg):
+    """The fourth program of a model whose protocol has the chunk
+    without logits (``SlotModel.prefill_body_into_cache``), else None:
+    ``prefill_body(params, cache, slot, tokens, start) -> (end, cache)``
+    on the same donated cache. ``end`` (the position after the chunk) is
+    the launch's handle: what :meth:`ContinuousScheduler._await_device`
+    waits for where ``prefill_chunk`` gives its token. Jitted under a
+    name of its own, so a trace tells ``jit_prefill_body`` from
+    ``jit_prefill_chunk``; :func:`slot_kernels` keeps returning three."""
+    import jax
+    import jax.numpy as jnp
+
+    model = cfg.slot_model()
+    if model.prefill_body_into_cache is None:
+        return None
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def prefill_body(params, cache, slot, tokens, start):
+        cache = model.prefill_body_into_cache(
+            params, cfg, cache, slot, tokens, start
+        )
+        return jnp.asarray(start, jnp.int32) + tokens.shape[1], cache
+
+    return prefill_body
+
+
 class _LoopClock:
     """Where the scheduler loop's time goes, always on: the loop thread
     calls :meth:`mark` at each phase boundary, which closes the phase
@@ -593,6 +619,7 @@ class ContinuousScheduler:
         # injected kernels carry no model and keep these.
         self._tier_at_fill, self._sparse_positions = False, lambda pos: 0
         self._attend_kernel = lambda size: False
+        self._prefill_body = None
         self.cache_bytes: dict = {}
         if prefill_fn is not None or step_fn is not None:
             if prefill_fn is None or step_fn is None:
@@ -722,6 +749,9 @@ class ContinuousScheduler:
         self.discarded_lanes_total = 0
         self.retired_total = 0     # rows retired (eos + max_tokens)
         self.prefill_chunks_total = 0  # chunk-kernel launches
+        # ... of which the program that ends without logits ran
+        # (`SlotModel.prefill_body_into_cache`): nobody read their token.
+        self.prefill_body_chunks_total = 0
         # ... of which the model says its program holds its attention
         # kernel (`SlotModel.attend_kernel`, asked once a chunk size).
         self.attend_kernel_chunks_total = 0
@@ -771,6 +801,7 @@ class ContinuousScheduler:
         self._prefill, self._copy, self._step = slot_kernels(
             cfg, temperature, top_k, top_p
         )
+        self._prefill_body = slot_body_kernel(cfg)
 
     def _count_positions(self, pos) -> None:
         """Book the query positions ``pos`` of a chunk or a step under
@@ -826,7 +857,13 @@ class ContinuousScheduler:
             _, cache = self._prefill(
                 self._params, cache, np.int32(0), zeros, np.int32(0), key
             )
+            if self._prefill_body is not None:
+                _, cache = self._prefill_body(
+                    self._params, cache, np.int32(0), zeros, np.int32(0)
+                )
         warmed = ["prefill_chunk_into_cache"]
+        if self._prefill_body is not None:
+            warmed.append("prefill_body_into_cache")
         if self._P:
             # Self-copy of free slot 0: compiles the (src, dst)-traced
             # kernel without touching live state.
@@ -1409,11 +1446,22 @@ class ContinuousScheduler:
             except Exception as e:  # noqa: BLE001 — per item
                 self._free_slot_on_error(slot, e)
                 return
+        # Nobody reads the token of a chunk that ends no prompt, nor of
+        # a resume's re-prefill (its first token is known): where the
+        # model has the program that ends without logits, that one runs.
+        body = self._prefill_body is not None and (
+            start + size < self._T or occ["resume"] is not None)
         try:
-            tok, cache = self._prefill(
-                self._params, self._cache, np.int32(slot), tokens,
-                np.int32(start), self._next_key(),
-            )
+            if body:
+                tok, cache = self._prefill_body(
+                    self._params, self._cache, np.int32(slot), tokens,
+                    np.int32(start),
+                )
+            else:
+                tok, cache = self._prefill(
+                    self._params, self._cache, np.int32(slot), tokens,
+                    np.int32(start), self._next_key(),
+                )
         except Exception as e:  # noqa: BLE001 — donated cache: global
             self._device_fault(e)
             return
@@ -1422,6 +1470,7 @@ class ContinuousScheduler:
         clock.mark(_PREFILL_POST)
         occ["fill"] = start + size
         self.prefill_chunks_total += 1
+        self.prefill_body_chunks_total += body
         clock.prefill_tokens += size
         clock.prefill_starts += start
         self.attend_kernel_chunks_total += self._attend_kernel(size)
@@ -1433,6 +1482,7 @@ class ContinuousScheduler:
             GOODPUT.record_prefill_chunk(
                 self._gp_model, start, size,
                 final=occ["fill"] >= self._T and occ["resume"] is None,
+                body=body,
             )
         now = time.monotonic()
         if item["ctx"] is not None:
