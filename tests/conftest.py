@@ -174,6 +174,12 @@ QUICK_TESTS = {
     "test_sparse_attend": [
         "test_kernel_matches_the_loop[1024-bfloat16]",
         "test_the_shapes_alone_decide_which_path_runs[ragged-chunk]"],
+    # PR 32: the decode step's shared-K/V attention kernel against the
+    # XLA path, and a planted fault it must catch.
+    "test_decode_attend": [
+        "test_kernel_matches_the_xla_path[tile-edges-bfloat16--0.4]",
+        "test_a_kernel_that_reads_one_tile_too_few_is_caught",
+        "test_the_shapes_alone_decide[cell-step]"],
     "test_multihost_real": ["test_two_process_collectives"],
     "test_native_codec": ["test_examples_roundtrip_and_parity",
                           "test_fuzz_model_roundtrip_native_vs_python"],

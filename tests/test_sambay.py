@@ -514,6 +514,141 @@ def test_other_families_hand_over_no_body_program():
     assert len(slot_kernels(CFG16, 0.0, None, None)) == 3
 
 
+# ------------------ the shared K/V through kernels/decode_attend.py
+
+# Heads of 16: a whole bfloat16 sublane tile, so the served type tiles
+# too.  CFG32 (heads of 8, float32) tiles, CFG16 (heads of 8, bfloat16)
+# does not: every test above with CFG32 already runs the kernel.
+WIDE = dict(TOY, hidden_size=128)
+WIDE16 = sambay.SambaYConfig.from_dict(WIDE)
+
+
+@pytest.mark.parametrize("cfg, tiled", [
+    (CFG32, True), (CFG16, False), (WIDE16, True)],
+    ids=["f32-heads-of-8", "bf16-heads-of-8", "bf16-heads-of-16"])
+def test_the_shapes_alone_decide_how_the_tail_reads_the_cache(cfg, tiled):
+    """What `SlotModel.step_kv_tiles` says is what the step and the
+    final chunk hold: a `pallas_call` named decode_attend where the
+    shapes tile, `_attend_rows` over the whole extent elsewhere."""
+    assert (cfg.slot_model().step_kv_tiles(S, T + N - 1) is not None) is tiled
+    params = jax.eval_shape(lambda: sambay.init_sambay(jax.random.key(0), cfg))
+    cache = jax.eval_shape(lambda: sambay.init_slot_cache(cfg, S + 1, T + N - 1))
+    ints = jax.ShapeDtypeStruct((S,), jnp.int32)
+    step = str(jax.make_jaxpr(lambda p, c, pos, tok: sambay.decode_step_slots(
+        p, c, pos, tok, cfg))(params, cache, ints, ints))
+    chunk = str(jax.make_jaxpr(
+        lambda p, c, tok, st: sambay.prefill_chunk_into_cache(
+            p, cfg, c, 1, tok, st))(
+        params, cache, jax.ShapeDtypeStruct((1, 13), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32)))
+    for text in (step, chunk):
+        assert ("decode_attend" in text) is tiled
+
+
+@pytest.mark.parametrize("wide", [False, True], ids=["f32", "bf16"])
+def test_kernel_and_xla_paths_leave_the_same_cache_and_tokens(
+        monkeypatch, weights, rows, wide):
+    """Three slots prefilled in chunks (the final chunk's tail is the
+    kernel at one slot) and decoded for 24 steps, one of them joining
+    late, with the kernel and with the dispatch forced to
+    `_attend_rows`: every leaf of the cache bit for bit and logits
+    within the file's tolerances.  In float32 the decode is greedy and
+    the tokens are the same; in bfloat16, where `_attend_rows` rounds
+    `a` to 8 bits and the kernel does not, a near-tie among 512 logits
+    may fall either way (seen: 1 of 65), so the tokens are given and
+    the greedy choices agree but for such ties."""
+    from tpu_dist_nn.kernels import decode_attend
+
+    cfg = WIDE16 if wide else CFG32
+    params = cfg.cast_params(
+        ref.make_weights(WIDE, 3, "float32") if wide else weights)
+
+    def run():
+        pre, body, step = _programs(params, cfg)
+        cache = sambay.init_slot_cache(cfg, S + 1, T + N - 1)
+        pos, tok, seen = np.zeros(S, np.int32), np.zeros(S, np.int32), []
+        for s in range(S):
+            n = T - 10 * s
+            logits, cache = _prefill(pre, cache, s, rows[s, :n], 13,
+                                     body=body)
+            seen.append(np.asarray(logits, np.float32)[0])
+            pos[s], tok[s] = n, int(np.argmax(seen[-1]))
+        active, toks = np.array([True, True, False]), []
+        for i in range(24):
+            logits, cache = step(cache, jnp.asarray(pos), jnp.asarray(tok),
+                                 jnp.asarray(active))
+            logits = np.asarray(logits, np.float32)
+            seen.extend(logits[active])
+            toks.extend(logits.argmax(-1)[active].tolist())
+            pos += active
+            given = rows[np.arange(S), pos] if wide else logits.argmax(-1)
+            tok = np.where(active, given, tok).astype(np.int32)
+            active[2] |= i == 6
+        return toks, np.stack(seen), cache
+
+    assert cfg.slot_model().step_kv_tiles(S, T + N - 1) is not None
+    got_toks, got, got_cache = run()
+    monkeypatch.setattr(decode_attend, "tiles", lambda *a: None)
+    assert cfg.slot_model().step_kv_tiles(S, T + N - 1) is None
+    want_toks, want, want_cache = run()
+    agree = np.mean(np.asarray(got_toks) == np.asarray(want_toks))
+    assert agree >= 0.95 if wide else agree == 1.0
+    for name in want_cache:
+        assert (np.asarray(got_cache[name].astype(jnp.float32))
+                == np.asarray(want_cache[name].astype(jnp.float32))).all(), name
+    assert want.std() > 0.5
+    if wide:
+        assert _rms(got - want) < 0.02
+    else:
+        np.testing.assert_allclose(got, want, atol=1e-4)
+
+
+def test_scheduler_counts_the_kv_tiles_its_steps_copy_and_skip(weights, rows):
+    """Two slots of extent 256 (two 128-lane tiles) decoding from
+    position 50: a step copies the first tile of each and skips the
+    second; a slot the step does not decode is read as at position 0.
+    Fetched and skipped add up to slots x tiles a step, on the scheduler
+    and on /metrics."""
+    from tpu_dist_nn.obs.registry import Registry
+    from tpu_dist_nn.obs.runtime import RuntimeSampler
+
+    reg = Registry()
+    sampler = RuntimeSampler(registry=reg)
+    sched = ContinuousScheduler(weights, CFG32, slots=2, prompt_len=T,
+                                max_new_tokens=150, prefill_chunk=25)
+    sampler.add_generation_scheduler(sched)
+    try:
+        streams = [sched.submit_stream(rows[i:i + 1, :T], max_new_tokens=b)
+                   for i, b in enumerate((5, 3))]
+        assert [len(_drain(s)) for s in streams] == [5, 3]
+        sampler.sample_once()
+    finally:
+        sched.close()
+    visited, skipped = (sched.step_kv_tiles_visited_total,
+                        sched.step_kv_tiles_skipped_total)
+    assert sched.steps_total >= 4
+    assert visited + skipped == sched.steps_total * 2 * 2
+    assert visited == skipped  # nobody got past position 128
+    scraped = {m.name: {k: c.value for k, c in m.samples()}
+               for m in reg.collect()}["tdn_gen_step_kv_tiles_total"]
+    assert scraped == {("visited",): visited, ("skipped",): skipped}
+
+
+def test_a_step_that_reads_the_whole_extent_counts_no_tiles(served):
+    """CFG16's heads of 8 do not tile; GPT-2 and SALA have no such
+    kernel: the scheduler asks once and does no work for them."""
+    from tpu_dist_nn.models.transformer import TransformerConfig
+
+    sched = served[3]
+    assert sched._kv_tiles is None and sched.steps_total > 0
+    assert sched.step_kv_tiles_visited_total == 0
+    assert sched.step_kv_tiles_skipped_total == 0
+    with open(os.path.join(CONFIGS, "rehearsal-sala-tiny.json")) as f:
+        sala_cfg = sala.SalaConfig.from_dict(json.load(f))
+    for cfg in (TransformerConfig(), sala_cfg):
+        assert cfg.slot_model().step_kv_tiles(8, 383) is None
+
+
 # ------------------------------------------------------- the entry point
 
 def test_cli_lm_model_config_serves_over_the_wire():

@@ -235,7 +235,10 @@ def _sambay_step(S, jnp):
     calls write the ring rows at `pos mod 512` and the shared K/V rows
     at `pos`, and nothing else in the compiled program yields a layer of
     a ring (nor, eight times that, the shared K or V): the rows are
-    read where they lie."""
+    read where they lie, the shared K/V by the Mosaic call of
+    kernels/decode_attend.py (the full layer's and the cross layers'
+    scan body's), which fits VMEM at the tile it picks, and nothing is
+    left of the float32 scores `(96, 20, 2, 3072)` XLA kept in HBM."""
     import jax
 
     from tools.aot_step_ops import big_ops
@@ -268,9 +271,17 @@ def _sambay_step(S, jnp):
         problems = [] if len(calls) == 2 else [
             f"{len(calls)} row writes at cache size, not the rings' and "
             "the shared K/V's"]
+        if text.count('custom_call_target="tpu_custom_call"') != 4 \
+                or "decode_attend" not in text:
+            problems.append("not two row writes and two decode_attend calls")
+        scores = slots * cfg.n_kv_heads * 2 * 3072
         return problems + [
             f"{op['name']} ({op['opcode']}) {op['shapes']}"
-            for op in found if op not in calls]
+            for op in found if op not in calls] + [
+            f"scores in HBM: {op['name']} {op['shapes']}"
+            for op in big_ops(text, scores)["ops"]
+            if any(d == "f32" and dims[-1] == 3072
+                   for d, dims, _, _ in op["shapes"])]
 
     args = (params, cache, ints, S((slots,), jnp.bool_), ints, key, ints,
             S((), jnp.int32))
@@ -289,6 +300,20 @@ def _sparse_attend_small_tiles(S, jnp):
         q, k, v, sel, start, 64)), (
         S((128, 2, 16, 128), jnp.bfloat16), rows, rows,
         S((128, 2, 18), jnp.bool_), S((), jnp.int32))
+
+
+def _decode_attend_one_slot(S, jnp):
+    """The kernel alone as a final chunk's tail runs it: one slot of a
+    cache of 96 (the step's 96 slots compile inside `sambay_step_...`
+    below), float32 rows as the CPU tests hold them."""
+    from tpu_dist_nn.kernels import decode_attend
+
+    assert decode_attend.tiles(1, 20, 64, 3072, jnp.float32) == 512
+    cache = S((1, 96, 20, 64, 3072))
+    own = S((1, 20, 64))
+    return decode_attend.attend_rows, (
+        S((1, 10, 2, 2, 64)), cache, cache, own, own, S((1,), jnp.int32),
+        S(()))
 
 
 CASES = {
@@ -315,6 +340,7 @@ CASES = {
     "sala_chunk_c2048_g2_d128_m33023_attend_kernel": _sala_chunk,
     "sparse_attend_q128_k384_smallest_tiles": _sparse_attend_small_tiles,
     "sambay_step_g20_d64_w512_m3071_in_place": _sambay_step,
+    "decode_attend_one_slot_g20_d64_m3072_f32": _decode_attend_one_slot,
 }
 
 

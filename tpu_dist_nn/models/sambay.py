@@ -50,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from tpu_dist_nn.kernels import decode_attend
 from tpu_dist_nn.kernels.kv_write import write_rows
 from tpu_dist_nn.models.sala import (
     _einsum32,
@@ -185,7 +186,26 @@ class SambaYConfig:
             cache_bytes=cache_bytes,
             recurrent=True,
             prefill_body_into_cache=prefill_body_into_cache,
+            step_kv_tiles=self.step_kv_tiles,
         )
+
+    def step_kv_tiles(self, slots: int, max_len: int):
+        """``pos (int array) -> (fetched, skipped)``: of the 128-lane
+        position tiles of the shared K/V extent, how many a decode step
+        copies for queries at ``pos`` and how many it leaves in HBM; or
+        ``None`` where the step of ``slots`` slots into a cache made for
+        ``max_len`` reads the whole extent (:func:`_tail`'s dispatch,
+        asked from outside the program)."""
+        M = _extent(max_len)
+        if decode_attend.tiles(slots, self.n_kv_heads, self.head_dim, M,
+                               self.param_dtype) is None:
+            return None
+
+        def count(pos):
+            fetched = int(decode_attend.fetched_tiles(pos).sum())
+            return fetched, len(pos) * (M // _LANES) - fetched
+
+        return count
 
     # ---------------------------------------------------------- loading
     @classmethod
@@ -552,20 +572,36 @@ def _window_step_layer(x, blk, lam0, wk, wv, pos, cfg):
 
 def _tail(params, cfg, x, m, K, V, pos):
     """Layers ``L / 2 + 1 ..`` on one position a row: ``x (S, D)`` out
-    of the last Mamba layer with its ``m (S, E)``, each row at ``pos
-    (S,)`` over its K/V rows ``(S, G, d, M)`` before ``pos``.  The full
-    layer's own key and value ``(S, G, d)`` come back for the caller to
-    land at ``pos``; the cross layers attend them as the full layer
-    does.  A decode step's second half, and a prompt's last position in
-    the chunk that ends it."""
+    of the last Mamba layer with its ``m (S, E)``, row ``s`` at ``pos[s]``
+    over the positions before it of slot ``s`` of the whole cache arrays
+    ``K``, ``V (1, slots, G, d, M)``, ``slots >= S``.  The full layer's
+    own key and value ``(S, G, d)`` come back for the caller to land at
+    ``pos``; the cross layers attend them as the full layer does.  A
+    decode step's second half, and a prompt's last position in the chunk
+    that ends it.
+
+    The eight layers read K and V through
+    :func:`tpu_dist_nn.kernels.decode_attend.attend_rows` where its
+    ``tiles`` says the shapes tile (each slot's live tiles once a layer,
+    the scores on the chip), and through :func:`_attend_rows` over the
+    whole extent elsewhere: only the shapes decide."""
+    S = x.shape[0]
     lam0 = cfg.lambda_init()
-    visible = jnp.arange(K.shape[-1])[None, :] < pos[:, None]
     blk = _layer(params["attn"], cfg.n_pairs)
     with jax.named_scope("sambay.attn.full"):
         q, k, v = _qkv(x, blk, cfg)
+        if decode_attend.tiles(S, *K.shape[2:], K.dtype) is not None:
+            def attend(q, lam):
+                return decode_attend.attend_rows(q, K, V, k, v, pos, lam)
+        else:
+            visible = jnp.arange(K.shape[-1])[None, :] < pos[:, None]
+
+            def attend(q, lam):
+                return _attend_rows(q, K[0, :S], V[0, :S], k, v, visible,
+                                    lam)
+
         first = float(lam0["attn"][-1])
-        o = _attend_rows(q, K, V, k, v, visible, _lam(blk) + first)
-        x = _diff_out(x, o, blk, first, cfg)
+        x = _diff_out(x, attend(q, _lam(blk) + first), blk, first, cfg)
 
     def pair(x, inputs):
         i, l0 = inputs
@@ -578,9 +614,8 @@ def _tail(params, cfg, x, m, K, V, pos):
             blk = _layer(params["cross"], i)
             qc = _ln(x, blk["ln1_g"], blk["ln1_b"], cfg.ln_eps) \
                 @ blk["w_q"] + blk["b_q"]
-            qc = qc.reshape(q.shape)
-            o = _attend_rows(qc, K, V, k, v, visible, _lam(blk) + l0)
-            x = _diff_out(x, o, blk, l0, cfg)
+            x = _diff_out(x, attend(qc.reshape(q.shape), _lam(blk) + l0),
+                          blk, l0, cfg)
         return x, None
 
     x, _ = lax.scan(pair, x, (jnp.arange(cfg.n_cross),
@@ -704,8 +739,8 @@ def prefill_chunk_into_cache(params: dict, cfg: SambaYConfig, cache: dict,
     x, m, mine, cache = _chunk_body(params, cfg, cache, slot, tokens, start)
     last = jnp.asarray(start, jnp.int32) + tokens.shape[1] - 1
     with jax.named_scope("sambay.tail"):
-        x, _, _ = _tail(params, cfg, x[-1:], m[-1:], mine["k"], mine["v"],
-                        last[None])
+        x, _, _ = _tail(params, cfg, x[-1:], m[-1:], mine["k"][:, None],
+                        mine["v"][:, None], last[None])
         return _unembed(params, x, cfg), cache
 
 
@@ -753,8 +788,10 @@ def decode_step_slots(params: dict, cache: dict, pos: jnp.ndarray,
         pair, (x, cache["conv"], cache["state"]),
         (jnp.arange(P), jnp.asarray(lam0["attn"][:P])))
     x, conv_all, state_all, m = mamba(*carry, P)
-    x, k, v = _tail(params, cfg, x, m, cache["k"][0, :S], cache["v"][0, :S],
-                    pos)
+    # A slot the step does not decode attends its own key alone: nothing
+    # of its rows is read.
+    x, k, v = _tail(params, cfg, x, m, cache["k"], cache["v"],
+                    jnp.where(active, pos, 0))
     wk, wv = write_rows(cache["wk"], cache["wv"],
                         wk_new.astype(cache["wk"].dtype),
                         wv_new.astype(cache["wv"].dtype), _ring_lane(pos, W),
@@ -777,8 +814,8 @@ def forward(params: dict, tokens: jnp.ndarray, cfg: SambaYConfig):
         x, m, mine, _ = _chunk_body(
             params, cfg, init_slot_cache(cfg, 1, T), 0, toks[None], 0)
         spread = lambda a: jnp.broadcast_to(a, (T,) + a.shape[1:])  # noqa: E731
-        x, _, _ = _tail(params, cfg, x, m, spread(mine["k"]),
-                        spread(mine["v"]), jnp.arange(T))
+        x, _, _ = _tail(params, cfg, x, m, spread(mine["k"])[None],
+                        spread(mine["v"])[None], jnp.arange(T))
         return _unembed(params, x, cfg)
 
     return jnp.stack([row(toks) for toks in tokens])
@@ -795,9 +832,11 @@ class SambaYFlopModel:
     (layers ``L / 2 + 1 ..``, the keys up to it in the full and cross
     layers, the head) only where a token is read from it: every decoded
     position, a prompt's last.  USEFUL counts the keys a position
-    attends; the STATIC launch counts what the programs compute: the
-    step and a chunk's tail score the whole extent, a chunk's window
-    layers the ring and the chunk."""
+    attends; the STATIC launch counts the whole extent for the step and
+    a chunk's tail (what ``_attend_rows`` computes; where
+    kernels/decode_attend.py runs it stops at each slot's frontier
+    tile, and the count is an upper bound), the ring and the chunk for
+    a chunk's window layers."""
 
     def __init__(self, cfg: SambaYConfig, cache_extent: int):
         self.cfg, self.M = cfg, _extent(cache_extent)

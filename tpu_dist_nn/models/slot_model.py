@@ -29,6 +29,10 @@ def _no_kernel(size, max_len) -> bool:
     return False
 
 
+def _whole_extent(slots, max_len) -> None:
+    return None
+
+
 @dataclasses.dataclass(frozen=True)
 class SlotModel:
     # (cfg, slots, max_len) -> cache pytree, every leaf (layers, slots, ...)
@@ -62,3 +66,10 @@ class SlotModel:
     # every chunk whose token nobody reads; it leaves the cache what
     # `prefill_chunk_into_cache` leaves.  None: every chunk is that one.
     prefill_body_into_cache: Callable | None = None
+    # (slots, max_len) -> None, or `pos (int array) -> (fetched,
+    # skipped)`: how many 128-lane position tiles of its K/V extent the
+    # decode step of `slots` slots into a cache made for `max_len`
+    # copies for queries at `pos`, and how many it leaves in HBM.  None:
+    # the step reads the whole extent, and the scheduler counts nothing.
+    # It reads the shapes and `pos`, as the model's kernel does.
+    step_kv_tiles: Callable = _whole_extent

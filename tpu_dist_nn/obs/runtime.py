@@ -217,6 +217,17 @@ class RuntimeSampler:
                 "never published",
             ),
         }
+        kv_tiles = reg.counter(
+            "tdn_gen_step_kv_tiles_total",
+            "128-lane position tiles of the K/V extent over the slots of "
+            "every decode step, by whether the step copied them (visited) "
+            "or left them in HBM past a slot's frontier (skipped); only a "
+            "model whose step stops there counts (kernels/decode_attend.py)",
+            labels=("state",),
+        )
+        for state in ("visited", "skipped"):
+            self._c_gen_counts[f"step_kv_tiles_{state}_total"] = \
+                kv_tiles.labels(state=state)
         self._g_gen_cache = reg.gauge(
             "tdn_gen_cache_bytes",
             "bytes of the slot cache by kind of state: kv rows, "

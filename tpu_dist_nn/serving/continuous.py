@@ -619,6 +619,7 @@ class ContinuousScheduler:
         # injected kernels carry no model and keep these.
         self._tier_at_fill, self._sparse_positions = False, lambda pos: 0
         self._attend_kernel = lambda size: False
+        self._kv_tiles = None
         self._prefill_body = None
         self.cache_bytes: dict = {}
         if prefill_fn is not None or step_fn is not None:
@@ -755,6 +756,12 @@ class ContinuousScheduler:
         # ... of which the model says its program holds its attention
         # kernel (`SlotModel.attend_kernel`, asked once a chunk size).
         self.attend_kernel_chunks_total = 0
+        # 128-lane position tiles of the K/V extent the decode steps
+        # copied and left in HBM, summed over the slots of every step,
+        # where the model's step stops at each slot's frontier
+        # (`SlotModel.step_kv_tiles`, asked once); else both stay 0.
+        self.step_kv_tiles_visited_total = 0
+        self.step_kv_tiles_skipped_total = 0
         self.ttft_recent: collections.deque[float] = collections.deque(
             maxlen=1024
         )
@@ -787,6 +794,7 @@ class ContinuousScheduler:
         # is asked when a size is first launched, not every chunk.
         self._attend_kernel = functools.lru_cache(maxsize=None)(
             lambda size: bool(model.attend_kernel(size, M)))
+        self._kv_tiles = model.step_kv_tiles(self._S, M)
         self._make_cache = lambda: model.init_slot_cache(
             cfg, self._S + self._P, M)
         self._cache = self._make_cache()
@@ -1716,6 +1724,15 @@ class ContinuousScheduler:
         self.discarded_lanes_total += discarded
         active = clock.active_slots = len(lanes)
         self._count_positions(np.array([ln[2] for ln in lanes], np.int32))
+        if self._kv_tiles is not None:
+            # What the launch read: every lane it decoded at its
+            # position, the step's other slots as at position 0.
+            at = np.zeros(self._S, np.int32)
+            for ln in rec["lanes"]:
+                at[ln[0]] = ln[2]
+            visited, skipped = self._kv_tiles(at)
+            self.step_kv_tiles_visited_total += visited
+            self.step_kv_tiles_skipped_total += skipped
         self.slot_steps_total += active
         self._m_rows.observe(active)
         if self._gp_model is not None:
