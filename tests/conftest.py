@@ -168,6 +168,11 @@ QUICK_TESTS = {
     "test_sambay": ["test_forward_matches_reference",
                     "test_prefill_then_decode_matches_full_forward[13]",
                     "test_scheduler_streams_complete_with_lengths_as_asked"],
+    # PR 33: the fourth block family (a latent cache, a share of the
+    # routed experts) against its plain reference.
+    "test_mla_moe": ["test_forward_matches_reference",
+                     "test_prefill_then_decode_matches_full_forward[136]",
+                     "test_the_shares_add_up_to_the_uncut_layer"],
     "test_kv_write": [
         "test_write_rows_lands_rows_and_nothing_else[partial_lane_block]"],
     # PR 28: the chunk's block-masked attention kernel against the loop.

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpu_dist_nn.kernels.kv_write import write_rows
+from tpu_dist_nn.kernels.kv_write import write_row, write_rows
 
 
 @pytest.mark.parametrize(
@@ -43,3 +43,28 @@ def test_write_rows_lands_rows_and_nothing_else(L, total, S, H, Dh, M, dtype):
         np.testing.assert_array_equal(
             np.asarray(got.astype(jnp.float32)), want
         )
+
+
+@pytest.mark.parametrize(
+    "L, total, S, H, Dh, M, dtype",
+    [
+        # One latent "head" of 40 numbers, three lane blocks, a pool behind.
+        pytest.param(3, 5, 4, 1, 40, 300, "bfloat16", id="latent_row"),
+        pytest.param(2, 3, 3, 2, 8, 13, "float32", id="one_block_f32"),
+    ],
+)
+def test_write_row_is_write_rows_on_one_array(L, total, S, H, Dh, M, dtype):
+    """The one-array twin (a model that caches a latent row, not a key
+    and a value) leaves what `write_rows` leaves in either of its two."""
+    keys = jax.random.split(jax.random.key(1), 2)
+    cache = jax.random.normal(keys[0], (L, total, H, Dh, M)).astype(dtype)
+    new = jax.random.normal(keys[1], (L, S, H, Dh)).astype(dtype)
+    pos = jnp.asarray([0, M - 1, M + 7, 5][:S], jnp.int32)
+    active = jnp.asarray([True, True, False, True][:S])
+    got = jax.jit(write_row)(cache, new, pos, active)
+    want, _ = jax.jit(write_rows)(cache, cache, new, new, pos, active)
+    assert got.dtype == cache.dtype
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)),
+                                  np.asarray(want.astype(jnp.float32)))
+    assert (np.asarray(got.astype(jnp.float32))
+            != np.asarray(cache.astype(jnp.float32))).any()

@@ -288,6 +288,94 @@ def _sambay_step(S, jnp):
     return step, args, check
 
 
+def _mla_moe_config():
+    from tpu_dist_nn.models import mla_moe
+
+    # The published attention and expert widths, one dense and two
+    # expert layers, a narrow dense FFN and a small vocabulary.
+    return mla_moe.MlaMoeConfig(
+        vocab_size=1024, hidden_size=7168, n_heads=64, q_lora_rank=1536,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, intermediate_size=512, moe_intermediate_size=2048,
+        n_layers=3, first_k_dense=1, router_width=384,
+        experts_held=tuple(range(12)), n_experts_per_tok=8,
+        routed_scaling_factor=2.827, max_seq_len=262144, rope_theta=50000.0,
+        rope_factor=64.0, rope_mscale_all_dim=1.0)
+
+
+def _mla_moe_programs(S, jnp):
+    import jax
+
+    from tpu_dist_nn.models import mla_moe
+    from tpu_dist_nn.serving.continuous import slot_kernels
+
+    cfg = _mla_moe_config()
+    slots, pool, extent = 48, 2, 9215
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: S(a.shape, a.dtype), tree)
+
+    params = shaped(jax.eval_shape(
+        lambda: mla_moe.init_mla_moe(jax.random.key(0), cfg)))
+    cache = shaped(jax.eval_shape(
+        lambda: mla_moe.init_slot_cache(cfg, slots + pool, extent)))
+    key = shaped(jax.eval_shape(lambda: jax.random.key(0)))
+    chunk, _, step = slot_kernels(cfg, 0.0, None, None)
+    return cfg, slots, params, cache, key, chunk, step
+
+
+def _mla_moe_step(S, jnp):
+    """The scheduler's step program for the latent-attention family at
+    the published widths (64 heads on one 576-wide latent row) and the
+    benchmark cell's 48 slots of extent 9215, with a prefix pool: one
+    Mosaic call writes the new rows of every layer at `pos`, and
+    nothing else in the compiled program yields a layer of latent rows:
+    they are read where they lie, all 576 of them in both products."""
+    from tools.aot_step_ops import big_ops
+
+    cfg, slots, params, cache, key, _, step = _mla_moe_programs(S, jnp)
+    ints = S((slots,), jnp.int32)
+
+    def check(text):
+        layer = slots * cfg.latent_dim * 9216
+        found = big_ops(text, layer)["ops"]
+        calls = [op for op in found
+                 if op["opcode"] == "custom-call tpu_custom_call"]
+        problems = [] if len(calls) == 1 and "kv_write_row" in text else [
+            f"{len(calls)} row writes at cache size, not one"]
+        return problems + [
+            f"{op['name']} ({op['opcode']}) {op['shapes']}"
+            for op in found if op not in calls]
+
+    args = (params, cache, ints, S((slots,), jnp.bool_), ints, key, ints,
+            S((), jnp.int32))
+    return step, args, check
+
+
+def _mla_moe_chunk(S, jnp):
+    """The chunk program of the same family at a chunk of 1024: the
+    slot's rows are written in place, the ragged expert product slices
+    one expert's matrices inside each tile's products and never copies a
+    layer of them (12 x 7168 x 4096: 0.7 GB a layer when it did), and
+    no key or value of a whole extent is expanded (a tile of 512 is)."""
+    from tools.aot_step_ops import big_ops
+
+    cfg, slots, params, cache, key, chunk, _ = _mla_moe_programs(S, jnp)
+
+    def check(text):
+        experts = cfg.n_held * cfg.hidden_size * cfg.moe_intermediate_size
+        found = big_ops(text, experts)["ops"]
+        return [f"{op['name']} ({op['opcode']}) {op['shapes']}"
+                for op in found
+                if not any(dims[:2] == (cfg.n_layers, slots + 2)
+                           for _, dims, _, _ in op["shapes"])]
+
+    args = (params, cache, S((), jnp.int32), S((1, 1024), jnp.int32),
+            S((), jnp.int32), key)
+    return chunk, args, check
+
+
+
 def _sparse_attend_small_tiles(S, jnp):
     """The kernel alone at the smallest tiles its dispatch keeps (query
     128, key 384: an extent of 1152 that 768 does not divide); the cell's
@@ -341,7 +429,14 @@ CASES = {
     "sparse_attend_q128_k384_smallest_tiles": _sparse_attend_small_tiles,
     "sambay_step_g20_d64_w512_m3071_in_place": _sambay_step,
     "decode_attend_one_slot_g20_d64_m3072_f32": _decode_attend_one_slot,
+    "mla_moe_step_h64_r576_m9215_in_place": _mla_moe_step,
 }
+# Programs that hold no Mosaic call: compiled the same way, held to
+# their own check alone.
+XLA_CASES = {
+    "mla_moe_chunk_c1024_r576_m9215_no_expert_copy": _mla_moe_chunk,
+}
+CASES.update(XLA_CASES)
 
 
 def _compile(case: str) -> dict:
@@ -388,8 +483,7 @@ def _compile(case: str) -> dict:
     return out
 
 
-@pytest.mark.parametrize("case", sorted(CASES))
-def test_kernel_compiles_to_a_mosaic_call_for_v5e(case):
+def _compiled_in_a_child(case: str) -> dict:
     lock = os.path.join(tempfile.gettempdir(), "tdn_tpu_compile.lock")
     with open(lock, "a") as held:
         fcntl.flock(held, fcntl.LOCK_EX)  # released when the file closes
@@ -401,7 +495,19 @@ def test_kernel_compiles_to_a_mosaic_call_for_v5e(case):
     out = json.loads(proc.stdout.strip().splitlines()[-1])
     if "skip" in out:
         pytest.skip(f"no described v5e here: {out['skip']}")
+    return out
+
+
+@pytest.mark.parametrize("case", sorted(set(CASES) - set(XLA_CASES)))
+def test_kernel_compiles_to_a_mosaic_call_for_v5e(case):
+    out = _compiled_in_a_child(case)
     assert out == {"custom_call": True}, out
+
+
+@pytest.mark.parametrize("case", sorted(XLA_CASES))
+def test_program_compiles_for_v5e_and_passes_its_check(case):
+    out = _compiled_in_a_child(case)
+    assert out == {"custom_call": False}, out
 
 
 if __name__ == "__main__":

@@ -4004,8 +4004,8 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--model-config", default=None, metavar="FILE.json",
                    help="serve a published architecture instead of "
                         "training the Tiny-Transformer: a config.json in "
-                        "the source's own keys (model_type: minicpm_sala "
-                        "or phi4flash), "
+                        "the source's own keys (model_type: minicpm_sala, "
+                        "phi4flash or kimi_k2), "
                         "seeded random weights in its param_dtype, served "
                         "by --serve-generate on the continuous scheduler "
                         "(docs/MODEL_CONFIG.md)")

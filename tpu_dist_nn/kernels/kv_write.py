@@ -108,3 +108,62 @@ def write_rows(k_cache: jnp.ndarray, v_cache: jnp.ndarray,
         k_cache, v_cache,
         tpu=call(False), default=call(True),
     )
+
+
+def _kernel_one(pos_ref, active_ref, new_ref, old_ref, out_ref):
+    s = pl.program_id(1)
+    block = old_ref.shape  # (1, 1, H, Dh, lanes)
+    lanes, group = block[-1], new_ref.shape[-1]
+    hit = (
+        lax.broadcasted_iota(jnp.int32, block, 4) == pos_ref[s] % lanes
+    ) & (active_ref[s] != 0)
+    mine = lax.broadcasted_iota(jnp.int32, new_ref.shape, 3) == s % group
+    row = jnp.sum(
+        jnp.where(mine, new_ref[...].astype(jnp.float32), 0.0),
+        axis=3, keepdims=True,
+    )[None]
+    out_ref[...] = jnp.where(
+        hit, row, old_ref[...].astype(jnp.float32)
+    ).astype(out_ref.dtype)
+
+
+def write_row(cache: jnp.ndarray, new: jnp.ndarray, pos: jnp.ndarray,
+              active: jnp.ndarray):
+    """:func:`write_rows` for a model that caches ONE array a layer (a
+    latent row, not a key and a value): ``cache[:, s, :, :, pos[s]] =
+    new[:, s]`` for the active ones of the first ``S`` slots.  ``cache
+    (L, >=S, H, Dh, M)``, ``new (L, S, H, Dh)``; the same visit of one
+    128-lane block a layer and slot, the same aliasing."""
+    L, _, H, Dh, M = cache.shape
+    S = new.shape[1]
+    lanes, group = min(M, _LANES), min(S, _LANES)
+    pos = jnp.clip(pos.astype(jnp.int32), 0, M - 1)
+    cache_spec = pl.BlockSpec(
+        (1, 1, H, Dh, lanes),
+        lambda layer, s, pos_ref, active_ref: (
+            layer, s, 0, 0, pos_ref[s] // lanes),
+    )
+    new_spec = pl.BlockSpec(
+        (1, H, Dh, group),
+        lambda layer, s, pos_ref, active_ref: (layer, 0, 0, s // group),
+    )
+
+    def call(interpret: bool):
+        return pl.pallas_call(
+            _kernel_one,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(L, S),
+                in_specs=[new_spec, cache_spec],
+                out_specs=cache_spec,
+            ),
+            out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
+            input_output_aliases={3: 0},
+            interpret=interpret,
+            name="kv_write_row",
+        )
+
+    return lax.platform_dependent(
+        pos, active.astype(jnp.int32), new.transpose(0, 2, 3, 1), cache,
+        tpu=call(False), default=call(True),
+    )

@@ -946,9 +946,13 @@ def _load_other_family(path: str, d: dict):
         from tpu_dist_nn.models.sambay import SambaYConfig
 
         return SambaYConfig.from_dict(d)
+    if d.get("model_type") == "kimi_k2":
+        from tpu_dist_nn.models.mla_moe import MlaMoeConfig
+
+        return MlaMoeConfig.from_dict(d)
     raise ValueError(
         f"{path}: model_type {d.get('model_type')!r} has no loader "
-        "(known: 'minicpm_sala', 'phi4flash')")
+        "(known: 'minicpm_sala', 'phi4flash', 'kimi_k2')")
 
 
 def init_model_config(key: jax.Array, cfg):

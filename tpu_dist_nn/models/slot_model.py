@@ -3,14 +3,18 @@
 The scheduler (:mod:`tpu_dist_nn.serving.continuous`) builds its three
 programs — chunk prefill, slot copy, decode step — from four functions
 and never learns what a layer is.  A model's config hands them over
-with ``cfg.slot_model()``; the three implementers are
+with ``cfg.slot_model()``; the four implementers are
 :class:`~tpu_dist_nn.models.transformer.TransformerConfig` (GPT-2's
 block, one ``{k, v}`` cache: :mod:`.generate`),
 :class:`~tpu_dist_nn.models.sala.SalaConfig` (two kinds of layer, K/V
 rows plus compressed keys plus recurrent state: :mod:`.sala`) and
 :class:`~tpu_dist_nn.models.sambay.SambaYConfig` (five kinds of layer,
 one K/V cache plus window rings plus convolution inputs and scan state,
-and a fourth program: a chunk that ends without logits: :mod:`.sambay`).
+and a fourth program: a chunk that ends without logits: :mod:`.sambay`)
+and :class:`~tpu_dist_nn.models.mla_moe.MlaMoeConfig` (latent attention
+over one compressed row a position, routed experts of which the chip
+holds a share, and routing counts that accumulate on the device:
+:mod:`.mla_moe`).
 docs/MODEL_CONFIG.md says what the prefix pool and preemption require
 of an implementer.
 """
@@ -73,3 +77,12 @@ class SlotModel:
     # the step reads the whole extent, and the scheduler counts nothing.
     # It reads the shapes and `pos`, as the model's kernel does.
     step_kv_tiles: Callable = _whole_extent
+    # (cache) -> {name: small device array}, or None: counts the model's
+    # programs accumulate on the device, inside the cache they are handed
+    # and hand back (routing load is known nowhere else).  Every value
+    # is a running int32 total, a scalar or a vector.  The scheduler
+    # asks once at construction whether there are any, fetches them
+    # together every few dozen launches and when it runs dry (never a
+    # step), and keeps the growth as `routing_totals`.  None: nothing
+    # is fetched.
+    routing_counts: Callable | None = None

@@ -231,8 +231,35 @@ class RuntimeSampler:
         self._g_gen_cache = reg.gauge(
             "tdn_gen_cache_bytes",
             "bytes of the slot cache by kind of state: kv rows, "
-            "compressed keys, window rings, recurrent state",
+            "compressed keys, window rings, recurrent state, latent rows",
             labels=("kind",),
+        )
+        # Routing load, counted on the device by a model with routed
+        # experts (SlotModel.routing_counts) and fetched by the
+        # scheduler every few dozen iterations: its `routing_totals`.
+        self._c_gen_routing = {
+            "routed_pairs": reg.counter(
+                "tdn_gen_routed_pairs_total",
+                "(token, expert) pairs the router chose, every expert "
+                "layer of every position prefilled or decoded: experts "
+                "per token x tokens x expert layers, held here or not",
+            ),
+            "expert_touched": reg.counter(
+                "tdn_gen_expert_touched_total",
+                "(decode step, expert layer, held expert) triples in "
+                "which the expert got at least one pair",
+            ),
+            "expert_visits": reg.counter(
+                "tdn_gen_expert_visits_total",
+                "(decode step, expert layer, held expert) triples, "
+                "touched or not",
+            ),
+        }
+        self._c_gen_expert_pairs = reg.counter(
+            "tdn_gen_expert_pairs_total",
+            "(token, expert) pairs computed here, by expert held, summed "
+            "over expert layers, prefill and decode",
+            labels=("expert",),
         )
         # Router replica pools (serving/pool.py): the fleet-state
         # gauges nobody increments — per-replica outstanding requests
@@ -463,6 +490,21 @@ class RuntimeSampler:
                 self._tick_gen_loop(s.loop_totals(), seen)
                 for field, counter in self._c_gen_counts.items():
                     total = int(getattr(s, field, 0))
+                    if total > seen.get(field, 0):
+                        counter.inc(total - seen.get(field, 0))
+                        seen[field] = total
+                routing = getattr(s, "routing_totals", None)
+                if not routing:
+                    continue
+                ticks = [(name, counter, routing.get(name, 0))
+                         for name, counter in self._c_gen_routing.items()]
+                ticks += [
+                    (f"expert_pairs.{e}",
+                     self._c_gen_expert_pairs.labels(expert=str(e)), total)
+                    for e, total in zip(s.experts_held,
+                                        routing.get("expert_pairs", ()))]
+                for field, counter, total in ticks:
+                    total = int(total)
                     if total > seen.get(field, 0):
                         counter.inc(total - seen.get(field, 0))
                         seen[field] = total

@@ -314,6 +314,11 @@ def slot_body_kernel(cfg):
     return prefill_body
 
 
+# Loop iterations between two fetches of a model's device counts
+# (`SlotModel.routing_counts`): a few dozen bytes every second or two.
+_ROUTING_EVERY = 64
+
+
 class _LoopClock:
     """Where the scheduler loop's time goes, always on: the loop thread
     calls :meth:`mark` at each phase boundary, which closes the phase
@@ -620,6 +625,7 @@ class ContinuousScheduler:
         self._tier_at_fill, self._sparse_positions = False, lambda pos: 0
         self._attend_kernel = lambda size: False
         self._kv_tiles = None
+        self._routing = None
         self._prefill_body = None
         self.cache_bytes: dict = {}
         if prefill_fn is not None or step_fn is not None:
@@ -762,6 +768,19 @@ class ContinuousScheduler:
         # (`SlotModel.step_kv_tiles`, asked once); else both stay 0.
         self.step_kv_tiles_visited_total = 0
         self.step_kv_tiles_skipped_total = 0
+        # Counts the model's programs accumulate on the device, inside
+        # the cache (`SlotModel.routing_counts`, asked once; routing
+        # load is known nowhere else): their growth by name since the
+        # warm-up, read every `_ROUTING_EVERY` iterations and when the
+        # loop runs dry, never a step.  Empty for a model with none.
+        self.routing_totals: dict = {}
+        if self._routing is not None:
+            import jax
+
+            self.routing_totals = {
+                name: np.zeros(v.shape, np.int64) for name, v in
+                jax.eval_shape(self._routing, self._cache).items()}
+        self.experts_held = tuple(getattr(cfg, "experts_held", ()))
         self.ttft_recent: collections.deque[float] = collections.deque(
             maxlen=1024
         )
@@ -799,6 +818,8 @@ class ContinuousScheduler:
             cfg, self._S + self._P, M)
         self._cache = self._make_cache()
         self.cache_bytes = model.cache_bytes(self._cache)
+        self._routing = model.routing_counts
+        self._routing_seen, self._routing_age = None, 0
         # Goodput FLOP model at the kernels' static shapes: the decode
         # step runs the REQUEST region only (S slots of extent M; the
         # pool blocks behind them are not read), so the model's extent
@@ -886,7 +907,32 @@ class ContinuousScheduler:
         np.asarray(toks)  # force the compile + execution to finish
         self._cache = cache
         warmed.append("decode_step_slots")
+        self._read_routing(book=False)  # what the warm-up routed is no load
         return warmed
+
+    def _read_routing(self, book: bool = True) -> None:
+        """Fetch the model's device counts out of the cache (the loop
+        thread, with no launch in flight) and book their growth since
+        the last fetch; ``book=False`` only moves the mark.  The
+        device's totals are int32 and wrap; their growth between two
+        fetches does not."""
+        if self._routing is None:
+            return
+        import jax
+
+        self._routing_age = 0
+        try:
+            now = {name: np.asarray(v, np.int64) for name, v in
+                   jax.device_get(self._routing(self._cache)).items()}
+        except Exception:  # noqa: BLE001 — a dead cache has no counts
+            self._routing_seen = None
+            return
+        seen, self._routing_seen = self._routing_seen, now
+        if not book:
+            return
+        for name, v in now.items():
+            grown = (v - (0 if seen is None else seen[name])) & 0xFFFFFFFF
+            self.routing_totals[name] = self.routing_totals[name] + grown
 
     # ------------------------------------------------------------ submit
 
@@ -1247,6 +1293,7 @@ class ContinuousScheduler:
                 self._cache = self._make_cache()
             except Exception:  # noqa: BLE001 — backend fully down
                 log.exception("cache rebuild after device fault failed")
+            self._routing_seen = None  # the new cache counts from zero
         if self._pool is not None:
             self._pool.clear()
 
@@ -2030,6 +2077,11 @@ class ContinuousScheduler:
             ahead = self._active.any() and self._launch_step()
             self._land(keep=1 if ahead else 0)
             self._await_device()
+            if self._routing is not None:
+                self._routing_age += 1
+                if self._routing_age >= _ROUTING_EVERY \
+                        or not self._resident():
+                    self._read_routing()
 
     # ------------------------------------------------------------ close
 
