@@ -185,6 +185,10 @@ QUICK_TESTS = {
         "test_kernel_matches_the_xla_path[tile-edges-bfloat16--0.4]",
         "test_a_kernel_that_reads_one_tile_too_few_is_caught",
         "test_the_shapes_alone_decide[cell-step]"],
+    # PR 34: the chunk's expanded latent attention kernel against the loop.
+    "test_expand_attend": [
+        "test_kernel_matches_the_loop[37-bfloat16]",
+        "test_the_shapes_alone_decide_which_path_runs[rehearsal]"],
     "test_multihost_real": ["test_two_process_collectives"],
     "test_native_codec": ["test_examples_roundtrip_and_parity",
                           "test_fuzz_model_roundtrip_native_vs_python"],

@@ -21,6 +21,7 @@ such entries back without a chip.
 import fcntl
 import importlib
 import json
+import math
 import os
 import subprocess
 import sys
@@ -357,18 +358,31 @@ def _mla_moe_chunk(S, jnp):
     slot's rows are written in place, the ragged expert product slices
     one expert's matrices inside each tile's products and never copies a
     layer of them (12 x 7168 x 4096: 0.7 GB a layer when it did), and
-    no key or value of a whole extent is expanded (a tile of 512 is)."""
+    the expanded attention is the Mosaic call `expand_attend`: no key,
+    value or score of a key tile is an XLA value (float32 scores `(64,
+    1024, 512)` were, 134 MB a tile)."""
     from tools.aot_step_ops import big_ops
 
     cfg, slots, params, cache, key, chunk, _ = _mla_moe_programs(S, jnp)
 
     def check(text):
         experts = cfg.n_held * cfg.hidden_size * cfg.moe_intermediate_size
-        found = big_ops(text, experts)["ops"]
-        return [f"{op['name']} ({op['opcode']}) {op['shapes']}"
-                for op in found
-                if not any(dims[:2] == (cfg.n_layers, slots + 2)
-                           for _, dims, _, _ in op["shapes"])]
+        scores = (cfg.n_heads, 1024, 512)
+
+        def unwanted(op):
+            # A key tile's scores, or anything of a layer of experts'
+            # size that is not the cache.
+            dims = [d for _, d, _, _ in op["shapes"]]
+            return any(d[-3:] == scores for d in dims) or (
+                max(n for *_, n in op["shapes"]) >= experts
+                and not any(d[:2] == (cfg.n_layers, slots + 2)
+                            for d in dims))
+
+        return ([] if "expand_attend" in text else [
+            "no expand_attend call: the chunk fell to the XLA loop"]) + [
+            f"{op['name']} ({op['opcode']}) {op['shapes']}"
+            for op in big_ops(text, math.prod(scores))["ops"]
+            if unwanted(op)]
 
     args = (params, cache, S((), jnp.int32), S((1, 1024), jnp.int32),
             S((), jnp.int32), key)
@@ -388,6 +402,21 @@ def _sparse_attend_small_tiles(S, jnp):
         q, k, v, sel, start, 64)), (
         S((128, 2, 16, 128), jnp.bfloat16), rows, rows,
         S((128, 2, 18), jnp.bool_), S((), jnp.int32))
+
+
+def _expand_attend_cell(S, jnp):
+    """The kernel alone at the repository cell's shape and tiling: 64
+    heads in groups of 8, a chunk of 1024 whole in VMEM in query tiles of
+    512, key tiles of 1024 of an extent of 9216."""
+    from tpu_dist_nn.kernels import expand_attend
+
+    shape = (1024, 64, 512, 128, 64, 128, 9216)
+    assert expand_attend.tiles(*shape, jnp.bfloat16) == (8, 512, 1024)
+    C, H, rkv, dn, dr, dv, M = shape
+    bf = jnp.bfloat16
+    return (lambda *a: expand_attend.attend_chunk(*a, 0.1)), (
+        S((C, H, dn), bf), S((C, H, dr), bf), S((1, rkv + dr, M), bf),
+        S((rkv, H, dn), bf), S((rkv, H, dv), bf), S((), jnp.int32))
 
 
 def _decode_attend_one_slot(S, jnp):
@@ -430,13 +459,9 @@ CASES = {
     "sambay_step_g20_d64_w512_m3071_in_place": _sambay_step,
     "decode_attend_one_slot_g20_d64_m3072_f32": _decode_attend_one_slot,
     "mla_moe_step_h64_r576_m9215_in_place": _mla_moe_step,
+    "mla_moe_chunk_c1024_r576_m9215_expand_kernel": _mla_moe_chunk,
+    "expand_attend_c1024_h64_r576_m9216_bf16": _expand_attend_cell,
 }
-# Programs that hold no Mosaic call: compiled the same way, held to
-# their own check alone.
-XLA_CASES = {
-    "mla_moe_chunk_c1024_r576_m9215_no_expert_copy": _mla_moe_chunk,
-}
-CASES.update(XLA_CASES)
 
 
 def _compile(case: str) -> dict:
@@ -498,16 +523,10 @@ def _compiled_in_a_child(case: str) -> dict:
     return out
 
 
-@pytest.mark.parametrize("case", sorted(set(CASES) - set(XLA_CASES)))
+@pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_to_a_mosaic_call_for_v5e(case):
     out = _compiled_in_a_child(case)
     assert out == {"custom_call": True}, out
-
-
-@pytest.mark.parametrize("case", sorted(XLA_CASES))
-def test_program_compiles_for_v5e_and_passes_its_check(case):
-    out = _compiled_in_a_child(case)
-    assert out == {"custom_call": False}, out
 
 
 if __name__ == "__main__":

@@ -50,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from tpu_dist_nn.kernels import expand_attend
 from tpu_dist_nn.kernels.kv_write import write_row
 from tpu_dist_nn.models.sala import _einsum32, _put_slot, _rms, _take_slot
 from tpu_dist_nn.models.slot_model import SlotModel
@@ -182,7 +183,15 @@ class MlaMoeConfig:
             flop_model=MlaMoeFlopModel,
             cache_bytes=cache_bytes,
             routing_counts=self.routing_counts,
+            attend_kernel=self.attend_kernel,
         )
+
+    def attend_kernel(self, size, max_len) -> bool:
+        """Whether a chunk of ``size`` positions into a slot cache made
+        for ``max_len`` runs its expanded attention as the Pallas kernel:
+        the dispatch of :func:`_attend_expanded`, asked from outside the
+        program."""
+        return attend_kernel_tiles(self, int(size), _extent(max_len))
 
     def routing_counts(self, cache: dict) -> dict:
         """The cache's routing counts by name (device values; the
@@ -415,19 +424,42 @@ def _key_tile(M: int) -> int:
     return next(t for t in _KEY_TILES if M % t == 0)
 
 
+def attend_kernel_tiles(cfg: MlaMoeConfig, C: int, M: int) -> bool:
+    """Whether a chunk of ``C`` positions over a slot of ``M`` latent
+    rows runs its expanded attention as the Pallas kernel
+    (:mod:`tpu_dist_nn.kernels.expand_attend`): only the shapes decide."""
+    return expand_attend.tiles(
+        C, cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+        cfg.qk_rope_head_dim, cfg.v_head_dim, M,
+        cfg.param_dtype) is not None
+
+
 @jax.named_scope("mla_moe.attn.expand")
 def _attend_expanded(q_n, q_r, rows, t, blk, cfg):
     """A chunk's attention: queries ``q_n (C, H, d_n)``, ``q_r (C, H,
-    d_r)`` at positions ``t (C,)`` over the slot's latent rows ``(1, r,
-    M)``, which already hold the chunk's own.  A tile of key positions
-    at a time, up to the chunk's last: the tile's rows are expanded to
-    per-head keys and values (``w_kvb``) and attended with a running
-    softmax in float32.  Returns ``(C, H, d_v)`` float32."""
-    C, H = q_n.shape[:2]
-    rkv, M = cfg.kv_lora_rank, rows.shape[-1]
-    KT = _key_tile(M)
+    d_r)`` at positions ``t (C,)`` (consecutive) over the slot's latent
+    rows ``(1, r, M)``, which already hold the chunk's own.  A tile of
+    key positions at a time, up to the chunk's last: the tile's rows
+    are expanded to per-head keys and values (``w_kvb``) and attended
+    with a running softmax in float32.  Returns ``(C, H, d_v)``
+    float32.
+
+    Which path runs is read from the shapes: the Pallas kernel where
+    they tile (a tile's keys, values and scores never leave VMEM), the
+    XLA loop, its oracle, where they do not."""
     wk, wv = _kvb(blk, cfg)
-    scale = cfg.softmax_scale
+    if attend_kernel_tiles(cfg, q_n.shape[0], rows.shape[-1]):
+        return expand_attend.attend_chunk(q_n, q_r, rows, wk, wv, t[0],
+                                          cfg.softmax_scale)
+    return _expanded_loop(q_n, q_r, rows, t, wk, wv, cfg.softmax_scale)
+
+
+def _expanded_loop(q_n, q_r, rows, t, wk, wv, scale):
+    """:func:`_attend_expanded` as a ``fori_loop`` over the visible key
+    tiles, each tile's scores ``(H, C, KT)`` float32 an XLA value."""
+    C, H = q_n.shape[:2]
+    rkv, M = wk.shape[0], rows.shape[-1]
+    KT = _key_tile(M)
 
     def tile(j, carry):
         m, l, acc = carry
@@ -450,7 +482,7 @@ def _attend_expanded(q_n, q_r, rows, t, blk, cfg):
 
     init = (jnp.full((H, C), -jnp.inf, jnp.float32),
             jnp.zeros((H, C), jnp.float32),
-            jnp.zeros((H, C, cfg.v_head_dim), jnp.float32))
+            jnp.zeros((H, C, wv.shape[-1]), jnp.float32))
     _, l, acc = lax.fori_loop(0, t[-1] // KT + 1, tile, init)
     return (acc / l[..., None]).transpose(1, 0, 2)
 

@@ -199,8 +199,9 @@ class RuntimeSampler:
             "attend_kernel_chunks_total": reg.counter(
                 "tdn_gen_attend_kernel_chunks_total",
                 "prefill chunk launches whose shapes tile for the model's "
-                "Pallas attention kernel (kernels/sparse_attend.py); the "
-                "rest ran its XLA loop, or the model has none",
+                "Pallas attention kernel (kernels/sparse_attend.py, "
+                "kernels/expand_attend.py); the rest ran its XLA loop, or "
+                "the model has none",
             ),
             # The loop runs one launch ahead (serving/continuous.py).
             "overlapped_total": reg.counter(
