@@ -4,6 +4,10 @@ process-global ring of iteration records, queue wait / prefill wait /
 stream-out lag as cumulative counters, the `decode` span's ride
 attributes in place of per-step `decode.step` spans, the
 `tdn_gen_loop_*` families, and the `named_scope`s of the step programs.
+Since ISSUE 35 also: the loop thread's CPU time inside the publish, the
+process's,
+the time the device was starved, which iterations a `jax.profiler`
+capture holds, and the scheduler's own counters as ring columns.
 
 Driven with stub kernels on the CPU: what is asserted is where time is
 booked, never how much a device would take.
@@ -31,6 +35,10 @@ from tpu_dist_nn.serving.continuous import ContinuousScheduler
 
 T, N = 8, 10
 CUMULATIVE = ITER_FIELDS[ITER_FIELDS.index("active_slots") + 1:]
+# No capture runs, no stream is cancelled, and the stub kernels are no
+# model with an attention kernel or a step that skips K/V tiles.
+NOT_IN_THIS_RUN = ("captured", "discarded_lanes", "attend_kernel_chunks",
+                   "kv_tiles_visited", "kv_tiles_skipped")
 
 
 def _fake_sched(step_cost=0.0, readings=None, **kw):
@@ -159,7 +167,8 @@ def test_every_total_is_monotone(sampled_run, field):
     for series in (samples, records):
         values = [s[field] for s in series]
         assert all(b >= a for a, b in zip(values, values[1:])), field
-    if field not in ("stream_lag_ns", "stream_frames"):
+    if field not in ("stream_lag_ns", "stream_frames", "stream_send_ns",
+                     "stream_sends", *NOT_IN_THIS_RUN):
         assert samples[-1][field] > 0, field
 
 
@@ -289,6 +298,240 @@ def test_stream_consumer_delay_lands_in_stream_lag(consumer_sleep, lo_ms,
     assert lo_ms <= mean_ms < hi_ms
 
 
+# ------------------------------------- CPU by part, starved, captured
+
+
+def _burn(seconds):
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        pass
+
+
+@pytest.mark.parametrize("where", ["publish", "dispatch", None])
+def test_publish_cpu_is_the_publishs_own_and_inside_the_threads_total(
+        where, monkeypatch):
+    """A publish that spins for 2 ms is CPU time of `cpu.publish`; a
+    step kernel that spins inside the call into it, or sleeps there, is
+    none of it. Either way the publish's CPU time stays inside its
+    phase's wall time and the thread's own total, and the process's
+    total holds the thread's."""
+    from tpu_dist_nn.serving import continuous
+
+    monkeypatch.setattr(continuous, "_PROC_CPU_EVERY_NS", 0)
+
+    def fake_prefill(params, cache, slot, tokens, start, key):
+        return np.int32(1), cache
+
+    def fake_step(params, cache, pos, active, tok, key):
+        _burn(0.002) if where == "dispatch" else time.sleep(0.002)
+        return np.asarray(tok) + 1, cache
+
+    sched = ContinuousScheduler(None, None, prefill_fn=fake_prefill,
+                                step_fn=fake_step, slots=2, prompt_len=T,
+                                max_new_tokens=N)
+    publish, published = sched._publish, []
+
+    def slow_publish(occ, first=False):
+        if not first:  # a first token leaves in `prefill.post`
+            published.append(_burn(0.002))
+        publish(occ, first=first)
+
+    if where == "publish":
+        sched._publish = slow_publish
+    sched.submit(_prompts(4))
+    sched.close()
+    totals = sched.loop_totals()
+    cpu, steps = totals["cpu.publish"], sched.steps_total
+    assert 0 <= cpu <= totals["step.publish"] * 1.05 + 2e5
+    assert cpu <= totals["cpu_ns"]
+    assert totals["step.dispatch"] >= 0.002e9 * steps
+    if where == "publish":
+        assert len(published) >= 4 * (N - 1)
+        assert cpu >= 0.0015e9 * len(published)
+    else:
+        assert cpu <= 0.3 * totals["step.dispatch"]
+    if where == "dispatch":
+        assert totals["cpu_ns"] - cpu >= 0.0015e9 * steps
+    # The process's CPU time holds the loop thread's.
+    assert totals["proc_cpu_ns"] >= totals["cpu_ns"] > 0
+    records = _mine(sched)
+    assert all(r["proc_cpu_ns"] >= r["cpu_ns"] for r in records)
+
+
+def test_the_process_clock_is_read_every_tenth_of_a_second():
+    sched = _fake_sched(step_cost=0.002, slots=2, max_new_tokens=64)
+    sched.submit(_prompts(4))
+    sched.close()
+    records = _mine(sched)
+    proc = [r["proc_cpu_ns"] for r in records]
+    assert proc == sorted(proc) and proc[0] > 0
+    lasted = records[-1]["t_end"] - records[0]["t_end"]
+    assert lasted > 0.25 and len(records) > 100
+    assert 2 <= len(set(proc)) <= lasted / 0.1 + 2
+
+
+class _OnDevice:
+    """A kernel's result still on the device: waiting for it and
+    reading it are two things, as for a jax.Array."""
+
+    def __init__(self, value, log):
+        self.value, self.log = value, log
+
+    def block_until_ready(self):
+        time.sleep(0.002)
+        self.log.append(("ready", time.monotonic_ns()))
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.value, dtype)
+
+    def __int__(self):
+        return int(self.value)
+
+
+def _device_sched(log, **kw):
+    """Stub kernels whose results have to be waited for; `log` takes
+    ("ready", t) when a wait ends and ("fed", t) as a dispatch is about
+    to return, 3 ms after it was called."""
+    def fake_prefill(params, cache, slot, tokens, start, key):
+        time.sleep(0.003)
+        log.append(("fed", time.monotonic_ns()))
+        return _OnDevice(np.int32(1), log), cache
+
+    def fake_step(params, cache, pos, active, tok, key):
+        time.sleep(0.003)
+        log.append(("fed", time.monotonic_ns()))
+        return _OnDevice(np.asarray(tok) + 1, log), cache
+
+    kw.setdefault("slots", 2)
+    return ContinuousScheduler(None, None, prefill_fn=fake_prefill,
+                               step_fn=fake_step, prompt_len=T,
+                               max_new_tokens=N, **kw)
+
+
+def test_starved_time_runs_from_the_waits_end_to_the_next_dispatch():
+    log = []
+    sched = _device_sched(log)
+    sched.submit(_prompts(3))
+    sched.close()
+    totals = sched.loop_totals()
+    # From each wait's end to the first dispatch that returned after it
+    # (an iteration's chunk where it has one, else its step).
+    gaps, ready = [], None
+    for kind, t in log:
+        if kind == "ready":
+            ready = t
+        elif ready is not None:
+            gaps.append(t - ready)
+            ready = None
+    assert len(gaps) >= N
+    # The loop's own readings lie just outside the stub's: it notes the
+    # wait's end after the stub, the dispatch's return after the stub.
+    assert sum(gaps) <= totals["starved_ns"] <= sum(gaps) * 1.3 + 2e7
+    assert totals["starved_ns"] >= 0.003e9 * len(gaps)
+    # It is time of reap, admit, bind and the dispatches.
+    assert totals["starved_ns"] <= sum(
+        totals[p] for p in ("reap", "admit", "bind", "prefill.dispatch",
+                            "step.dispatch")
+    ) + 1e6 * len(gaps)
+    records = _mine(sched)
+    assert [r["starved_ns"] for r in records] == sorted(
+        r["starved_ns"] for r in records)
+
+
+def test_starved_time_leaves_out_the_loops_sleep_for_want_of_work():
+    sched = _fake_sched(slots=2)
+    sched.submit(_prompts(1))
+    before = sched.loop_totals()
+    time.sleep(0.3)  # nothing to do: the loop sleeps in `idle`
+    sched.submit(_prompts(1, seed=1))
+    sched.close()
+    totals = sched.loop_totals()
+    assert totals["idle"] - before["idle"] >= 0.25e9
+    assert 0 < totals["starved_ns"] < 0.1e9
+
+
+def test_captured_counts_the_iterations_a_profiler_capture_holds(tmp_path):
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0  # a short stop_trace
+    sched = _fake_sched(step_cost=0.001, slots=2)
+    made, real = [], sched._clock._host_span
+    sched._clock._host_span = lambda name: (made.append(name), real(name))[1]
+    try:
+        sched.submit(_prompts(2))
+        assert not made  # no annotation while nobody records it
+        t0 = time.monotonic()
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            t1 = time.monotonic()
+            sched.submit(_prompts(2, seed=1))
+            t2 = time.monotonic()
+        finally:
+            jax.profiler.stop_trace()
+        t3 = time.monotonic()
+        time.sleep(0.05)  # the loop's next mark ends the last one
+        made_by_stop = len(made)
+        sched.submit(_prompts(2, seed=2))
+    finally:
+        sched.close()
+    records = _mine(sched)
+    zero = dict.fromkeys(ITER_FIELDS, 0)
+    grew = [(b["t_end"], b["captured"] - a["captured"])
+            for a, b in zip([zero] + records, records)]
+    assert {g for _, g in grew} == {0, 1}  # by one a recorded iteration
+    assert all(g == 0 for t, g in grew if t < t0 or t > t3)
+    inside = [g for t, g in grew if t1 <= t <= t2]
+    assert len(inside) >= N and all(g == 1 for g in inside)
+    assert records[-1]["captured"] == sum(g for _, g in grew) >= len(inside)
+    # The phases under the capture were annotated, each on entry.
+    assert {"tdn.gen.step.dispatch", "tdn.gen.step.publish",
+            "tdn.gen.reap"} <= set(made)
+    assert len(made) >= 6 * len(inside)
+    assert len(made) == made_by_stop  # and none after it
+
+
+def test_the_schedulers_own_counters_ride_the_ring():
+    """`discarded_lanes`, `slot_steps`, `attend_kernel_chunks`,
+    `prefill_chunks` and the two `kv_tiles_*` columns are the
+    scheduler's counters as of each iteration's end."""
+    sched = _fake_sched(step_cost=0.002, slots=2, max_new_tokens=64)
+    stream = sched.submit_stream(_prompts(1))
+    got = []
+    while len(got) < 5:
+        got += stream.next_event(5.0)[1]
+    stream.cancel()  # found one launch late: a lane computed for nobody
+    sched.submit(_prompts(2, seed=1))
+    sched.close()
+    last = _mine(sched)[-1]
+    assert last["discarded_lanes"] == sched.discarded_lanes_total >= 1
+    assert last["slot_steps"] == sched.slot_steps_total > 0
+    assert last["prefill_chunks"] == sched.prefill_chunks_total == 3
+    assert last["attend_kernel_chunks"] == sched.attend_kernel_chunks_total
+    assert last["kv_tiles_visited"] == sched.step_kv_tiles_visited_total
+    assert last["kv_tiles_skipped"] == sched.step_kv_tiles_skipped_total
+
+
+def test_a_handlers_time_away_with_a_frame_lands_in_stream_send():
+    sched = _fake_sched(step_cost=0.01, slots=1, max_new_tokens=24)
+    stream = sched.submit_stream(_prompts(1))
+    got = []
+    while True:
+        kind, data = stream.next_event(10.0)
+        if kind == "end":
+            break
+        got += data
+        time.sleep(0.02)  # the handler's send
+    sched.close()
+    totals = sched.loop_totals()
+    assert len(got) == 24
+    # Sends that ended after the last publish are never folded in.
+    assert 0 < totals["stream_sends"] < 24
+    mean_ms = totals["stream_send_ns"] / totals["stream_sends"] / 1e6
+    assert 20.0 <= mean_ms < 100.0
+
+
 # -------------------------------------------------------------- spans
 
 
@@ -378,13 +621,29 @@ def scraped():
     ("tdn_gen_prefill_wait_requests_total", "first_tokens"),
     ("tdn_gen_stream_lag_seconds_total", "stream_lag_ns"),
     ("tdn_gen_stream_lag_frames_total", "stream_frames"),
+    ("tdn_gen_loop_publish_cpu_seconds_total", "cpu.publish"),
+    ("tdn_gen_process_cpu_seconds_total", "proc_cpu_ns"),
+    ("tdn_gen_stream_send_seconds_total", "stream_send_ns"),
+    ("tdn_gen_stream_send_frames_total", "stream_sends"),
+    ("tdn_gen_device_starved_seconds_total", "starved_ns"),
 ])
 def test_loop_totals_are_scraped_as_counters(scraped, family, field):
     once, twice, totals = scraped
     value = once[family][()]
     assert value > 0 and twice[family][()] == value
-    scale = 1e9 if field.endswith("_ns") else 1
+    scale = 1e9 if field.endswith("_ns") or field == "cpu.publish" else 1
     assert value <= totals[field] / scale + 1e-9
+
+
+def test_every_cumulative_column_but_the_readers_aid_has_a_counter():
+    """`captured` is for a reader of the ring alone; the scheduler's own
+    counters keep the `tdn_gen_*` families they had."""
+    sampler = RuntimeSampler(registry=Registry())
+    own = {"discarded_lanes", "slot_steps", "attend_kernel_chunks",
+           "prefill_chunks", "kv_tiles_visited", "kv_tiles_skipped",
+           "prefill_tokens", "prefill_starts", "steps_ahead"}
+    assert set(CUMULATIVE) - set(LOOP_PHASES) - set(sampler._c_gen_loop) \
+        - own == {"captured"}
 
 
 @pytest.mark.parametrize("phase", [p for p in LOOP_PHASES if p != "idle"])

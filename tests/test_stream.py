@@ -144,6 +144,34 @@ def test_publish_without_notify_leaves_the_consumer_asleep_until_woken():
     assert s.next_event(1.0) == ("tokens", [3])
 
 
+def test_send_time_is_one_call_handing_out_tokens_to_the_next_one_entered():
+    """A consumer is away with a frame from `next_event` returning it a
+    batch until it calls `next_event` again, whatever that call then
+    finds; the sums reach the sink at the stream's next `publish`, as
+    the stream-out lag's do."""
+    class Sink:
+        stream_lag_ns = stream_frames = stream_send_ns = stream_sends = 0
+
+    sink = Sink()
+    s = TokenStream(lag_sink=sink)
+    assert s.publish([1])
+    assert s.next_event(1.0) == ("tokens", [1])
+    time.sleep(0.03)  # away with the frame
+    assert s.next_event(0.01) is None  # back: that send is over
+    time.sleep(0.03)  # waiting for a token is no send
+    assert s.next_event(0.01) is None
+    assert (sink.stream_sends, sink.stream_send_ns) == (0, 0)
+    assert s.publish([1, 2])  # the loop's next publish moves the sums
+    assert sink.stream_sends == 1 == sink.stream_frames
+    assert 0.03e9 <= sink.stream_send_ns < 0.06e9
+    assert s.next_event(1.0) == ("tokens", [2])
+    time.sleep(0.02)
+    s.finish("max_tokens")
+    assert s.next_event(1.0)[0] == "end"  # the last send ends here,
+    assert s.publish([1, 2, 3])           # after the stream's last publish:
+    assert sink.stream_sends == 1         # never folded in
+
+
 # ------------------------------------------------------ wire parity
 
 

@@ -53,6 +53,38 @@ def test_gaps_are_put_down_to_the_phases_that_cover_them():
     assert max(got["by_phase"], key=got["by_phase"].get) == "step.publish"
 
 
+def test_a_gap_says_how_long_a_handler_was_sending_under_it():
+    """`tdn.stream.send` spans of three handler threads: the first gap
+    (100 to 130 ms) has one from under the step to 110, two that overlap
+    (105 to 112, 108 to 120) and one that it holds whole; overlapping
+    sends count once. The second gap (132 to 135) lies wholly under one
+    send, and a gap's sends are no phase of the loop."""
+    planes = _planes()
+    planes += [
+        ("/host:CPU", [("handler-1", [("tdn.stream.send", 90 * MS, 20 * MS),
+                                      ("tdn.stream.send", 131 * MS, 9 * MS)]),
+                       ("handler-2", [("tdn.stream.send", 105 * MS, 7 * MS),
+                                      ("tdn.stream.send", 125 * MS, 1 * MS)]),
+                       ("handler-3", [("tdn.stream.send", 108 * MS, 12 * MS),
+                                      ("decode", 100 * MS, 30 * MS)])])]
+    got = trace_gaps.attribute_gaps(planes)
+    first = got["by_gap"]["jit_step->jit_prefill_chunk"]
+    assert first["under_send_s"] == pytest.approx(0.020 + 0.001)
+    second = got["by_gap"]["jit_prefill_chunk->jit_step"]
+    assert second["under_send_s"] == pytest.approx(0.003)
+    assert got["under_send_s"] == pytest.approx(0.024)
+    assert got["attributed_s"] == pytest.approx(0.033)  # as without them
+    assert "stream.send" not in got["by_phase"]
+    assert set(first) - set(got["by_phase"]) == {
+        "gaps", "idle_s", "under_send_s"}
+
+
+def test_without_a_send_span_no_gap_is_under_one():
+    got = trace_gaps.attribute_gaps(_planes())
+    assert got["under_send_s"] == 0
+    assert all(g["under_send_s"] == 0 for g in got["by_gap"].values())
+
+
 def test_a_gap_no_span_covers_stays_unattributed():
     planes = _planes()
     planes[1] = ("/host:CPU", [("python3", [])])

@@ -11,7 +11,10 @@ tool reads one capture and prints two tables:
 * every second in which the device ran no program, between two launches,
   put down to the loop phase(s) that cover it (a gap spans the tail of
   the fetch that waited for the earlier program, the host phases, and
-  the head of the dispatch of the later one);
+  the head of the dispatch of the later one), and the part of it during
+  which at least one handler thread was sending a frame
+  (`tdn.stream.send`, serving/server.py: handlers busy on the loop's
+  interpreter while the device waits);
 * device seconds by `jax.named_scope` of the step programs
   (models/generate.py: `kv.write`, `attn.scores`, ...), from the name
   the compiler kept for each operation.
@@ -33,6 +36,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 SPAN_PREFIX = "tdn.gen."
+SEND_SPAN = "tdn.stream.send"
 # The scopes models/generate.py and the scheduler's kernels name; an
 # operation belongs to the innermost one on its path.
 SCOPES = ("params.cast", "embed", "kv.write", "attn.scores", "attn.softmax",
@@ -45,10 +49,13 @@ def _program(event_name: str) -> str:
 
 def attribute_gaps(planes) -> dict:
     """planes as `xplane.read_planes` gives them.  Returns
-    {"idle_s", "attributed_s", "by_phase": {phase: s},
-     "by_gap": {"<program>-><program>": {"gaps", "idle_s", phase: s}}}
-    over the first device plane that holds programs."""
-    modules, spans = [], []
+    {"idle_s", "attributed_s", "under_send_s", "by_phase": {phase: s},
+     "by_gap": {"<program>-><program>": {"gaps", "idle_s",
+                                         "under_send_s", phase: s}}}
+    over the first device plane that holds programs.  `under_send_s` is
+    the part of the idle time during which at least one
+    `tdn.stream.send` span was open (overlapping sends count once)."""
+    modules, spans, sends = [], [], []
     for plane_name, lines in planes:
         device = plane_name.startswith("/device:")
         for line_name, events in lines:
@@ -57,18 +64,35 @@ def attribute_gaps(planes) -> dict:
             elif not device:
                 spans += [(s, s + d, n[len(SPAN_PREFIX):])
                           for n, s, d in events if n.startswith(SPAN_PREFIX)]
+                sends += [(s, s + d) for n, s, d in events if n == SEND_SPAN]
     spans.sort()
+    # The sends of every handler thread as one set of disjoint intervals.
+    busy: list[list] = []
+    for s, e in sorted(sends):
+        if busy and s <= busy[-1][1]:
+            busy[-1][1] = max(busy[-1][1], e)
+        else:
+            busy.append([s, e])
     by_phase: dict[str, float] = {}
     by_gap: dict[str, dict] = {}
-    idle = attributed = 0.0
-    at = 0  # spans are in time order, and so are the gaps
+    idle = attributed = under_send = 0.0
+    at = at_send = 0  # spans are in time order, and so are the gaps
     for (_, a_end, a), (b_start, _, b) in zip(modules, modules[1:]):
         if b_start <= a_end:
             continue
-        gap = by_gap.setdefault(f"{a}->{b}", {"gaps": 0, "idle_s": 0.0})
+        gap = by_gap.setdefault(
+            f"{a}->{b}", {"gaps": 0, "idle_s": 0.0, "under_send_s": 0.0})
         gap["gaps"] += 1
         gap["idle_s"] += (b_start - a_end) / 1e9
         idle += (b_start - a_end) / 1e9
+        while at_send < len(busy) and busy[at_send][1] <= a_end:
+            at_send += 1
+        k = at_send
+        while k < len(busy) and busy[k][0] < b_start:
+            part = (min(busy[k][1], b_start) - max(busy[k][0], a_end)) / 1e9
+            gap["under_send_s"] += part
+            under_send += part
+            k += 1
         while at < len(spans) and spans[at][1] <= a_end:
             at += 1
         for s, e, phase in spans[at:]:
@@ -81,7 +105,8 @@ def attribute_gaps(planes) -> dict:
             by_phase[phase] = by_phase.get(phase, 0.0) + part
             attributed += part
     return {"idle_s": idle, "attributed_s": attributed,
-            "by_phase": by_phase, "by_gap": by_gap}
+            "under_send_s": under_send, "by_phase": by_phase,
+            "by_gap": by_gap}
 
 
 def scope_of(op_path: str) -> str:
@@ -218,17 +243,21 @@ def main(path: str) -> int:
     idle = got["idle_s"]
     print(f"idle between programs: {idle:.4f} s, "
           f"{100 * got['attributed_s'] / idle if idle else 0:.1f} % of it "
-          "under a tdn.gen.* span")
+          "under a tdn.gen.* span, "
+          f"{100 * got['under_send_s'] / idle if idle else 0:.1f} % while "
+          f"a handler was sending ({SEND_SPAN})")
     for phase, s in sorted(got["by_phase"].items(), key=lambda kv: -kv[1]):
         print(f"  {phase:<18}{s:9.4f} s {100 * s / idle:6.1f} %")
     for label, gap in sorted(got["by_gap"].items(),
                              key=lambda kv: -kv[1]["idle_s"]):
-        phases = {k: v for k, v in gap.items() if k not in ("gaps", "idle_s")}
+        phases = {k: v for k, v in gap.items()
+                  if k not in ("gaps", "idle_s", "under_send_s")}
         top = ", ".join(f"{k} {1e3 * v / gap['gaps']:.2f}" for k, v in
                         sorted(phases.items(), key=lambda kv: -kv[1])[:6])
         print(f"gap {label}: {gap['gaps']} gaps, {gap['idle_s']:.4f} s, "
-              f"{1e3 * gap['idle_s'] / gap['gaps']:.2f} ms each; ms a gap by "
-              f"phase: {top}")
+              f"{1e3 * gap['idle_s'] / gap['gaps']:.2f} ms each, "
+              f"{100 * gap['under_send_s'] / gap['idle_s']:.1f} % under a "
+              f"send; ms a gap by phase: {top}")
     scopes = device_seconds_by_scope(pb)
     for prog, per in sorted(scopes.items()):
         total = sum(per.values())

@@ -251,8 +251,8 @@ class MetricsServer:
     ``GET /profile?window=S&top=N`` serves the per-stage self-time
     breakdown over the same tracer (``tdn profile`` pretty-prints it).
     ``GET /debug/profile?seconds=N`` captures a ``jax.profiler`` device
-    trace for N seconds and returns the TensorBoard-format artifact as
-    one zip body; one capture at a time (409 while busy), 503 with a
+    trace (without its Python tracer) for N seconds and returns the
+    TensorBoard-format artifact as one zip body; one capture at a time (409 while busy), 503 with a
     JSON error where the backend has no profiler.
     """
 
@@ -609,7 +609,15 @@ class MetricsServer:
             try:
                 import jax
 
-                with jax.profiler.trace(tmp):
+                # Without the profiler's Python tracer (JAX's default
+                # has it on): it hooks every call of every thread, and
+                # under it a server's handler threads fall behind and
+                # two thirds of the device's idle share is its own
+                # (PERF.md section 5). The device planes and the
+                # TraceAnnotation spans are the host tracer's, and stay.
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0
+                with jax.profiler.trace(tmp, profiler_options=options):
                     # The capture window: whatever the serving/training
                     # threads dispatch during it lands in the trace.
                     time.sleep(seconds)

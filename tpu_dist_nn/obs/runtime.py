@@ -15,6 +15,7 @@ import logging
 import threading
 
 from tpu_dist_nn.obs.registry import REGISTRY, Registry
+from tpu_dist_nn.obs.trace import LOOP_PHASES
 
 log = logging.getLogger(__name__)
 
@@ -121,8 +122,10 @@ class RuntimeSampler:
         self._c_gen_phase = reg.counter(
             "tdn_gen_loop_seconds_total",
             "seconds the continuous scheduler's loop thread spent in "
-            "each phase of its iteration (idle and the two fetches "
-            "are waits, the rest is host time between launches)",
+            "each phase of its iteration (idle and the two fetches are "
+            "waits; reap, admit, bind and the dispatches run with "
+            "nothing queued on the device; account, post and publish "
+            "behind the step just launched)",
             labels=("phase",),
         )
         # Loop-total field (obs.trace.ITER_FIELDS) -> its counter.
@@ -167,6 +170,41 @@ class RuntimeSampler:
             "stream_frames": reg.counter(
                 "tdn_gen_stream_lag_frames_total",
                 "frames counted in tdn_gen_stream_lag_seconds_total",
+            ),
+            "cpu.publish": reg.counter(
+                "tdn_gen_loop_publish_cpu_seconds_total",
+                "CPU seconds of the loop thread inside step.publish; "
+                "that phase's seconds in tdn_gen_loop_seconds_total "
+                "minus this is time the loop wanted the interpreter "
+                "there and did not have it",
+            ),
+            "proc_cpu_ns": reg.counter(
+                "tdn_gen_process_cpu_seconds_total",
+                "CPU seconds of every thread of the serving process "
+                "(loop, handlers, gRPC's and the runtime's) since the "
+                "scheduler's loop started, read at an iteration's end "
+                "every tenth of a second; over wall time it says how "
+                "near one interpreter is to full",
+            ),
+            "stream_send_ns": reg.counter(
+                "tdn_gen_stream_send_seconds_total",
+                "seconds handler threads were away with a streamed "
+                "frame: from taking it to coming back for the next "
+                "(encoding, gRPC's write, the wait for its completion)",
+            ),
+            "stream_sends": reg.counter(
+                "tdn_gen_stream_send_frames_total",
+                "frames counted in tdn_gen_stream_send_seconds_total",
+            ),
+            "starved_ns": reg.counter(
+                "tdn_gen_device_starved_seconds_total",
+                "seconds nothing was queued on the device and the loop "
+                "knew it: from the end of its wait for the device to "
+                "the return of the next iteration's first dispatch, "
+                "idle for want of work apart; what the loop owns up to of "
+                "the device's idle time, without a capture (it leaves out "
+                "the wake after the wait and counts the whole dispatch "
+                "call, into which the device already runs)",
             ),
         }
         self._gen_loop_seen: list[dict] = []
@@ -342,8 +380,6 @@ class RuntimeSampler:
     def _tick_gen_loop(self, totals: dict, seen: dict) -> None:
         """Advance the tdn_gen_loop_* counters by what one scheduler's
         loop totals (``obs.trace.ITER_FIELDS``) grew since last tick."""
-        from tpu_dist_nn.obs.trace import LOOP_PHASES
-
         for field in (*LOOP_PHASES, *self._c_gen_loop):
             delta = totals[field] - seen.get(field, 0)
             seen[field] = totals[field]
@@ -351,7 +387,7 @@ class RuntimeSampler:
                 continue
             if field in LOOP_PHASES:
                 self._c_gen_phase.labels(phase=field).inc(delta / 1e9)
-            elif field.endswith("_ns"):
+            elif field.endswith("_ns") or field.startswith("cpu."):
                 self._c_gen_loop[field].inc(delta / 1e9)
             else:
                 self._c_gen_loop[field].inc(delta)

@@ -596,6 +596,25 @@ ITER_FIELDS = (
     # unread: the loop runs one launch ahead, so over a busy window
     # this grows by one an iteration.
     "steps_ahead",
+    # The loop thread's CPU time inside `step.publish`, ns: the phase's
+    # wall time minus it is time the loop wanted the interpreter there
+    # and did not have it (`cpu_ns` is the whole iteration's).
+    "cpu.publish",
+    # Every thread of the process, read every tenth of a second.
+    "proc_cpu_ns",
+    # A handler away with a frame: one `next_event` returning tokens to
+    # the next one entered (encode, gRPC's write and its completion).
+    "stream_send_ns", "stream_sends",
+    # Nothing queued on the device and the loop knows it: from the end
+    # of its wait for the device to the return of the next iteration's
+    # first dispatch, `idle` apart.
+    "starved_ns",
+    # Iterations recorded while a `jax.profiler` capture ran: the
+    # records over which it grows are the ones the capture holds.
+    "captured",
+    # The scheduler's own counters, as of the iteration's end.
+    "discarded_lanes", "slot_steps", "attend_kernel_chunks",
+    "prefill_chunks", "kv_tiles_visited", "kv_tiles_skipped",
 )
 
 

@@ -319,73 +319,127 @@ def slot_body_kernel(cfg):
 _ROUTING_EVERY = 64
 
 
+# Nanoseconds between two reads of the process's CPU clock
+# (`proc_cpu_ns`): a level over seconds needs no read an iteration.
+_PROC_CPU_EVERY_NS = 100_000_000
+
+
 class _LoopClock:
     """Where the scheduler loop's time goes, always on: the loop thread
     calls :meth:`mark` at each phase boundary, which closes the phase
     that was running into its cumulative total and opens the next, so
     the phases partition the thread's wall time exactly. Each phase is
     also a ``tdn.gen.<phase>`` annotation on the profiler's clock
-    (``utils.profiling.host_span``), recorded only while a
+    (``utils.profiling.host_span``), made only while a
     ``jax.profiler`` capture runs.
 
-    The thread's own CPU time is kept beside them, read once an
-    iteration (the read is a system call, 6 µs where the chip's host
-    runs it). The thread burns none while it waits (``idle``, the two
-    fetches), so it is the CPU time of the host phases, and their wall
-    time minus it is time the loop wanted to run and could not (the
-    GIL, a lock, a blocking call, descheduled).
+    The thread's own CPU time is kept beside them: whole (``cpu_ns``),
+    read once an iteration at its end as since PR 24, and the publish's
+    share of it (``cpu_publish_ns``), read on entering and on leaving
+    ``step.publish``: three reads an iteration (a system call each,
+    6 µs where the chip's host runs it). The thread burns next to none
+    while it waits (``idle``, the two fetches), so the host phases'
+    wall time minus ``cpu_ns`` is time the loop wanted to run and could
+    not (the GIL, a lock, a blocking call, descheduled), and the
+    publish's wall minus its CPU is how much of that lies there.
+    ``proc_cpu_ns`` is the whole process's, every thread of it, read at
+    an iteration's end once ``_PROC_CPU_EVERY_NS`` have passed since
+    the read before.
+
+    ``starved_ns`` is the device's idle time the loop can own up to:
+    from the end of its wait for the device (:meth:`device_idle`:
+    nothing is queued there and the loop knows it) to the return of the
+    next iteration's first dispatch (:meth:`fed`), ``idle`` apart.
+    ``captured`` counts the iterations recorded while a
+    ``jax.profiler`` capture ran.
 
     One writer, no lock: every field is written by the loop thread
-    alone and read as plain ints by anyone. At the end of an iteration
-    that launched something the totals go to ``obs.trace.ITERATIONS``
-    as one record (``obs.trace.ITER_FIELDS``).
+    alone and read as plain ints by anyone (the ``stream_*`` sums are
+    moved in by the loop at a publish: ``serving/stream.py``). At the
+    end of an iteration that launched something the totals go to
+    ``obs.trace.ITERATIONS`` as one record (``obs.trace.ITER_FIELDS``),
+    with ``counts()``, the scheduler's own counters, at its end.
     """
 
-    def __init__(self):
+    def __init__(self, counts=lambda: (0,) * 6):
+        import jax
+
         from tpu_dist_nn.utils.profiling import host_span
 
         self._host_span = host_span
+        self._capturing = jax.profiler.TraceAnnotation.is_enabled
+        self._counts = counts
         self.sched = next(_SCHED_IDS)
         self.seq = 0                 # iterations recorded
         self.ns = [0] * len(_trace.LOOP_PHASES)
-        self.cpu_ns = 0
+        self.cpu_ns = self.cpu_publish_ns = self.proc_cpu_ns = 0
         self.queue_wait_ns = self.binds = 0
         self.prefill_wait_ns = self.first_tokens = 0
         self.stream_lag_ns = self.stream_frames = 0
+        self.stream_send_ns = self.stream_sends = 0
         # Positions prefilled, and the first positions of their chunks
         # summed: neighbours' difference is an iteration's chunk.
         self.prefill_tokens = self.prefill_starts = 0
         # Step launches made while the step before them was unread.
         self.steps_ahead = 0
+        self.starved_ns = 0
+        self.captured = 0
         # Of the iteration in progress.
         self.prefilled = False
         self.active_slots = 0
         self.launched = False
         self._phase = _IDLE
         self._t = time.monotonic_ns()
+        # Since when nothing is queued on the device, and `idle` then.
+        self._starved: tuple | None = None
+        self._span = None  # the phase's annotation, while a capture runs
 
     def start(self) -> None:
         """On the loop thread, before its first iteration."""
         self._t = time.monotonic_ns()
-        self._cpu0 = time.thread_time_ns()
-        self._span = self._host_span(_SPAN_NAMES[_IDLE])
-        self._span.__enter__()
+        self._cpu0 = self._cpu_at = time.thread_time_ns()
+        self._proc0 = time.process_time_ns()
+        self._proc_at = 0  # the first recorded iteration reads it
 
     def mark(self, phase: int) -> None:
         prev = self._phase
         now = time.monotonic_ns()
         self.ns[prev] += now - self._t
         self._t = now
+        if phase != prev:
+            if phase == _STEP_PUBLISH:
+                self._cpu_at = time.thread_time_ns()
+            elif prev == _STEP_PUBLISH:
+                self.cpu_publish_ns += time.thread_time_ns() - self._cpu_at
         # A new annotation each time: one made before a capture began
-        # records nothing in it.
-        self._span.__exit__(None, None, None)
-        self._span = self._host_span(_SPAN_NAMES[phase])
-        self._span.__enter__()
+        # records nothing in it. None is made while no capture runs.
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
+        if self._capturing():
+            self._span = self._host_span(_SPAN_NAMES[phase])
+            self._span.__enter__()
         self._phase = phase
 
     def stop(self) -> None:
         self.mark(_IDLE)
-        self._span.__exit__(None, None, None)
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
+
+    def device_idle(self) -> None:
+        """The loop's wait for the device is over, or it had nothing to
+        wait for: nothing is queued there until the next dispatch."""
+        if self._starved is None:
+            self._starved = (time.monotonic_ns(), self.ns[_IDLE])
+
+    def fed(self) -> None:
+        """A dispatch has returned: the device has work again."""
+        if self._starved is not None:
+            since, idle = self._starved
+            self._starved = None
+            self.starved_ns += (time.monotonic_ns() - since
+                                - (self.ns[_IDLE] - idle))
 
     def host_ns(self) -> int:
         ns = self.ns
@@ -400,6 +454,9 @@ class _LoopClock:
             self.prefill_wait_ns, self.first_tokens,
             self.stream_lag_ns, self.stream_frames,
             self.prefill_tokens, self.prefill_starts, self.steps_ahead,
+            self.cpu_publish_ns, self.proc_cpu_ns,
+            self.stream_send_ns, self.stream_sends,
+            self.starved_ns, self.captured, *self._counts(),
         )
 
     def end_iteration(self) -> None:
@@ -410,6 +467,10 @@ class _LoopClock:
         if self.launched:
             self.seq += 1
             self.cpu_ns = time.thread_time_ns() - self._cpu0
+            if self._t - self._proc_at >= _PROC_CPU_EVERY_NS:
+                self._proc_at = self._t
+                self.proc_cpu_ns = time.process_time_ns() - self._proc0
+            self.captured += self._capturing()
             _trace.ITERATIONS.append(self.record())
             self.prefilled = self.launched = False
 
@@ -785,7 +846,11 @@ class ContinuousScheduler:
             maxlen=1024
         )
         self._m_rows = _BATCH_ROWS.labels(method=self.method)
-        self._clock = _LoopClock()
+        self._clock = _LoopClock(counts=lambda: (
+            self.discarded_lanes_total, self.slot_steps_total,
+            self.attend_kernel_chunks_total, self.prefill_chunks_total,
+            self.step_kv_tiles_visited_total,
+            self.step_kv_tiles_skipped_total))
         self._thread = threading.Thread(
             target=self._loop, name="tdn-gen-continuous", daemon=True
         )
@@ -1009,10 +1074,11 @@ class ContinuousScheduler:
     def loop_totals(self) -> dict:
         """The loop's cumulative accounting as of its last phase
         boundary, keyed by ``obs.trace.ITER_FIELDS``: nanoseconds per
-        phase, the loop thread's CPU time, and the
-        queue-wait, prefill-wait and stream-out-lag sums with their
-        counts (the ``tdn_gen_loop_*`` families, the benchmark's
-        ``sched_*`` metrics)."""
+        phase, the loop thread's CPU time whole and inside the publish,
+        the process's, the queue-wait, prefill-wait, stream-out-lag and
+        stream-send sums with their counts, and the starved time (the
+        ``tdn_gen_loop_*`` families, the benchmark's ``sched_*``
+        metrics)."""
         return dict(zip(_trace.ITER_FIELDS, self._clock.record()))
 
     # Prefix-cache accounting (None-safe: 0 with the pool off, so the
@@ -1522,6 +1588,7 @@ class ContinuousScheduler:
             return
         self._cache = cache
         self._launched = (_PREFILL_FETCH, tok)
+        clock.fed()
         clock.mark(_PREFILL_POST)
         occ["fill"] = start + size
         self.prefill_chunks_total += 1
@@ -1690,6 +1757,7 @@ class ContinuousScheduler:
         self._cache = cache
         self._prev = toks
         self._launched = (_STEP_FETCH, toks)
+        clock.fed()
         if self._unread and "lanes" in self._unread[0]:
             clock.steps_ahead += 1
         lanes = []
@@ -2077,6 +2145,7 @@ class ContinuousScheduler:
             ahead = self._active.any() and self._launch_step()
             self._land(keep=1 if ahead else 0)
             self._await_device()
+            clock.device_idle()
             if self._routing is not None:
                 self._routing_age += 1
                 if self._routing_age >= _ROUTING_EVERY \

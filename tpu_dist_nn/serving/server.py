@@ -1029,6 +1029,8 @@ def _make_generate_stream_handler(run_submit_stream, prompt_len: int,
     method unregistered (UNIMPLEMENTED — the honest answer).
     """
 
+    from tpu_dist_nn.utils.profiling import host_span
+
     def generate_stream(request_bytes: bytes, context):
         _RPC_REQUESTS.labels(method="GenerateStream").inc()
         span, budget, md = _request_span(context, "GenerateStream")
@@ -1145,7 +1147,11 @@ def _make_generate_stream_handler(run_submit_stream, prompt_len: int,
                 kind, data = ev
                 if kind == "tokens":
                     ntok += len(data)
-                    yield encode_token_frame(data)
+                    # The generator resumes on the thread that left it:
+                    # the span is this handler away with the frame
+                    # (TokenStream counts the same as stream_send_ns).
+                    with host_span("tdn.stream.send"):
+                        yield encode_token_frame(data)
                     continue
                 if data["reason"] == "error":
                     span.annotate(

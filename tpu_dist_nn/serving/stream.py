@@ -85,6 +85,11 @@ class TokenStream:
     next ``publish`` of this stream, so the sink keeps one writer and
     no lock is added. (The frames a consumer takes after the stream's
     last publish are therefore never counted: one of a request's ~190.)
+    The same way goes the stream-send time: how long the consumer was
+    away with each frame, from ``next_event`` handing it a batch to its
+    next call of ``next_event`` (encoding, gRPC's write and the wait
+    for its completion), into ``stream_send_ns`` / ``stream_sends``.
+    With the lag it is a frame's whole life on the server.
     """
 
     def __init__(self, max_buffer: int = 4096, lag_sink=None):
@@ -99,6 +104,9 @@ class TokenStream:
         self._pending_since = 0.0  # guarded-by: _cond
         self._lag_s = 0.0  # guarded-by: _cond
         self._lag_frames = 0  # guarded-by: _cond
+        self._taken_at: float | None = None  # guarded-by: _cond
+        self._send_s = 0.0  # guarded-by: _cond
+        self._sends = 0  # guarded-by: _cond
         _STREAM_REQUESTS.inc()
 
     # ---------------------------------------------------- producer side
@@ -135,6 +143,10 @@ class TokenStream:
                 self._lag_sink.stream_lag_ns += int(self._lag_s * 1e9)
                 self._lag_sink.stream_frames += self._lag_frames
                 self._lag_s, self._lag_frames = 0.0, 0
+            if self._sends and self._lag_sink is not None:
+                self._lag_sink.stream_send_ns += int(self._send_s * 1e9)
+                self._lag_sink.stream_sends += self._sends
+                self._send_s, self._sends = 0.0, 0
             if not self._pending:
                 self._pending_since = now
             self._sent += len(fresh)
@@ -198,13 +210,19 @@ class TokenStream:
         the buffer drains, or ``None`` on timeout — the handler's
         per-token-gap deadline hook."""
         with self._cond:
-            deadline = (None if timeout is None
-                        else time.monotonic() + timeout)
+            now = time.monotonic()
+            if self._taken_at is not None:
+                # Back from sending the batch the call before took.
+                self._send_s += now - self._taken_at
+                self._sends += 1
+                self._taken_at = None
+            deadline = None if timeout is None else now + timeout
             while True:
                 if self._pending:
                     batch = self._pending
                     self._pending = []
-                    self._lag_s += time.monotonic() - self._pending_since
+                    self._taken_at = time.monotonic()
+                    self._lag_s += self._taken_at - self._pending_since
                     self._lag_frames += 1
                     _STREAM_FRAMES.labels(kind="tokens").inc()
                     return "tokens", batch
