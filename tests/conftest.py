@@ -189,6 +189,12 @@ QUICK_TESTS = {
     "test_expand_attend": [
         "test_kernel_matches_the_loop[37-bfloat16]",
         "test_the_shapes_alone_decide_which_path_runs[rehearsal]"],
+    # PR 36: the decode step's absorbed latent attention kernel against
+    # the XLA einsums, and a planted fault it must catch.
+    "test_latent_attend": [
+        "test_kernel_matches_the_xla_path[tile-edges-bfloat16-2]",
+        "test_a_kernel_that_reads_one_tile_too_few_is_caught",
+        "test_the_shapes_alone_decide[cell-step]"],
     "test_multihost_real": ["test_two_process_collectives"],
     "test_native_codec": ["test_examples_roundtrip_and_parity",
                           "test_fuzz_model_roundtrip_native_vs_python"],

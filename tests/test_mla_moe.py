@@ -162,8 +162,8 @@ def test_absorbed_step_equals_expanded_form():
     q_n, q_r = f(1, cfg.n_heads, 16), f(1, cfg.n_heads, 8)
     rows, own = f(1, 1, cfg.latent_dim, M), f(1, cfg.latent_dim)
     blk = {"w_kvb": f(32, cfg.n_heads * 32) / 6}
-    visible = (jnp.arange(M) < pos)[None]
-    absorbed = mla_moe._attend_latent(q_n, q_r, rows, own, visible, blk, cfg)
+    absorbed = mla_moe._attend_latent(q_n, q_r, rows[None], 0, own,
+                                      jnp.asarray([pos]), blk, cfg)
     held = rows[0].at[:, :, pos].set(own)
     expanded = mla_moe._attend_expanded(q_n, q_r, held, jnp.asarray([pos]),
                                         blk, cfg)

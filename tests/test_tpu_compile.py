@@ -329,9 +329,13 @@ def _mla_moe_step(S, jnp):
     """The scheduler's step program for the latent-attention family at
     the published widths (64 heads on one 576-wide latent row) and the
     benchmark cell's 48 slots of extent 9215, with a prefix pool: one
-    Mosaic call writes the new rows of every layer at `pos`, and
-    nothing else in the compiled program yields a layer of latent rows:
-    they are read where they lie, all 576 of them in both products."""
+    Mosaic call writes the new rows of every layer at `pos`, the Mosaic
+    call of kernels/latent_attend.py (the dense layer's and the expert
+    layers' scan body's) reads the rows where they lie, layer by its
+    scalar index, and nothing else in the compiled program yields a
+    layer of latent rows (no slice of a layer handed to the call, no
+    copy of the cache beside the in-place write), nor is anything left
+    of the float32 scores `(48, 1, 64, 9217)` XLA kept in HBM."""
     from tools.aot_step_ops import big_ops
 
     cfg, slots, params, cache, key, _, step = _mla_moe_programs(S, jnp)
@@ -344,9 +348,17 @@ def _mla_moe_step(S, jnp):
                  if op["opcode"] == "custom-call tpu_custom_call"]
         problems = [] if len(calls) == 1 and "kv_write_row" in text else [
             f"{len(calls)} row writes at cache size, not one"]
+        if text.count('custom_call_target="tpu_custom_call"') != 3 \
+                or "latent_attend" not in text:
+            problems.append("not one row write and two latent_attend calls")
+        scores = slots * cfg.n_heads * 9216
         return problems + [
             f"{op['name']} ({op['opcode']}) {op['shapes']}"
-            for op in found if op not in calls]
+            for op in found if op not in calls] + [
+            f"scores in HBM: {op['name']} {op['shapes']}"
+            for op in big_ops(text, scores)["ops"]
+            if any(d == "f32" and dims[-1] in (9216, 9217)
+                   for d, dims, _, _ in op["shapes"])]
 
     args = (params, cache, ints, S((slots,), jnp.bool_), ints, key, ints,
             S((), jnp.int32))
@@ -433,6 +445,19 @@ def _decode_attend_one_slot(S, jnp):
         S(()))
 
 
+def _latent_attend_cell(S, jnp):
+    """The kernel alone at the repository cell's step: 48 slots of a
+    cache of 50 and six layers, 64 heads on 576-wide rows of which 512
+    are values, an extent of 9216 in tiles of 1024."""
+    from tpu_dist_nn.kernels import latent_attend
+
+    assert latent_attend.tiles(48, 64, 576, 512, 9216, jnp.bfloat16) == 1024
+    bf = jnp.bfloat16
+    return (lambda *a: latent_attend.attend_rows(*a, 512, 0.1)), (
+        S((48, 64, 576), bf), S((6, 50, 1, 576, 9216), bf),
+        S((), jnp.int32), S((48, 576), bf), S((48,), jnp.int32))
+
+
 CASES = {
     "fused_dense_256x784x128_relu": _fused_dense(256, 784, 128, "relu"),
     "fused_dense_256x64x10_softmax": _fused_dense(256, 64, 10, "softmax"),
@@ -461,6 +486,7 @@ CASES = {
     "mla_moe_step_h64_r576_m9215_in_place": _mla_moe_step,
     "mla_moe_chunk_c1024_r576_m9215_expand_kernel": _mla_moe_chunk,
     "expand_attend_c1024_h64_r576_m9216_bf16": _expand_attend_cell,
+    "latent_attend_s48_h64_r576_m9216_bf16": _latent_attend_cell,
 }
 
 

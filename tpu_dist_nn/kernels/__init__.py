@@ -18,7 +18,9 @@ path that stays as its oracle):
 * :mod:`.decode_attend` — a step's differential attention over the
   shared K/V, live tiles only (:mod:`tpu_dist_nn.models.sambay`);
 * :mod:`.expand_attend` — a chunk's latent attention in its expanded
-  form (:mod:`tpu_dist_nn.models.mla_moe`).
+  form (:mod:`tpu_dist_nn.models.mla_moe`);
+* :mod:`.latent_attend` — a step's absorbed latent attention over the
+  latent rows, live tiles only (:mod:`tpu_dist_nn.models.mla_moe`).
 """
 
 from tpu_dist_nn.kernels.fused_dense import (

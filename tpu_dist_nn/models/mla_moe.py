@@ -50,7 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from tpu_dist_nn.kernels import expand_attend
+from tpu_dist_nn.kernels import expand_attend, latent_attend
 from tpu_dist_nn.kernels.kv_write import write_row
 from tpu_dist_nn.models.sala import _einsum32, _put_slot, _rms, _take_slot
 from tpu_dist_nn.models.slot_model import SlotModel
@@ -184,6 +184,7 @@ class MlaMoeConfig:
             cache_bytes=cache_bytes,
             routing_counts=self.routing_counts,
             attend_kernel=self.attend_kernel,
+            step_kv_tiles=self.step_kv_tiles,
         )
 
     def attend_kernel(self, size, max_len) -> bool:
@@ -192,6 +193,24 @@ class MlaMoeConfig:
         the dispatch of :func:`_attend_expanded`, asked from outside the
         program."""
         return attend_kernel_tiles(self, int(size), _extent(max_len))
+
+    def step_kv_tiles(self, slots: int, max_len: int):
+        """``pos (int array) -> (fetched, skipped)``: of the 128-lane
+        position tiles of the slots' latent extent, how many a decode
+        step copies a layer for queries at ``pos`` and how many it
+        leaves in HBM; or ``None`` where the step of ``slots`` slots into
+        a cache made for ``max_len`` reads the whole extent
+        (:func:`_attend_latent`'s dispatch, asked from outside the
+        program)."""
+        M = _extent(max_len)
+        if step_kernel_tile(self, slots, M) is None:
+            return None
+
+        def count(pos):
+            fetched = int(latent_attend.fetched_tiles(pos).sum())
+            return fetched, len(pos) * (M // _LANES) - fetched
+
+        return count
 
     def routing_counts(self, cache: dict) -> dict:
         """The cache's routing counts by name (device values; the
@@ -487,30 +506,59 @@ def _expanded_loop(q_n, q_r, rows, t, wk, wv, scale):
     return (acc / l[..., None]).transpose(1, 0, 2)
 
 
+def step_kernel_tile(cfg: MlaMoeConfig, S: int, M: int):
+    """The position tile a step of ``S`` slots over an extent of ``M``
+    runs its latent attention with as the Pallas kernel
+    (:mod:`tpu_dist_nn.kernels.latent_attend`), or ``None`` where it
+    keeps the XLA einsums: only the shapes decide."""
+    return latent_attend.tiles(S, cfg.n_heads, cfg.latent_dim,
+                               cfg.kv_lora_rank, M, cfg.param_dtype)
+
+
 @jax.named_scope("mla_moe.attn.latent")
-def _attend_latent(q_n, q_r, lat, own, visible, blk, cfg):
+def _attend_latent(q_n, q_r, lat, layer, own, live, blk, cfg):
     """The decode step's attention, one query a slot: ``q_n (S, H,
-    d_n)``, ``q_r (S, H, d_r)`` over the latent rows ``lat (S, 1, r,
-    M)`` where ``visible (S, M)`` and over the position's own row ``own
+    d_n)``, ``q_r (S, H, d_r)`` over the first ``live (S,)`` positions
+    of slots ``[0, S)`` of layer ``layer`` (traced) of the whole cache
+    ``lat (L, slots, 1, r, M)`` and over the position's own row ``own
     (S, r)``, which no cache holds yet.  ``w_kvb``'s key half is folded
     into the query and its value half applied behind the softmax: the
-    rows enter both products as they lie, all ``r`` of them (the
-    rotated key's ``d_r`` ride through the second and are dropped: no
-    slice of the cache is made).  Returns ``(S, H, d_v)`` float32."""
-    rkv = cfg.kv_lora_rank
+    rows enter both products as they lie (the rotated key's ``d_r`` are
+    no values: no slice of the cache is made).  Returns ``(S, H, d_v)``
+    float32.
+
+    Which path runs is read from the shapes: the Pallas kernel where
+    they tile (each slot's live rows once, the scores on the chip), the
+    XLA einsums over the whole extent, its oracle, where they do not."""
+    S, M, rkv = q_n.shape[0], lat.shape[-1], cfg.kv_lora_rank
     wk, wv = _kvb(blk, cfg)
     q = jnp.concatenate(
         [_einsum32("shd,rhd->shr", q_n, wk).astype(q_r.dtype), q_r], -1)
+    if step_kernel_tile(cfg, S, M) is not None:
+        o = latent_attend.attend_rows(q, lat, layer, own, live, rkv,
+                                      cfg.softmax_scale)
+    else:
+        rows = lax.dynamic_slice(
+            lat, (layer, 0, 0, 0, 0), (1, S) + lat.shape[2:])[0]
+        o = _latent_einsums(q, rows, own, live, cfg.softmax_scale)[..., :rkv]
+    return _einsum32("shr,rhd->shd", o.astype(lat.dtype), wv)
+
+
+def _latent_einsums(q, rows, own, live, scale):
+    """:func:`_attend_latent` over ``rows (S, 1, r, M)`` as XLA values:
+    every slot's whole extent scored and masked, all ``r`` numbers of a
+    row through the second product.  ``(S, H, r)`` float32."""
+    M = rows.shape[-1]
     q = q[:, None]  # (S, 1, H, r): the one latent "head" a batch dimension
-    s = _einsum32("sghr,sgrm->sghm", q, lat) * cfg.softmax_scale
+    s = _einsum32("sghr,sgrm->sghm", q, rows) * scale
     mine = _einsum32("sghr,sgr->sgh", q.astype(jnp.float32),
-                     own[:, None].astype(jnp.float32)) * cfg.softmax_scale
+                     own[:, None].astype(jnp.float32)) * scale
+    visible = jnp.arange(M)[None, :] < live[:, None]
     s = jnp.where(visible[:, None, None, :], s, -jnp.inf)
     p = jax.nn.softmax(jnp.concatenate([s, mine[..., None]], -1), -1)
-    M = lat.shape[-1]
-    o = _einsum32("sghm,sgrm->sghr", p[..., :M].astype(lat.dtype), lat) \
+    o = _einsum32("sghm,sgrm->sghr", p[..., :M].astype(rows.dtype), rows) \
         + p[..., M:] * own[:, None, None, :].astype(jnp.float32)
-    return _einsum32("shr,rhd->shd", o[:, 0, :, :rkv].astype(lat.dtype), wv)
+    return o[:, 0]
 
 
 @jax.named_scope("mla_moe.mlp")
@@ -745,14 +793,12 @@ def decode_step_slots(params: dict, cache: dict, pos: jnp.ndarray,
     pos = jnp.clip(jnp.asarray(pos, jnp.int32), 0, M - 1)
     # A slot the step does not decode attends its own row alone: nothing
     # of its rows is read into a softmax.
-    visible = jnp.arange(M)[None, :] < jnp.where(active, pos, 0)[:, None]
+    live = jnp.where(active, pos, 0)
     x = params["embed"][token]
 
     def attend(x, blk, layer):
-        rows = lax.dynamic_slice(
-            lat, (layer, 0, 0, 0, 0), (1, S) + lat.shape[2:])[0]
         q_n, q_r, new = _project(x, blk, pos, cfg)
-        o = _attend_latent(q_n, q_r, rows, new, visible, blk, cfg)
+        o = _attend_latent(q_n, q_r, lat, layer, new, live, blk, cfg)
         return _attn_out(x, o, blk), new
 
     def dense(x, i):

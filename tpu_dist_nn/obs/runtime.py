@@ -261,7 +261,8 @@ class RuntimeSampler:
             "128-lane position tiles of the K/V extent over the slots of "
             "every decode step, by whether the step copied them (visited) "
             "or left them in HBM past a slot's frontier (skipped); only a "
-            "model whose step stops there counts (kernels/decode_attend.py)",
+            "model whose step stops there counts (kernels/decode_attend.py, "
+            "kernels/latent_attend.py)",
             labels=("state",),
         )
         for state in ("visited", "skipped"):
