@@ -173,6 +173,13 @@ QUICK_TESTS = {
     "test_mla_moe": ["test_forward_matches_reference",
                      "test_prefill_then_decode_matches_full_forward[136]",
                      "test_the_shares_add_up_to_the_uncut_layer"],
+    # PR 37: the fifth block family (window rings beside full K/V, two
+    # head counts, the shared expert layer) against its plain reference,
+    # and the programs of the two families it shares code with.
+    "test_laguna": ["test_forward_matches_reference",
+                    "test_prefill_then_decode_matches_full_forward[23]",
+                    "test_the_shares_add_up_to_the_uncut_layer",
+                    "test_kimis_programs_are_the_parents_operation_for_operation[step]"],
     "test_kv_write": [
         "test_write_rows_lands_rows_and_nothing_else[partial_lane_block]"],
     # PR 28: the chunk's block-masked attention kernel against the loop.

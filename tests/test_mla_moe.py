@@ -32,7 +32,7 @@ import numpy as np
 import pytest
 
 from benchmark.harness import lookup
-from tpu_dist_nn.models import mla_moe, sala
+from tpu_dist_nn.models import experts, mla_moe, sala
 from tpu_dist_nn.serving.continuous import ContinuousScheduler, slot_kernels
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -232,10 +232,10 @@ def test_a_tokens_result_does_not_depend_on_its_batch():
                     jnp.float32)
     assert mla_moe.experts_form(160) == "ragged"
     assert mla_moe.experts_form(5) == "dense"
-    ragged = np.asarray(_routed(u, moe, CFG32, mla_moe._experts_ragged))
+    ragged = np.asarray(_routed(u, moe, CFG32, experts.experts_ragged))
     assert np.abs(ragged).max() > 0.1
     for lo, hi in ((0, 5), (77, 78), (155, 160)):
-        alone = _routed(u[lo:hi], moe, CFG32, mla_moe._experts_dense)
+        alone = _routed(u[lo:hi], moe, CFG32, experts.experts_dense)
         np.testing.assert_allclose(np.asarray(alone), ragged[lo:hi],
                                    atol=1e-5)
 
@@ -248,8 +248,8 @@ def test_ragged_form_is_sized_for_every_token_on_every_held_expert():
     moe, _ = _moe_block(wide)
     u = jnp.asarray(np.random.default_rng(6).normal(size=(300, 64)),
                     jnp.float32)
-    ragged = _routed(u, moe, cfg, mla_moe._experts_ragged)
-    dense = _routed(u, moe, cfg, mla_moe._experts_dense)
+    ragged = _routed(u, moe, cfg, experts.experts_ragged)
+    dense = _routed(u, moe, cfg, experts.experts_dense)
     np.testing.assert_allclose(np.asarray(ragged), np.asarray(dense),
                                atol=1e-5)
 
@@ -277,7 +277,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
         moe, w = _moe_block(share)
         np.testing.assert_array_equal(np.asarray(w["ex_gu"]),
                                       np.asarray(whole["ex_gu"])[ids])
-        part = np.asarray(_routed(u, moe, cfg, mla_moe._experts_dense))
+        part = np.asarray(_routed(u, moe, cfg, experts.experts_dense))
         assert np.abs(part).max() > 0.05  # every share has work here
         total = total + part
     np.testing.assert_allclose(total, np.asarray(routed), atol=1e-5)

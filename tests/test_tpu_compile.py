@@ -402,6 +402,74 @@ def _mla_moe_chunk(S, jnp):
 
 
 
+def _laguna_programs(S, jnp):
+    import jax
+
+    from tpu_dist_nn.models import laguna
+    from tpu_dist_nn.serving.continuous import slot_kernels
+
+    # The published attention widths and the cell's five layer kinds
+    # (48 and 72 query heads over 8 K/V heads of 128, a window of 512),
+    # 8 of the router's 256 experts held, a narrow dense FFN and a small
+    # vocabulary; the cell's 16 slots of extent 17407.
+    full, window = laguna.FULL, laguna.WINDOW
+    cfg = laguna.LagunaConfig(
+        vocab_size=1024, hidden_size=3072, head_dim=128, n_kv_heads=8,
+        layer_types=(full, window, window, window, full),
+        mlp_layer_types=("dense",) + ("sparse",) * 4,
+        heads_per_layer=(48, 72, 72, 72, 48), intermediate_size=512,
+        moe_intermediate_size=1024, shared_intermediate_size=1024,
+        router_width=256, experts_held=tuple(range(8)), n_experts_per_tok=10,
+        routed_scaling_factor=2.5, sliding_window=512, max_seq_len=1048576,
+        rope_factor=128.0, attention_factor=1.4852030263919618)
+    slots, extent = 16, 17407
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: S(a.shape, a.dtype), tree)
+
+    params = shaped(jax.eval_shape(
+        lambda: laguna.init_laguna(jax.random.key(0), cfg)))
+    cache = shaped(jax.eval_shape(
+        lambda: laguna.init_slot_cache(cfg, slots, extent)))
+    key = shaped(jax.eval_shape(lambda: jax.random.key(0)))
+    chunk, _, step = slot_kernels(cfg, 0.0, None, None)
+    return cfg, slots, params, cache, key, chunk, step
+
+
+def _not_the_cache(text, cfg, slots):
+    """What the compiled program yields at a layer of K rows' size or
+    more, other than the full layers' K/V cache itself."""
+    from tools.aot_step_ops import big_ops
+
+    layer = slots * cfg.n_kv_heads * cfg.head_dim * 17408
+    return [op for op in big_ops(text, layer)["ops"]
+            if not all(dims[:2] == (cfg.n_full, slots)
+                       for _, dims, _, _ in op["shapes"])]
+
+
+def _laguna_step(S, jnp):
+    """The scheduler's step program for Laguna's family at the published
+    attention widths and the cell's 16 slots of extent 17407: the new
+    rows land by two Mosaic calls (the full layers' K/V at `pos`, the
+    window layers' rings at `pos mod 512`), the one at cache size in
+    place, and nothing else in the compiled program yields a layer of
+    K/V rows: the full layers read their rows where they lie."""
+    cfg, slots, params, cache, key, _, step = _laguna_programs(S, jnp)
+    ints = S((slots,), jnp.int32)
+
+    def check(text):
+        problems = [] if text.count(
+            'custom_call_target="tpu_custom_call"') == 2 else [
+            "not two row writes"]
+        return problems + [f"{op['name']} ({op['opcode']}) {op['shapes']}"
+                           for op in _not_the_cache(text, cfg, slots)
+                           if "kv_write_rows" not in op["name"]]
+
+    args = (params, cache, ints, S((slots,), jnp.bool_), ints, key, ints,
+            S((), jnp.int32))
+    return step, args, check
+
+
 def _sparse_attend_small_tiles(S, jnp):
     """The kernel alone at the smallest tiles its dispatch keeps (query
     128, key 384: an extent of 1152 that 768 does not divide); the cell's
@@ -487,6 +555,7 @@ CASES = {
     "mla_moe_chunk_c1024_r576_m9215_expand_kernel": _mla_moe_chunk,
     "expand_attend_c1024_h64_r576_m9216_bf16": _expand_attend_cell,
     "latent_attend_s48_h64_r576_m9216_bf16": _latent_attend_cell,
+    "laguna_step_g8_h48_72_w512_m17407_in_place": _laguna_step,
 }
 
 

@@ -25,11 +25,9 @@ head, and in every layer
   share of an expert-parallel deployment): it routes over all of them,
   computes the part of the result its own give, and knows nothing else
   of the deployment.  It is dropless: no capacity, no dropped token.
-  Two forms, chosen by the number of tokens (:func:`experts_form`):
-  every held expert on every token under the gate (a decode step: the
-  matrices are read once either way), or the (token, expert) pairs
-  sorted by expert into row tiles, a loop over the tiles that hold a
-  pair (a chunk: ragged by load).
+  That layer is :mod:`.experts`, which Laguna's family (:mod:`.laguna`)
+  calls too with its own router: a dense form for a step, a ragged one
+  for a chunk (:func:`~.experts.experts_form`).
 
 The slot cache: ``lat (L, S, 1, kv_lora_rank + qk_rope_head_dim, M)``,
 positions in the 128 lanes (PR 25's rule), the new rows landed in place
@@ -52,27 +50,14 @@ from jax import lax
 
 from tpu_dist_nn.kernels import expand_attend, latent_attend
 from tpu_dist_nn.kernels.kv_write import write_row
+from tpu_dist_nn.models import experts
+from tpu_dist_nn.models.experts import experts_form
 from tpu_dist_nn.models.sala import _einsum32, _put_slot, _rms, _take_slot
 from tpu_dist_nn.models.slot_model import SlotModel
 
 _LANES = 128
-# Rows of a tile of (token, expert) pairs in the ragged form: one
-# expert's matrices are read once a tile.
-_PAIR_TILE = 128
-# The most tokens the masked form serves: below it a held expert's
-# matrices are read longer than its products take on every token (v5e:
-# 6 D F bytes at 819 GB/s against tokens x 6 D F FLOPs at 197 TFLOP/s
-# cross at 240 tokens).
-_DENSE_TOKENS = 128
 # Key positions a chunk expands and attends at a time.
 _KEY_TILES = (512, 256, 128)
-
-
-def experts_form(tokens: int) -> str:
-    """``"dense"`` or ``"ragged"``: which form of the routed product a
-    program of ``tokens`` tokens is built with.  It reads the shape,
-    once, at trace time."""
-    return "dense" if int(tokens) <= _DENSE_TOKENS else "ragged"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -216,9 +201,7 @@ class MlaMoeConfig:
         """The cache's routing counts by name (device values; the
         scheduler fetches them together): ``expert_pairs (n_held,)``,
         ``routed_pairs``, ``expert_touched``, ``expert_visits``."""
-        r, n = cache["routed"], self.n_held
-        return {"expert_pairs": r[:n], "routed_pairs": r[n],
-                "expert_touched": r[n + 1], "expert_visits": r[n + 2]}
+        return experts.routing_counts(cache["routed"], self.n_held)
 
     # ---------------------------------------------------------- loading
     @classmethod
@@ -585,71 +568,8 @@ def route(u, blk, cfg):
 
 def _held_gates(chosen, w, cfg):
     """``(A, n_held)``: whether each token chose each held expert, and
-    the expert's weight for it (float32, zero where it did not)."""
-    held = jnp.asarray(cfg.experts_held, jnp.int32)
-    hit = chosen[:, :, None] == held[None, None, :]
-    return jnp.any(hit, 1), jnp.sum(jnp.where(hit, w[:, :, None], 0.0), 1)
-
-
-def _experts_dense(u, on, gates, ex_gu, ex_d, layer):
-    """Every held expert of layer ``layer`` of the stacks ``ex_gu (Lm,
-    N, D, 2 F)``, ``ex_d (Lm, N, F, D)`` on every token, under the
-    gate."""
-    gu = jnp.einsum("ad,ndf->naf", u, lax.dynamic_index_in_dim(
-        ex_gu, layer, 0, keepdims=False))
-    F = ex_d.shape[-2]
-    y = _einsum32("naf,nfd->nad", jax.nn.silu(gu[..., :F]) * gu[..., F:],
-                  lax.dynamic_index_in_dim(ex_d, layer, 0, keepdims=False))
-    return jnp.sum(y * gates.T[:, :, None], 0)
-
-
-def _experts_ragged(u, on, gates, ex_gu, ex_d, layer):
-    """The pairs (token, held expert) that were chosen, sorted by
-    expert into tiles of ``_PAIR_TILE`` rows, each expert's rows padded
-    to whole tiles; a loop over the tiles that hold a pair, one expert's
-    matrices a tile, sliced out of the stacks inside the tile's own
-    products (a layer of them handed to the loop whole would be copied
-    first: 1.06 GB a layer at the published widths).  Sized for the
-    worst case (every token on every held expert) by shape, and as long
-    as the load by trip count."""
-    A, N = gates.shape
-    R = _PAIR_TILE
-    on = on.T  # (N, A): expert-major, so a sort is a cumsum
-    count = jnp.sum(on, -1)
-    tiles = -(-count // R)
-    first_tile = jnp.cumsum(tiles) - tiles
-    n_tiles = -(-A // R) * N  # every expert full
-    # Row of the pair (n, a) in the tiled table, or past it.
-    rank = jnp.cumsum(on, -1) - 1
-    dest = jnp.where(on, first_tile[:, None] * R + rank, n_tiles * R)
-    token = jnp.full((n_tiles * R + 1,), A, jnp.int32).at[dest.ravel()].set(
-        jnp.broadcast_to(jnp.arange(A, dtype=jnp.int32), (N, A)).ravel())
-    gate = jnp.zeros((n_tiles * R + 1,), jnp.float32).at[dest.ravel()].set(
-        gates.T.ravel())
-    expert_of_tile = jnp.sum(
-        jnp.arange(n_tiles)[:, None] >= (first_tile + tiles)[None, :], -1)
-    F = ex_d.shape[-2]
-    padded = jnp.concatenate([u, jnp.zeros((1, u.shape[1]), u.dtype)])
-
-    def expert(stack, n):
-        return lax.dynamic_slice(stack, (layer, n, 0, 0),
-                                 (1, 1) + stack.shape[2:])[0, 0]
-
-    def tile(i, out):
-        rows = lax.dynamic_slice(token, (i * R,), (R,))
-        g = lax.dynamic_slice(gate, (i * R,), (R,))
-        n = jnp.minimum(expert_of_tile[i], N - 1)
-        gu = padded[rows] @ expert(ex_gu, n)
-        y = _einsum32("rf,fd->rd", jax.nn.silu(gu[:, :F]) * gu[:, F:],
-                      expert(ex_d, n)) * g[:, None]
-        # Back to the tokens by a product with the rows' one-hot: the
-        # MXU's work, where a scatter-add is the scalar core's.
-        hot = rows[None, :] == jnp.arange(A, dtype=jnp.int32)[:, None]
-        return out + _einsum32("ar,rd->ad", hot.astype(u.dtype),
-                               y.astype(u.dtype))
-
-    return lax.fori_loop(0, jnp.sum(tiles), tile,
-                         jnp.zeros((A, u.shape[1]), jnp.float32))
+    the expert's weight for it (:func:`.experts.held_gates`)."""
+    return experts.held_gates(chosen, w, cfg.experts_held)
 
 
 @jax.named_scope("mla_moe.shared")
@@ -660,32 +580,24 @@ def _shared_expert(u, blk):
 def _moe_ffn(x, norm_g, moe, layer, counted, cfg):
     """``x (A, D)`` through expert layer ``layer`` of the stacked
     ``moe`` parameters: the shared expert plus the held experts' part of
-    the routed sum.  Also the layer's routing counts over the tokens
-    ``counted (A,)``: pairs by held expert ``(n_held,)``."""
+    the routed sum (:mod:`.experts`).  Also the layer's routing counts
+    over the tokens ``counted (A,)``: pairs by held expert
+    ``(n_held,)``."""
     blk = _layer({n: a for n, a in moe.items() if not n.startswith("ex_")},
                  layer)
     u = _rms(x, norm_g, cfg.rms_eps)
     chosen, w = route(u, blk, cfg)
     on, gates = _held_gates(chosen, w, cfg)
-    with jax.named_scope("mla_moe.experts"):
-        form = _experts_dense if experts_form(x.shape[0]) == "dense" \
-            else _experts_ragged
-        routed = form(u, on, gates, moe["ex_gu"], moe["ex_d"], layer)
-    pairs = jnp.sum(on & counted[:, None], 0, dtype=jnp.int32)
+    routed, pairs = experts.routed(u, on, gates, moe["ex_gu"], moe["ex_d"],
+                                   layer, counted, "mla_moe.experts")
     return x + _shared_expert(u, blk) + routed.astype(x.dtype), pairs
 
 
 def _counts(cfg, pairs, tokens, step: bool):
-    """What one launch adds to ``cache["routed"]``: ``pairs (n_moe,
-    n_held)`` by layer and held expert over ``tokens`` counted tokens."""
-    visits = cfg.n_moe * cfg.n_held
-    return jnp.concatenate([
-        jnp.sum(pairs, 0),
-        jnp.stack([
-            tokens * (cfg.n_experts_per_tok * cfg.n_moe),
-            jnp.sum(pairs > 0, dtype=jnp.int32) if step else jnp.int32(0),
-            jnp.where(tokens > 0, visits, 0) if step else jnp.int32(0),
-        ]).astype(jnp.int32)])
+    """What one launch adds to ``cache["routed"]``
+    (:func:`.experts.counts`)."""
+    return experts.counts(cfg.n_moe, cfg.n_held, cfg.n_experts_per_tok,
+                          pairs, tokens, step)
 
 
 # ----------------------------------------------------------- slot cache
@@ -900,7 +812,7 @@ class MlaMoeFlopModel:
             routed = c * self.cfg.n_moe * self.cfg.n_held * self._expert
         else:
             routed = max(self._routed(c), self.cfg.n_moe * self.cfg.n_held
-                         * _PAIR_TILE * self._expert)
+                         * experts.PAIR_TILE * self._expert)
         return c * self._fixed + (self._chunk_key * c + self._expand) \
             * self.M + routed + self._logit
 

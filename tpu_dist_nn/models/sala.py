@@ -35,7 +35,6 @@ benchmark/configs/minicpm_sala_reference.py.
 from __future__ import annotations
 
 import dataclasses
-import json
 
 import jax
 import jax.numpy as jnp
@@ -44,7 +43,10 @@ from jax import lax
 
 from tpu_dist_nn.kernels import sparse_attend
 from tpu_dist_nn.kernels.kv_write import write_rows
-from tpu_dist_nn.models.slot_model import SlotModel
+from tpu_dist_nn.models.slot_model import (  # noqa: F401
+    SlotModel,
+    load_model_config,
+)
 
 LIGHTNING = "lightning-attn"
 SPARSE = "minicpm4"
@@ -227,18 +229,6 @@ class SalaConfig:
             param_dtype=str(d.get("param_dtype", "bfloat16")),
             **sparse,
         )
-
-
-def load_model_config(path: str):
-    """The config of ``tdn lm --model-config <file.json>``: dispatch on
-    the file's ``model_type``.  The other families are dispatched at
-    the end of this file, so that no line of a function this family's
-    programs trace moves (their compile-cache keys hold them)."""
-    with open(path) as f:
-        d = json.load(f)
-    if d.get("model_type") == "minicpm_sala":
-        return SalaConfig.from_dict(d)
-    return _load_other_family(path, d)
 
 
 # ------------------------------------------------------------ parameters
@@ -937,23 +927,9 @@ class SalaFlopModel:
 
 
 # ------------------------------------------- the --model-config entry
-# Below every traced function of this file: a program's compile-cache
-# key holds the source lines of what it traces (PERF.md section 6, PR
-# 29), and this family's programs are to stay what they were.
-
-def _load_other_family(path: str, d: dict):
-    if d.get("model_type") == "phi4flash":
-        from tpu_dist_nn.models.sambay import SambaYConfig
-
-        return SambaYConfig.from_dict(d)
-    if d.get("model_type") == "kimi_k2":
-        from tpu_dist_nn.models.mla_moe import MlaMoeConfig
-
-        return MlaMoeConfig.from_dict(d)
-    raise ValueError(
-        f"{path}: model_type {d.get('model_type')!r} has no loader "
-        "(known: 'minicpm_sala', 'phi4flash', 'kimi_k2')")
-
+# `load_model_config`, which reads the file and dispatches on its
+# `model_type`, is models/slot_model.py's; the name stays importable
+# from here, where the benchmark's drivers import it.
 
 def init_model_config(key: jax.Array, cfg):
     """What ``tdn lm --model-config`` needs of a loaded config, whatever

@@ -3,7 +3,7 @@
 The scheduler (:mod:`tpu_dist_nn.serving.continuous`) builds its three
 programs — chunk prefill, slot copy, decode step — from four functions
 and never learns what a layer is.  A model's config hands them over
-with ``cfg.slot_model()``; the four implementers are
+with ``cfg.slot_model()``; the five implementers are
 :class:`~tpu_dist_nn.models.transformer.TransformerConfig` (GPT-2's
 block, one ``{k, v}`` cache: :mod:`.generate`),
 :class:`~tpu_dist_nn.models.sala.SalaConfig` (two kinds of layer, K/V
@@ -14,15 +14,44 @@ and a fourth program: a chunk that ends without logits: :mod:`.sambay`)
 and :class:`~tpu_dist_nn.models.mla_moe.MlaMoeConfig` (latent attention
 over one compressed row a position, routed experts of which the chip
 holds a share, and routing counts that accumulate on the device:
-:mod:`.mla_moe`).
+:mod:`.mla_moe`) and :class:`~tpu_dist_nn.models.laguna.LagunaConfig`
+(full and window GQA layers with their own head counts, one K/V cache
+beside window rings, and the same expert layer, :mod:`.experts`, under
+a softmax router: :mod:`.laguna`).
 docs/MODEL_CONFIG.md says what the prefix pool and preemption require
-of an implementer.
+of an implementer.  :func:`load_model_config` reads a
+``--model-config`` file into the config of its family.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import importlib
+import json
 from typing import Callable
+
+# model_type -> (module under tpu_dist_nn.models, its config class)
+FAMILIES = {
+    "minicpm_sala": ("sala", "SalaConfig"),
+    "phi4flash": ("sambay", "SambaYConfig"),
+    "kimi_k2": ("mla_moe", "MlaMoeConfig"),
+    "laguna": ("laguna", "LagunaConfig"),
+}
+
+
+def load_model_config(path: str):
+    """The config of ``tdn lm --model-config <file.json>``: the file's
+    ``model_type`` names the family, whose ``from_dict`` reads the rest."""
+    with open(path) as f:
+        d = json.load(f)
+    family = FAMILIES.get(d.get("model_type"))
+    if family is None:
+        known = ", ".join(repr(k) for k in FAMILIES)
+        raise ValueError(
+            f"{path}: model_type {d.get('model_type')!r} has no loader "
+            f"(known: {known})")
+    module = importlib.import_module(f"tpu_dist_nn.models.{family[0]}")
+    return getattr(module, family[1]).from_dict(d)
 
 
 def _all_dense(pos) -> int:
