@@ -26,7 +26,9 @@ benchmark's rehearsal uses (tests/bench_harness/test_bench_laguna.py).
 
 The last section pins what the PR that brought this family moved: the
 expert layer that Kimi-K2's programs call (models/experts.py) and the
-ring helpers SambaY's call leave their programs as they were.
+ring helpers SambaY's call leave their programs as they were; and so
+does the dense mode of kernels/sparse_attend.py, which this family's
+full layers run, for MiniCPM-SALA's chunk.
 """
 
 import collections
@@ -730,6 +732,36 @@ def test_kimis_programs_are_the_parents_operation_for_operation(program):
     assert scopes == want
     assert not re.search(r"laguna\.(attn|router|experts|shared|mlp)",
                          lowered.as_text(debug_info=True))
+
+
+# MiniCPM-SALA's chunk program at heads a TPU tile fits: (operations,
+# digest) taken before kernels/sparse_attend.py gained the dense mode
+# that Laguna's full layers run, for a chunk whose block-masked
+# attention is that module's kernel (128: interpreted here, so every
+# operation of its body is counted) and for one that keeps the loop.
+SALA_WIDE = sala.SalaConfig(
+    vocab_size=256, hidden_size=64, intermediate_size=128, n_heads=4,
+    n_kv_heads=2, head_dim=128, lightning_heads=2, lightning_head_dim=16,
+    mixer_types=(sala.SPARSE, sala.LIGHTNING, sala.SPARSE),
+    layer_ids=(9, 10, 16), published_layers=32, max_seq_len=4096,
+    scale_emb=12.0, scale_depth=1.4, dim_model_base=16,
+    kernel_size=32, kernel_stride=16, block_size=64, topk=2,
+    window_size=128, init_blocks=1, dense_len=256, param_dtype="float32")
+SALA_PARENT = {128: (3413, "826b82150b4449fb"),
+               100: (1759, "fd9086d97d8ebf98")}
+
+
+@pytest.mark.parametrize("size", sorted(SALA_PARENT))
+def test_salas_chunk_is_the_parents_operation_for_operation(size):
+    cfg = SALA_WIDE
+    assert cfg.attend_kernel(size, 1152) is (size == 128)
+    p = jax.eval_shape(lambda: sala.init_sala(jax.random.key(0), cfg))
+    cache = jax.eval_shape(lambda: sala.init_slot_cache(cfg, 2, 1152))
+    i32 = jax.ShapeDtypeStruct((), jnp.int32)
+    ops = _ops(jax.jit(lambda p, c, s, t, st: sala.prefill_chunk_into_cache(
+        p, cfg, c, s, t, st)).lower(
+            p, cache, i32, jax.ShapeDtypeStruct((1, size), jnp.int32), i32))
+    assert (sum(ops.values()), _digest(ops)) == SALA_PARENT[size], ops
 
 
 SAMBAY_PARENT = {"chunk": (2520, "43617b382a0c889d"),

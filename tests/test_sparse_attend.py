@@ -12,9 +12,16 @@ rounds its probabilities to 8 bits of mantissa after subtracting ITS
 running maximum, which differs with the tiling: 0.02 on outputs that
 spread by one is four such roundings.  tests/test_tpu_compile.py
 compiles the same kernel for a described v5e at the cell's shapes.
+
+Without a selection the kernel is a full layer's causal attention of
+Laguna's family (models/laguna.py), whose key-tile loop
+`_full_chunk_loop` is then the oracle: the same tolerances, the same
+reordering (key tiles of 1024 against the loop's 512).
 """
 
 import dataclasses
+import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +29,7 @@ import numpy as np
 import pytest
 
 from tpu_dist_nn.kernels import sparse_attend
-from tpu_dist_nn.models import sala
+from tpu_dist_nn.models import laguna, sala
 from tpu_dist_nn.models.transformer import TransformerConfig
 from tpu_dist_nn.obs.registry import Registry
 from tpu_dist_nn.obs.runtime import RuntimeSampler
@@ -40,6 +47,23 @@ WIDE = sala.SalaConfig(
 NARROW = dataclasses.replace(WIDE, head_dim=16)
 C, M = 128, 1152
 TOL = {"float32": 1e-5, "bfloat16": 2e-2}
+
+CONFIGS = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark", "configs")
+
+
+def _laguna(name, **changes):
+    with open(os.path.join(CONFIGS, name)) as f:
+        return laguna.LagunaConfig.from_dict(dict(json.load(f), **changes))
+
+
+# Laguna's toy at heads a TPU tile fits: 12 query heads on a full layer
+# over 2 K/V heads of 128, groups of 6 as the cell's 48 over 8.
+LAGUNA_WIDE = _laguna("rehearsal-laguna-tiny.json", head_dim=128,
+                      num_attention_heads_per_layer=[12, 6, 6, 6, 12],
+                      param_dtype="float32")
+LAGUNA_TOY = _laguna("rehearsal-laguna-tiny.json")
+LAGUNA_CELL = _laguna("laguna-s-2.1.json")
 
 
 def _operands(start, dtype, seed=0):
@@ -108,23 +132,88 @@ def test_a_row_with_nothing_selected_comes_back_zero():
     (WIDE, 128, 1088, False),     # an extent that no 128-lane tile divides
     (WIDE, 128, 640, False),      # ... that only an untimed key tile does
     (NARROW, 128, 1152, False),   # heads narrower than the lanes
+    # Laguna's full layers, without a selection: the 16k cell's chunk of
+    # 2048 in groups of 6 over an extent of 17 408 (17 key tiles of
+    # 1024), the toy's heads of 32, and a chunk that is no query tile.
+    (LAGUNA_CELL, 2048, 17407, True),
+    (LAGUNA_WIDE, 128, 512, True),
+    (LAGUNA_TOY, 16, 64, False),
+    (LAGUNA_WIDE, 100, 512, False),
 ], ids=["toy", "cell", "ragged-chunk", "ragged-extent", "small-key-tile",
-        "narrow-heads"])
+        "narrow-heads", "laguna-cell", "laguna-wide", "laguna-toy",
+        "laguna-ragged-chunk"])
 def test_the_shapes_alone_decide_which_path_runs(cfg, size, max_len, tiled):
     """What `SlotModel.attend_kernel` says is what the chunk program
     holds: a `pallas_call` where the shapes tile, the loop elsewhere."""
     assert cfg.slot_model().attend_kernel(size, max_len) is tiled
     if size > 128:
-        return  # the cell's program: tests/test_tpu_compile.py
-    params = jax.eval_shape(lambda: sala.init_sala(jax.random.key(0), cfg))
-    cache = jax.eval_shape(lambda: sala.init_slot_cache(cfg, 1, max_len))
+        return  # the cells' programs: tests/test_tpu_compile.py
+    family = laguna if isinstance(cfg, laguna.LagunaConfig) else sala
+    params = jax.eval_shape(lambda: laguna.init_laguna(
+        jax.random.key(0), cfg) if family is laguna else sala.init_sala(
+        jax.random.key(0), cfg))
+    cache = jax.eval_shape(lambda: family.init_slot_cache(cfg, 1, max_len))
     text = str(jax.make_jaxpr(
-        lambda p, c, tok, st: sala.prefill_chunk_into_cache(
+        lambda p, c, tok, st: family.prefill_chunk_into_cache(
             p, cfg, c, 0, tok, st))(
         params, cache, jax.ShapeDtypeStruct((1, size), jnp.int32),
         jax.ShapeDtypeStruct((), jnp.int32)))
     assert ("pallas_call" in text) is tiled
     assert ("sparse_attend" in text) is tiled
+
+
+# start 0 (the diagonal tile is the extent's first), 1024 (a chunk on a
+# key tile's edge), 1920 (the extent's last chunk) and 700 (a chunk
+# resumed from the prefix pool at no tile multiple).
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("start", [0, 1024, 1920, 700])
+# Groups of 6 (Laguna's 48 over 8) and of 16 (SALA's 32 over 2).
+@pytest.mark.parametrize("G, group", [(2, 6), (1, 16)],
+                         ids=["laguna-group", "sala-group"])
+def test_dense_kernel_matches_the_loop(G, group, start, dtype):
+    """No selection: every key up to the query's own, in float32 out."""
+    Mx, dt = 2048, jnp.dtype(dtype)
+    ks = jax.random.split(jax.random.key(start), 3)
+    q = jax.random.normal(ks[0], (C, G, group, 128)).astype(dt)
+    k = jax.random.normal(ks[1], (G, 128, Mx)).astype(dt)
+    v = jax.random.normal(ks[2], (G, 128, Mx)).astype(dt)
+    assert sparse_attend.tiles(C, group, 128, Mx) == (128, 1024)
+    got = jax.jit(lambda q, k, v, s: sparse_attend.attend_chunk(
+        q, k, v, None, s))(q, k, v, start)
+    want = jax.jit(laguna._full_chunk_loop)(q, k, v, start)
+    assert got.shape == want.shape == (C, G, group, 128)
+    assert got.dtype == jnp.float32
+    got, want = np.asarray(got), np.asarray(want)
+    assert np.abs(want).max() > 0.1
+    assert np.abs(got - want).max() < TOL[dtype]
+
+
+def test_laguna_chunks_through_the_model_agree_with_the_loop(monkeypatch):
+    """A prompt of 384 in chunks of 128 through every layer of Laguna's
+    toy at heads of 128, into a cache of 512 (one key tile): last-position
+    logits of each chunk with the kernel and with the dispatch forced to
+    the loop, and the full layers' rows they leave."""
+    params = laguna.init_laguna(jax.random.key(3), LAGUNA_WIDE)
+    tokens = np.random.default_rng(4).integers(0, 512, (1, 384))
+
+    def run():
+        pre = jax.jit(lambda c, t, st: laguna.prefill_chunk_into_cache(
+            params, LAGUNA_WIDE, c, 0, t, st))
+        cache, out = laguna.init_slot_cache(LAGUNA_WIDE, 1, 512), []
+        for at in range(0, 384, 128):
+            logits, cache = pre(cache, jnp.asarray(tokens[:, at:at + 128]), at)
+            out.append(np.asarray(logits))
+        return np.stack(out), cache
+
+    assert LAGUNA_WIDE.attend_kernel(128, 512)
+    got, got_cache = run()
+    monkeypatch.setattr(sparse_attend, "tiles", lambda *a: None)
+    assert not LAGUNA_WIDE.attend_kernel(128, 512)
+    want, want_cache = run()
+    assert np.abs(got - want).max() < 1e-4 and np.abs(want).max() > 0.5
+    for name in ("k", "v", "wk", "wv"):
+        np.testing.assert_allclose(got_cache[name], want_cache[name],
+                                   atol=1e-4)
 
 
 def test_gpt2_has_no_attention_kernel_to_report():
@@ -159,16 +248,22 @@ def test_chunks_through_the_model_agree_with_the_loop(monkeypatch):
                                    atol=1e-4)
 
 
-@pytest.fixture(scope="module")
-def served():
+@pytest.fixture(scope="module", params=["sala", "laguna"])
+def served(request):
     """One request of 200 prompt positions in chunks of 128 (a whole
-    query tile, then 72 positions that are none) into an extent of 384,
-    and the scheduler's counters as /metrics shows them."""
+    query tile, then 72 positions that are none) into an extent of 384
+    (SALA's) or of 512 (Laguna's: one dense key tile), and the
+    scheduler's counters as /metrics shows them."""
     reg = Registry()
     sampler = RuntimeSampler(registry=reg)
-    params = sala.init_sala(jax.random.key(5), WIDE)
-    sched = ContinuousScheduler(params, WIDE, slots=1, prompt_len=200,
-                                max_new_tokens=185, prefill_chunk=128)
+    if request.param == "sala":
+        cfg, new = WIDE, 185
+        params = sala.init_sala(jax.random.key(5), WIDE)
+    else:
+        cfg, new = LAGUNA_WIDE, 313
+        params = laguna.init_laguna(jax.random.key(5), LAGUNA_WIDE)
+    sched = ContinuousScheduler(params, cfg, slots=1, prompt_len=200,
+                                max_new_tokens=new, prefill_chunk=128)
     sampler.add_generation_scheduler(sched)
     try:
         prompt = np.random.default_rng(6).integers(0, 256, (1, 200))

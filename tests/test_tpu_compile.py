@@ -470,6 +470,31 @@ def _laguna_step(S, jnp):
     return step, args, check
 
 
+def _laguna_chunk(S, jnp):
+    """The scheduler's chunk program for Laguna's family at the cell's
+    shapes (a chunk of 2048, `start` traced): the full layers' attention
+    is the Mosaic call of kernels/sparse_attend.py without a selection,
+    and nothing is left in HBM of the XLA loop's float32 temporaries `(G
+    8, group 6, C 2048, ...)`: a key tile's scores, or its accumulator."""
+    from tools.aot_step_ops import big_ops
+
+    cfg, _, params, cache, key, chunk, _ = _laguna_programs(S, jnp)
+    assert cfg.attend_kernel(2048, 17407)
+
+    def check(text):
+        problems = [] if "sparse_attend" in text else [
+            "no custom call named sparse_attend"]
+        found = big_ops(text, 8 * 6 * 2048 * 128)["ops"]
+        return problems + [
+            f"{op['name']} ({op['opcode']}) {op['shapes']}" for op in found
+            if any(d == "f32" and dims[:3] == (8, 6, 2048)
+                   for d, dims, _, _ in op["shapes"])]
+
+    args = (params, cache, S((), jnp.int32), S((1, 2048), jnp.int32),
+            S((), jnp.int32), key)
+    return chunk, args, check
+
+
 def _sparse_attend_small_tiles(S, jnp):
     """The kernel alone at the smallest tiles its dispatch keeps (query
     128, key 384: an extent of 1152 that 768 does not divide); the cell's
@@ -556,6 +581,7 @@ CASES = {
     "expand_attend_c1024_h64_r576_m9216_bf16": _expand_attend_cell,
     "latent_attend_s48_h64_r576_m9216_bf16": _latent_attend_cell,
     "laguna_step_g8_h48_72_w512_m17407_in_place": _laguna_step,
+    "laguna_chunk_c2048_g8_h48_m17407_attend_kernel": _laguna_chunk,
 }
 
 

@@ -14,7 +14,8 @@ path that stays as its oracle):
 * :mod:`.kv_write` — the decode step's new rows into the slot cache in
   place (every family);
 * :mod:`.sparse_attend` — a chunk's block-masked attention
-  (:mod:`tpu_dist_nn.models.sala`);
+  (:mod:`tpu_dist_nn.models.sala`), and without a selection a full
+  layer's causal grouped-query attention (:mod:`tpu_dist_nn.models.laguna`);
 * :mod:`.decode_attend` — a step's differential attention over the
   shared K/V, live tiles only (:mod:`tpu_dist_nn.models.sambay`);
 * :mod:`.expand_attend` — a chunk's latent attention in its expanded

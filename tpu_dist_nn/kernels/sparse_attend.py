@@ -4,9 +4,9 @@ The chunk's queries ``q (C, G, group, Dh)`` at positions ``start ..
 start + C - 1`` attend the slot's keys and values ``(G, Dh, M)``
 (positions in the lanes, as the slot cache stores them) where their
 K/V group's block selection ``sel (C, G, NB)`` and causality allow:
-the contract of :func:`tpu_dist_nn.models.sala._attend_chunk`, which
-stays as the path of shapes no TPU tile fits and as this kernel's
-oracle.
+the contract of :func:`tpu_dist_nn.models.sala._attend_chunk`, whose
+loop stays for shapes no TPU tile fits and as the oracle.  Without a
+selection causality alone decides (the dense kernel, end of the file).
 
 That loop forms each key tile's float32 scores ``(G, group, C, KT)``
 in HBM, 268 MB a tile at the published widths, and crosses them three
@@ -73,14 +73,14 @@ def _resident(group: int, tq: int, Dh: int) -> int:
     return group * tq * ((Dh + 2 * _LANES) * 4 + 2 * 2 * Dh * 2)
 
 
-def tiles(C: int, group: int, Dh: int, M: int, block_size: int):
-    """``(query tile, key tile)`` the kernel runs these shapes with, or
-    ``None`` where they do not tile: the caller keeps its XLA loop."""
+def tiles(C: int, group: int, Dh: int, M: int, block_size=None):
+    """``(query tile, key tile)`` the kernel runs these shapes with (no
+    selection where ``block_size`` is None), or None: the XLA loop."""
     if Dh % _LANES:
         return None
-    kt = next((k for k in _KEY_TILES
-               if M % k == 0 and k % block_size == 0
-               and k // block_size <= _WORD_BITS), None)
+    kt = _dense_key_tile(M) if block_size is None else next(
+        (k for k in _KEY_TILES if M % k == 0 and k % block_size == 0
+         and k // block_size <= _WORD_BITS), None)
     tq = next((t for t in _QUERY_TILES
                if C % t == 0 and _resident(group, t, Dh) <= _RESIDENT_BYTES),
               None)
@@ -166,14 +166,14 @@ def _selection_words(sel, KT, block_size):
     return words.transpose(1, 0, 2)
 
 
-def attend_chunk(q, k_rows, v_rows, sel, start, block_size: int, *,
+def attend_chunk(q, k_rows, v_rows, sel, start, block_size=None, *,
                  tile=None, unroll: int = _HEAD_UNROLL):
-    """``q (C, G, group, Dh)`` at positions ``start .. start + C - 1``
-    (``start`` traced) over ``k_rows``, ``v_rows (G, Dh, M)`` restricted
-    to ``sel (C, G, M / block_size)``; returns ``(C, G * group * Dh)``
-    in ``q``'s type.  The shapes must tile (:func:`tiles`).  ``tile``
-    ``(query tile, key tile)`` and ``unroll`` are for timing other
-    tilings (tools/sparse_attend_sweep.py): the program passes neither."""
+    """``q (C, G, group, Dh)`` at ``start ..`` (traced) over ``k_rows``,
+    ``v_rows (G, Dh, M)`` where ``sel (C, G, M / block_size)`` allows:
+    ``(C, G * group * Dh)`` in q's type.  ``sel`` None: :func:`_attend_dense`.
+    Shapes that tile (:func:`tiles`); ``tile``, ``unroll``: the sweep's."""
+    if sel is None:
+        return _attend_dense(q, k_rows, v_rows, start, tile)
     C, G, group, Dh = q.shape
     M = k_rows.shape[-1]
     tq, KT = tile or tiles(C, group, Dh, M, block_size)
@@ -221,3 +221,135 @@ def attend_chunk(q, k_rows, v_rows, sel, start, block_size: int, *,
         tpu=call(False), default=call(True),
     )
     return o.transpose(2, 0, 1, 3).reshape(C, G * group * Dh)
+
+
+# ------------------------------------------------------------------ dense
+# Without a selection (a full layer of grouped-query attention,
+# models/laguna.py) the mask is ``key_pos <= t`` alone.  A key tile that
+# a query tile sees whole (all but the one or two on a late chunk's
+# diagonal) runs the group's heads in line and unmasked, so that one
+# head's softmax can overlap the next one's products.  q and the output
+# keep the caller's ``(C, G * group * Dh)`` layout, a head a 128-lane
+# slice, so neither is transposed in HBM; the float32 output block is
+# the accumulator, resident over the key tiles.  The first key tile
+# holds position 0, which every query sees: no maximum stays -inf.
+#
+# Key tiles, largest first, and the query tile `tiles` picks, as timed
+# on a v5e at the 16k repository cell's full layer (PERF.md section 6;
+# ms a layer at `start` 14 336): 512 x 1024 6.09, 256 x 1024 6.61,
+# 512 x 512 10.4, 1024 x 1024 10.7, the XLA loop 27.4.
+_DENSE_KEY_TILES = (1024, 512)
+
+
+def _dense_key_tile(M: int):
+    return next((k for k in _DENSE_KEY_TILES if M % k == 0), None)
+
+
+def _dense_kernel(start_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
+                  Dh, widen):
+    qi, j = pl.program_id(1), pl.program_id(2)
+    tq, group = q_ref.shape[0], m_ref.shape[0]
+    KT = k_ref.shape[-1]
+    first = start_ref[0] + qi * tq  # the tile's first query position
+    visits = (first + tq - 1) // KT + 1
+    scale = 1.0 / np.sqrt(Dh)
+    wide = (lambda a: a.astype(jnp.float32)) if widen else (lambda a: a)
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
+
+    def head(h, seen):
+        """Head ``h`` on the key tile, under the mask ``seen`` or, where
+        the tile is seen whole, under none."""
+        lanes = slice(h * Dh, (h + 1) * Dh)
+        s = jnp.dot(wide(q_ref[:, lanes]), wide(k_ref[0]),
+                    preferred_element_type=jnp.float32) * scale
+        if seen is not None:
+            s = jnp.where(seen, s, -jnp.inf)
+        m = m_ref[h]  # (tq, 1)
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        fade = jnp.exp(m - m_new)
+        l_ref[h] = fade * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
+        o_ref[:, lanes] = fade * o_ref[:, lanes] + lax.dot_general(
+            wide(p.astype(v_ref.dtype)), wide(v_ref[0]),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        m_ref[h] = m_new
+
+    clear = j * KT + KT - 1 <= first
+
+    @pl.when((j < visits) & clear)
+    def _():
+        for h in range(group):
+            head(h, None)
+
+    @pl.when((j < visits) & jnp.logical_not(clear))
+    def _():
+        seen = j * KT + lax.broadcasted_iota(jnp.int32, (tq, KT), 1) \
+            <= first + lax.broadcasted_iota(jnp.int32, (tq, KT), 0)
+        for h in range(group):
+            head(h, seen)
+
+    @pl.when(j == visits - 1)
+    def _():
+        for h in range(group):
+            lanes = slice(h * Dh, (h + 1) * Dh)
+            o_ref[:, lanes] = o_ref[:, lanes] / l_ref[h]
+
+
+@functools.lru_cache(maxsize=None)
+def _dense_call(C, G, group, Dh, M, tiling, interpret: bool):
+    """The dense ``pallas_call`` of these shapes.  One object a shape:
+    the layers of a program that share it trace the kernel once
+    (expand_attend.py)."""
+    tq, KT = tiling
+
+    def kv_map(g, qi, j, start_ref):
+        visits = (start_ref[0] + (qi + 1) * tq - 1) // KT + 1
+        return g, 0, jnp.minimum(j, visits - 1)
+
+    heads = pl.BlockSpec((tq, group * Dh),
+                         lambda g, qi, j, start_ref: (qi, g))
+    kv_spec = pl.BlockSpec((1, Dh, KT), kv_map)
+    return pl.pallas_call(
+        functools.partial(_dense_kernel, Dh=Dh, widen=interpret),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(G, C // tq, M // KT),
+            in_specs=[heads, kv_spec, kv_spec],
+            out_specs=heads,
+            scratch_shapes=[
+                pltpu.VMEM((group, tq, 1), jnp.float32),
+                pltpu.VMEM((group, tq, 1), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((C, G * group * Dh), jnp.float32),
+        compiler_params=_PARAMS,
+        interpret=interpret,
+        name="sparse_attend",
+    )
+
+
+def _attend_dense(q, k_rows, v_rows, start, tile=None):
+    """``q (C, G, group, Dh)`` at positions ``start ..`` (traced) over
+    every key of ``k_rows``, ``v_rows (G, Dh, M)`` up to its own;
+    ``(C, G, group, Dh)`` float32.  The shapes must tile (:func:`tiles`
+    with no ``block_size``).  ``tile`` ``(query tile, key tile)``, as
+    :func:`attend_chunk`'s ``tile`` and ``unroll``, is for timing other
+    tilings (tools/sparse_attend_sweep.py): the program passes none."""
+    C, G, group, Dh = q.shape
+    M = k_rows.shape[-1]
+    shape = (C, G, group, Dh, M, tuple(tile or tiles(C, group, Dh, M)))
+    # Chosen by the platform the program is LOWERED for (kv_write.py);
+    # the interpreted twin is traced only where something can run it
+    # (decode_attend.py).
+    twin = {} if jax.default_backend() == "tpu" else {
+        "default": _dense_call(*shape, True)}
+    o = lax.platform_dependent(
+        jnp.asarray(start, jnp.int32).reshape(1),
+        q.reshape(C, G * group * Dh), k_rows, v_rows,
+        tpu=_dense_call(*shape, False), **twin)
+    return o.reshape(C, G, group, Dh)

@@ -53,6 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from tpu_dist_nn.kernels import sparse_attend
 from tpu_dist_nn.kernels.kv_write import write_rows
 from tpu_dist_nn.models import experts
 from tpu_dist_nn.models.experts import experts_form
@@ -243,7 +244,17 @@ class LagunaConfig:
             # got to: a prefix's only where a chunk ended.
             recurrent=True,
             routing_counts=self.routing_counts,
+            attend_kernel=self.attend_kernel,
         )
+
+    def attend_kernel(self, size, max_len) -> bool:
+        """Whether a chunk of ``size`` positions into a slot cache made
+        for ``max_len`` runs the full layers' attention as the Pallas
+        kernel: the dispatch of :func:`_full_chunk_attend`, asked from
+        outside the program."""
+        return self.n_full > 0 and sparse_attend.tiles(
+            int(size), self.heads(FULL) // self.n_kv_heads, self.head_dim,
+            _extent(max_len)) is not None
 
     def routing_counts(self, cache: dict) -> dict:
         """The cache's routing counts by name (:func:`.experts.
@@ -469,15 +480,26 @@ def _key_tile(M: int) -> int:
 
 
 @jax.named_scope("laguna.attn.full")
-def _full_chunk_attend(q, k_rows, v_rows, t):
-    """A full layer's chunk: queries ``q (C, G, g, d)`` at positions ``t
-    (C,)`` (consecutive) over the slot's rows ``(G, d, M)``, which
-    already hold the chunk's own; a running softmax in float32 over the
-    key tiles up to the chunk's last position.  ``(C, G, g, d)``
-    float32."""
+def _full_chunk_attend(q, k_rows, v_rows, start):
+    """A full layer's chunk: queries ``q (C, G, g, d)`` at positions
+    ``start ..`` over the slot's rows ``(G, d, M)``, which already hold
+    the chunk's own.  ``(C, G, g, d)`` float32.  Which path runs is read
+    from the shapes: the Pallas kernel of :mod:`tpu_dist_nn.kernels.
+    sparse_attend` without a block selection where they tile, the XLA
+    loop :func:`_full_chunk_loop` (its oracle) where they do not."""
+    C, _, g, d = q.shape
+    if sparse_attend.tiles(C, g, d, k_rows.shape[-1]):
+        return sparse_attend.attend_chunk(q, k_rows, v_rows, None, start)
+    return _full_chunk_loop(q, k_rows, v_rows, start)
+
+
+def _full_chunk_loop(q, k_rows, v_rows, start):
+    """:func:`_full_chunk_attend` as a running softmax in float32 over
+    the key tiles up to the chunk's last position."""
     C, G, g, d = q.shape
     M = k_rows.shape[-1]
     KT = _key_tile(M)
+    t = start + jnp.arange(C)
     qh = q.transpose(1, 2, 0, 3)  # (G, g, C, d)
 
     def tile(j, carry):
@@ -681,7 +703,7 @@ def _chunk_hidden(params, cfg, cache, slot, tokens, start):
             k_rows, k.transpose(1, 2, 0).astype(k_rows.dtype), at)
         v_rows = lax.dynamic_update_slice(
             v_rows, v.transpose(1, 2, 0).astype(v_rows.dtype), at)
-        o = _full_chunk_attend(q, k_rows, v_rows, t)
+        o = _full_chunk_attend(q, k_rows, v_rows, start)
         return _attn_out(x, o, gate, blk), (k_rows, v_rows)
 
     def window(x, blk, rings):

@@ -90,8 +90,9 @@ class SlotModel:
     # (size, max_len) -> whether the program of a chunk of `size`
     # positions into a cache made for `max_len` is built with the
     # model's Pallas attention kernel (kernels/sparse_attend.py for
-    # SALA's block-masked attention, kernels/expand_attend.py for
-    # Kimi-K2's expanded latent attention) and not its XLA loop.  It
+    # SALA's block-masked attention and Laguna's full layers,
+    # kernels/expand_attend.py for Kimi-K2's expanded latent
+    # attention) and not its XLA loop.  It
     # reads the shapes, as the model's dispatch does.
     attend_kernel: Callable = _no_kernel
     # (params, cfg, cache, slot, tokens (1, C), start) -> cache: the

@@ -237,8 +237,9 @@ class RuntimeSampler:
             "attend_kernel_chunks_total": reg.counter(
                 "tdn_gen_attend_kernel_chunks_total",
                 "prefill chunk launches whose shapes tile for the model's "
-                "Pallas attention kernel (kernels/sparse_attend.py, "
-                "kernels/expand_attend.py); the rest ran its XLA loop, or "
+                "Pallas attention kernel (kernels/sparse_attend.py with or "
+                "without a block selection, kernels/expand_attend.py); the "
+                "rest ran its XLA loop, or "
                 "the model has none",
             ),
             # The loop runs one launch ahead (serving/continuous.py).
