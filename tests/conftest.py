@@ -186,6 +186,11 @@ QUICK_TESTS = {
                        "test_prefill_then_decode_matches_full_forward[13]",
                        "test_chunked_form_equals_the_recurrence_across_blocks_and_chunks",
                        "test_a_readmitted_slot_starts_from_zero_state"],
+    # The expert layer both routed families call: the ragged form
+    # against the masked form, and what it is built from.
+    "test_experts": [
+        "test_ragged_form_is_the_masked_form[crowded-float32]",
+        "test_ragged_form_sorts_the_choices_and_sizes_nothing_by_the_worst_case"],
     "test_kv_write": [
         "test_write_rows_lands_rows_and_nothing_else[partial_lane_block]"],
     # PR 28: the chunk's block-masked attention kernel against the loop.

@@ -527,16 +527,17 @@ with open(os.path.join(CONFIGS, "rehearsal-laguna-tiny.json")) as f:
 LAGUNA = {"toy": LAGUNA_TOY,
           "wide": dict(LAGUNA_TOY, head_dim=128, param_dtype="float32",
                        num_attention_heads_per_layer=[12, 6, 6, 6, 12])}
-# (operations, digest of their counts by name) of Laguna's programs at
-# the parent of the PR that moved its full-layer helpers to models/
-# gqa.py: the same operations, whatever file they are written in.
+# (operations, digest of their counts by name) of Laguna's programs
+# since the expert layer sorts only a chunk's choices and counts its
+# tile rows (models/experts.py): the same operations, whatever file they
+# are written in (its full-layer helpers live in models/gqa.py).
 LAGUNA_PARENT = {
-    ("toy", "chunk_16"): (1677, "d26a936e60b5eea4"),
-    ("toy", "chunk_136"): (1966, "86335ca7e16fd42d"),
-    ("toy", "step"): (2412, "6f3e9b603a1822f9"),
-    ("wide", "chunk_128"): (9151, "1e1e44121ba17c0c"),
-    ("wide", "chunk_136"): (1821, "f8c3e0bf014dbd21"),
-    ("wide", "step"): (2262, "eb9aef780773f073")}
+    ("toy", "chunk_16"): (1685, "044f2bf28cf6c05f"),
+    ("toy", "chunk_136"): (2129, "542ac3b064d6e44d"),
+    ("toy", "step"): (2421, "459154b6001bbac4"),
+    ("wide", "chunk_128"): (9159, "c21183168606602d"),
+    ("wide", "chunk_136"): (1984, "5c0fb9ac9715521b"),
+    ("wide", "step"): (2271, "4ab08267c63c995e")}
 
 
 @pytest.mark.parametrize("toy,program", sorted(LAGUNA_PARENT))

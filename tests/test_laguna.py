@@ -276,10 +276,9 @@ def _routed(u, moe, cfg):
     blk = laguna._layer({n: a for n, a in moe.items()
                          if not n.startswith("ex_")}, 0)
     chosen, w = laguna.route(u, blk, cfg)
-    on, gates = experts.held_gates(chosen, w, cfg.experts_held)
-    form = experts.experts_dense if experts.experts_form(u.shape[0]) \
-        == "dense" else experts.experts_ragged
-    return form(u, on, gates, moe["ex_gu"], moe["ex_d"], 0)
+    return experts.routed(u, chosen, w, cfg.experts_held, moe["ex_gu"],
+                          moe["ex_d"], 0, jnp.ones((u.shape[0],), bool),
+                          "laguna.experts")[0]
 
 
 def test_router_weights_sum_to_the_scale_and_only_held_experts_count():
@@ -710,12 +709,14 @@ def _kimi_programs():
             p, cache, v3, v3, b3)}
 
 
-# (operations, digest of their counts by name) of each program at the
-# parent commit of the PR that moved the expert layer (PR 37): the same
-# operations, whatever file they are written in.
-KIMI_PARENT = {"chunk_16": (1057, "d05415e83ced1c64"),
-               "chunk_136": (1216, "0178b40165b5280a"),
-               "step": (1293, "0f0bf7a24a561108")}
+# (operations, digest of their counts by name) of each program since
+# the expert layer sorts only a chunk's choices and counts its tile rows
+# (models/experts.py), and Kimi-K2 hands it weights zeroed where the
+# expert is held elsewhere (mla_moe._held_gates): the same operations,
+# whatever file they are written in.
+KIMI_PARENT = {"chunk_16": (1085, "a13fea593a7c0d9d"),
+               "chunk_136": (1342, "69813e8b5a2f998f"),
+               "step": (1322, "3108726022fc7fab")}
 
 
 @pytest.mark.parametrize("program", sorted(KIMI_PARENT))

@@ -219,8 +219,12 @@ def _routed(u, moe, cfg, form):
     blk = mla_moe._layer({n: a for n, a in moe.items()
                           if not n.startswith("ex_")}, 0)
     chosen, w = mla_moe.route(u, blk, cfg)
-    on, gates = mla_moe._held_gates(chosen, w, cfg)
-    return form(u, on, gates, moe["ex_gu"], moe["ex_d"], 0)
+    if form is experts.experts_dense:
+        on, gates = experts.held_gates(chosen, w, cfg.experts_held)
+        return form(u, on, gates, moe["ex_gu"], moe["ex_d"], 0)
+    return form(u, experts.held_choices(chosen, cfg.experts_held), w,
+                jnp.ones((u.shape[0],), bool), moe["ex_gu"], moe["ex_d"],
+                0)[0]
 
 
 def test_a_tokens_result_does_not_depend_on_its_batch():
@@ -447,6 +451,11 @@ def test_routing_counters_reach_the_registry(weights, rows):
     assert scraped["tdn_gen_expert_visits_total"][()] == 4 * 2 * 6
     assert scraped["tdn_gen_expert_pairs_total"] == {
         (str(e),): int(n) for e, n in enumerate(totals["expert_pairs"])}
+    # The 136-token chunk ran the ragged form, the 64-token one did not.
+    rows = scraped["tdn_gen_expert_tile_rows_total"]
+    assert rows == {("pair",): int(totals["tile_rows_pair"]),
+                    ("pad",): int(totals["tile_rows_pad"])}
+    assert rows["pair",] > 0 and (rows["pair",] + rows["pad",]) % 128 == 0
     assert scraped["tdn_gen_cache_bytes"] == {
         ("latent",): float(sched.cache_bytes["latent"])}
 

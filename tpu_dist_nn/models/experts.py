@@ -62,88 +62,152 @@ def experts_dense(u, on, gates, ex_gu, ex_d, layer):
     return jnp.sum(y * gates.T[:, :, None], 0)
 
 
-def experts_ragged(u, on, gates, ex_gu, ex_d, layer):
-    """The pairs (token, held expert) that were chosen, sorted by
-    expert into tiles of ``PAIR_TILE`` rows, each expert's rows padded
-    to whole tiles; a loop over the tiles that hold a pair, one expert's
+def held_choices(chosen, held: tuple):
+    """``(A, k)`` int32: the place in ``held`` of each expert a token
+    chose, or ``len(held)`` where it is held elsewhere.  Elementwise,
+    one comparison a run of consecutive ids in ``held`` (a chip's share
+    of an expert-parallel deployment is one run), made at trace time: a
+    lookup table would be a gather of ``A k`` scalars, 0.17 ms a layer
+    of the 16k repository cell's chunk on a v5e (tools/experts_sweep.py,
+    PERF.md)."""
+    pick = jnp.full(chosen.shape, len(held), jnp.int32)
+    first = 0
+    for n in range(1, len(held) + 1):
+        if n == len(held) or held[n] != held[n - 1] + 1:
+            lo = held[first]
+            pick = jnp.where((chosen >= lo) & (chosen <= held[n - 1]),
+                             chosen - lo + first, pick)
+            first = n
+    return pick
+
+
+def experts_ragged(u, pick, w, counted, ex_gu, ex_d, layer):
+    """The chosen pairs (token, held expert) of ``pick (A, k)``
+    (:func:`held_choices`) with weights ``w (A, k)``, sorted by expert
+    and cut into tiles of ``PAIR_TILE`` rows, each expert's last tile
+    padded; a loop over the tiles that hold a pair, one expert's
     matrices a tile, sliced out of the stacks inside the tile's own
     products (a layer of them handed to the loop whole would be copied
     first: 1.06 GB a layer of Kimi-K2's share, 2.42 GB of Laguna's).
-    Sized for the worst case (every token on every held expert) by
-    shape, and as long as the load by trip count."""
-    A, N = gates.shape
-    R = PAIR_TILE
-    on = on.T  # (N, A): expert-major, so a sort is a cumsum
-    count = jnp.sum(on, -1)
+    Only the ``A k`` choices are sorted, and a tile reads its rows and
+    weights as slices of the sorted choices: nothing is sized by every
+    token on every held expert.  Each tile's rows go back to their
+    tokens by a product with their one-hot, the MXU's work (a gather of
+    every choice's row, or a scatter of a tile's, moves rows at a fifth
+    of the bytes' rate or less: PERF.md).
+
+    Returns ``(out (A, D) float32, pairs (N,), rows (2,))``: the pairs
+    by held expert over the tokens ``counted (A,)``, and the tile rows
+    the loop computed and the pair rows among them."""
+    A, k = pick.shape
+    N, R, D = ex_gu.shape[1], PAIR_TILE, u.shape[1]
+    E = A * k
+    # Choices held elsewhere sort last, past every held expert's.
+    key, entry, gate = lax.sort(
+        (pick.ravel(), jnp.arange(E, dtype=jnp.int32),
+         w.ravel().astype(jnp.float32)), num_keys=1, is_stable=True)
+    bound = jnp.searchsorted(key, jnp.arange(N + 1, dtype=key.dtype),
+                             method="scan_unrolled").astype(jnp.int32)
+    start, count = bound[:-1], jnp.diff(bound)
     tiles = -(-count // R)
     first_tile = jnp.cumsum(tiles) - tiles
-    n_tiles = -(-A // R) * N  # every expert full
-    # Row of the pair (n, a) in the tiled table, or past it.
-    rank = jnp.cumsum(on, -1) - 1
-    dest = jnp.where(on, first_tile[:, None] * R + rank, n_tiles * R)
-    token = jnp.full((n_tiles * R + 1,), A, jnp.int32).at[dest.ravel()].set(
-        jnp.broadcast_to(jnp.arange(A, dtype=jnp.int32), (N, A)).ravel())
-    gate = jnp.zeros((n_tiles * R + 1,), jnp.float32).at[dest.ravel()].set(
-        gates.T.ravel())
-    expert_of_tile = jnp.sum(
-        jnp.arange(n_tiles)[:, None] >= (first_tile + tiles)[None, :], -1)
+    # The most tiles the choices can fill: each expert's last one partly.
+    n_tiles = min(-(-A // R) * N, (A * min(k, N) + N * (R - 1)) // R)
+    owner = jnp.minimum(jnp.sum(jnp.arange(n_tiles)[:, None]
+                                >= (first_tile + tiles)[None, :], -1), N - 1)
+    done = (jnp.arange(n_tiles) - first_tile[owner]) * R
+    # A tile's expert, first row among the sorted choices, live rows.
+    plan = jnp.stack([owner, start[owner] + done, count[owner] - done], 1)
+    # Past the last choice a tile's slices read padding.
+    token = jnp.concatenate([entry // k, jnp.full((R,), A, jnp.int32)])
+    gate = jnp.concatenate([gate, jnp.zeros((R,), jnp.float32)])
     F = ex_d.shape[-2]
-    padded = jnp.concatenate([u, jnp.zeros((1, u.shape[1]), u.dtype)])
+    padded = jnp.concatenate([u, jnp.zeros((1, D), u.dtype)])
 
     def expert(stack, n):
         return lax.dynamic_slice(stack, (layer, n, 0, 0),
                                  (1, 1) + stack.shape[2:])[0, 0]
 
     def tile(i, out):
-        rows = lax.dynamic_slice(token, (i * R,), (R,))
-        g = lax.dynamic_slice(gate, (i * R,), (R,))
-        n = jnp.minimum(expert_of_tile[i], N - 1)
+        n, at, live = lax.dynamic_index_in_dim(plan, i, keepdims=False)
+        live = jnp.arange(R) < live
+        rows = jnp.where(live, lax.dynamic_slice(token, (at,), (R,)), A)
+        g = jnp.where(live, lax.dynamic_slice(gate, (at,), (R,)), 0.0)
         gu = padded[rows] @ expert(ex_gu, n)
         y = _einsum32("rf,fd->rd", jax.nn.silu(gu[:, :F]) * gu[:, F:],
                       expert(ex_d, n)) * g[:, None]
-        # Back to the tokens by a product with the rows' one-hot: the
-        # MXU's work, where a scatter-add is the scalar core's.
         hot = rows[None, :] == jnp.arange(A, dtype=jnp.int32)[:, None]
         return out + _einsum32("ar,rd->ad", hot.astype(u.dtype),
                                y.astype(u.dtype))
 
-    return lax.fori_loop(0, jnp.sum(tiles), tile,
-                         jnp.zeros((A, u.shape[1]), jnp.float32))
+    out = lax.fori_loop(0, jnp.sum(tiles), tile,
+                        jnp.zeros((A, D), jnp.float32))
+    seen = jnp.cumsum(counted[entry // k], dtype=jnp.int32)
+    seen = jnp.concatenate([jnp.zeros((1,), jnp.int32), seen])
+    return out, seen[bound[1:]] - seen[bound[:-1]], \
+        jnp.stack([jnp.sum(tiles) * R, jnp.sum(count)])
 
 
-def routed(u, on, gates, ex_gu, ex_d, layer, counted, scope: str):
+def routed(u, chosen, w, held: tuple, ex_gu, ex_d, layer, counted,
+           scope: str):
     """The held experts' part of the routed sum of ``u (A, D)``
-    (float32), for the tokens' choices of held experts ``on`` and their
-    weights ``gates`` (:func:`held_gates`), under the family's traced
-    scope ``scope``; and the layer's routing counts over the tokens
-    ``counted (A,)``: pairs by held expert ``(n_held,)``."""
+    (float32), for the tokens' ``chosen (A, k)`` experts of the router
+    and their weights ``w``, of which this layer holds ``held``, under
+    the family's traced scope ``scope``; and the layer's routing counts
+    ``(n_held + 2,)``: pairs by held expert over the tokens ``counted
+    (A,)``, then the tile rows the ragged form computed and the pair
+    rows among them (none in the masked form)."""
+    if experts_form(u.shape[0]) == "dense":
+        on, gates = held_gates(chosen, w, held)
+        with jax.named_scope(scope):
+            out = experts_dense(u, on, gates, ex_gu, ex_d, layer)
+        return out, jnp.concatenate([
+            jnp.sum(on & counted[:, None], 0, dtype=jnp.int32),
+            jnp.zeros((2,), jnp.int32)])
     with jax.named_scope(scope):
-        form = experts_dense if experts_form(u.shape[0]) == "dense" \
-            else experts_ragged
-        out = form(u, on, gates, ex_gu, ex_d, layer)
-    return out, jnp.sum(on & counted[:, None], 0, dtype=jnp.int32)
+        out, pairs, rows = experts_ragged(
+            u, held_choices(chosen, held), w, counted, ex_gu, ex_d, layer)
+    return out, jnp.concatenate([pairs, rows])
+
+
+def no_pairs(n_held: int):
+    """What a layer without routed experts adds to the routing counts
+    (:func:`routed`)."""
+    return jnp.zeros((n_held + 2,), jnp.int32)
 
 
 def counts(n_moe: int, n_held: int, k: int, pairs, tokens, step: bool):
     """What one launch adds to a family's ``routed`` vector (``n_held +
-    3`` int32): ``pairs (n_moe, n_held)`` by layer and held expert over
-    ``tokens`` counted tokens, then the pairs routed anywhere, and in a
-    step the (layer, held expert) visits that got a pair and all of
-    them."""
+    5`` int32, :func:`init_counts`): ``pairs (n_moe, n_held + 2)`` by
+    layer (:func:`routed`) over ``tokens`` counted tokens.  The pairs by
+    held expert, then the pairs routed anywhere, in a step the (layer,
+    held expert) visits that got a pair and all of them, then the
+    ragged form's tile rows and the pair rows among them."""
     visits = n_moe * n_held
+    held = pairs[:, :n_held]
+    total = jnp.sum(pairs, 0)
     return jnp.concatenate([
-        jnp.sum(pairs, 0),
+        total[:n_held],
         jnp.stack([
             tokens * (k * n_moe),
-            jnp.sum(pairs > 0, dtype=jnp.int32) if step else jnp.int32(0),
+            jnp.sum(held > 0, dtype=jnp.int32) if step else jnp.int32(0),
             jnp.where(tokens > 0, visits, 0) if step else jnp.int32(0),
-        ]).astype(jnp.int32)])
+        ]).astype(jnp.int32),
+        total[n_held:]])
+
+
+def init_counts(n_held: int):
+    """A slot cache's zeroed ``routed`` vector (:func:`counts`)."""
+    return jnp.zeros((n_held + 5,), jnp.int32)
 
 
 def routing_counts(routed_vec, n_held: int) -> dict:
     """A ``routed`` vector by name (device values; the scheduler
     fetches them together): ``expert_pairs (n_held,)``,
-    ``routed_pairs``, ``expert_touched``, ``expert_visits``."""
+    ``routed_pairs``, ``expert_touched``, ``expert_visits``, and the
+    ragged form's tile rows by what they held, ``tile_rows_pair`` and
+    ``tile_rows_pad``."""
     r, n = routed_vec, n_held
     return {"expert_pairs": r[:n], "routed_pairs": r[n],
-            "expert_touched": r[n + 1], "expert_visits": r[n + 2]}
+            "expert_touched": r[n + 1], "expert_visits": r[n + 2],
+            "tile_rows_pair": r[n + 4], "tile_rows_pad": r[n + 3] - r[n + 4]}

@@ -551,18 +551,19 @@ def _moe_ffn(x, norm_g, moe, layer, counted, cfg):
                  layer)
     u = _rms(x, norm_g, cfg.rms_eps)
     chosen, w = route(u, blk, cfg)
-    on, gates = experts.held_gates(chosen, w, cfg.experts_held)
-    routed, pairs = experts.routed(u, on, gates, moe["ex_gu"], moe["ex_d"],
-                                   layer, counted, "laguna.experts")
+    routed, pairs = experts.routed(u, chosen, w, cfg.experts_held,
+                                   moe["ex_gu"], moe["ex_d"], layer, counted,
+                                   "laguna.experts")
     return x + _shared_expert(u, blk) + routed.astype(x.dtype), pairs
 
 
 def _ffn(x, blk, params, mlp, i, counted, cfg):
     """The feed-forward of kind ``mlp``, the ``i``-th of its kind:
-    ``(x, pairs (n_held,))``, no pairs in a dense layer."""
+    ``(x, pairs (n_held + 2,))`` (:func:`.experts.routed`), no pairs in
+    a dense layer."""
     if mlp == DENSE:
         return _dense_ffn(x, blk["ln2_g"], _layer(params["dense"], i),
-                          cfg), jnp.zeros((cfg.n_held,), jnp.int32)
+                          cfg), experts.no_pairs(cfg.n_held)
     return _moe_ffn(x, blk["ln2_g"], params["moe"], i, counted, cfg)
 
 
@@ -595,7 +596,7 @@ def init_slot_cache(cfg: LagunaConfig, slots: int, max_len: int) -> dict:
     return {
         "k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype),
         "wk": jnp.zeros(ring, dtype), "wv": jnp.zeros(ring, dtype),
-        "routed": jnp.zeros((cfg.n_held + 3,), jnp.int32),
+        "routed": experts.init_counts(cfg.n_held),
     }
 
 

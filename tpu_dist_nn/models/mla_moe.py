@@ -198,9 +198,8 @@ class MlaMoeConfig:
         return count
 
     def routing_counts(self, cache: dict) -> dict:
-        """The cache's routing counts by name (device values; the
-        scheduler fetches them together): ``expert_pairs (n_held,)``,
-        ``routed_pairs``, ``expert_touched``, ``expert_visits``."""
+        """The cache's routing counts by name, device values the
+        scheduler fetches together (:func:`.experts.routing_counts`)."""
         return experts.routing_counts(cache["routed"], self.n_held)
 
     # ---------------------------------------------------------- loading
@@ -567,9 +566,10 @@ def route(u, blk, cfg):
 
 
 def _held_gates(chosen, w, cfg):
-    """``(A, n_held)``: whether each token chose each held expert, and
-    the expert's weight for it (:func:`.experts.held_gates`)."""
-    return experts.held_gates(chosen, w, cfg.experts_held)
+    """The router's ``chosen (A, k)`` experts and their weights, zero
+    where the expert is held elsewhere (:func:`.experts.routed`)."""
+    held = experts.held_choices(chosen, cfg.experts_held) < cfg.n_held
+    return chosen, jnp.where(held, w, 0.0)
 
 
 @jax.named_scope("mla_moe.shared")
@@ -581,15 +581,14 @@ def _moe_ffn(x, norm_g, moe, layer, counted, cfg):
     """``x (A, D)`` through expert layer ``layer`` of the stacked
     ``moe`` parameters: the shared expert plus the held experts' part of
     the routed sum (:mod:`.experts`).  Also the layer's routing counts
-    over the tokens ``counted (A,)``: pairs by held expert
-    ``(n_held,)``."""
+    over the tokens ``counted (A,)`` (:func:`.experts.routed`)."""
     blk = _layer({n: a for n, a in moe.items() if not n.startswith("ex_")},
                  layer)
     u = _rms(x, norm_g, cfg.rms_eps)
-    chosen, w = route(u, blk, cfg)
-    on, gates = _held_gates(chosen, w, cfg)
-    routed, pairs = experts.routed(u, on, gates, moe["ex_gu"], moe["ex_d"],
-                                   layer, counted, "mla_moe.experts")
+    chosen, w = _held_gates(*route(u, blk, cfg), cfg)
+    routed, pairs = experts.routed(u, chosen, w, cfg.experts_held,
+                                   moe["ex_gu"], moe["ex_d"], layer, counted,
+                                   "mla_moe.experts")
     return x + _shared_expert(u, blk) + routed.astype(x.dtype), pairs
 
 
@@ -617,7 +616,7 @@ def init_slot_cache(cfg: MlaMoeConfig, slots: int, max_len: int) -> dict:
     return {
         "lat": jnp.zeros((cfg.n_layers, slots, 1, cfg.latent_dim,
                           _extent(max_len)), jnp.dtype(cfg.param_dtype)),
-        "routed": jnp.zeros((cfg.n_held + 3,), jnp.int32),
+        "routed": experts.init_counts(cfg.n_held),
     }
 
 

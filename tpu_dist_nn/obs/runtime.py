@@ -278,7 +278,17 @@ class RuntimeSampler:
         # Routing load, counted on the device by a model with routed
         # experts (SlotModel.routing_counts) and fetched by the
         # scheduler every few dozen iterations: its `routing_totals`.
+        tile_rows = reg.counter(
+            "tdn_gen_expert_tile_rows_total",
+            "rows of the 128-row tiles that the ragged expert form "
+            "computed (a prefill chunk's routed experts), by whether a "
+            "row held a (token, expert) pair or padded its expert's last "
+            "tile; summed over expert layers",
+            labels=("state",),
+        )
         self._c_gen_routing = {
+            **{f"tile_rows_{state}": tile_rows.labels(state=state)
+               for state in ("pair", "pad")},
             "routed_pairs": reg.counter(
                 "tdn_gen_routed_pairs_total",
                 "(token, expert) pairs the router chose, every expert "
